@@ -14,6 +14,7 @@ multi-process world:  python cross_silo_grpc_multiprocess.py
 import os as _os, sys as _sys
 _sys.path.insert(0, _os.path.abspath(_os.path.join(_os.path.dirname(__file__), "..")))
 
+import os
 import socket
 import subprocess
 import sys
@@ -57,9 +58,12 @@ def main() -> None:
     args = mk("server", 0, port)
     ds, od = data_mod.load(args)
     server = FedMLCrossSiloServer(args, None, ds, model_mod.create(args, od))
+    # client organisations run on the CPU: this parent holds the chip (if
+    # any), and a chip belongs to one process
     procs = [
         subprocess.Popen([sys.executable, __file__, "--client", str(r),
-                          "--port", str(port)])
+                          "--port", str(port)],
+                         env=dict(os.environ, JAX_PLATFORMS="cpu"))
         for r in range(1, N_CLIENTS + 1)
     ]
     ok = False

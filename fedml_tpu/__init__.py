@@ -42,47 +42,24 @@ def init(args: Optional[Arguments] = None, should_init_logs: bool = True) -> Arg
         )
     if args is None:
         args = load_arguments()
+    # before the first compile (seeding builds a PRNGKey): JAX opens the
+    # persistent cache once per process
+    cache_dir = device.enable_compilation_cache()
     args.rng = seed_everything(int(args.random_seed))
     _update_client_id_list(args)
-    _maybe_enable_compilation_cache(args)
     from .core import mlops
 
     mlops.init(args)
     with _global_args_lock:
         _global_args = args
     logging.getLogger(__name__).info(
-        "init: platform=%s backend=%s optimizer=%s",
+        "init: platform=%s backend=%s optimizer=%s compile_cache=%s",
         args.training_type,
         args.backend,
         args.federated_optimizer,
+        cache_dir,
     )
     return args
-
-
-def _maybe_enable_compilation_cache(args: Arguments) -> None:
-    """Point XLA's persistent compilation cache at ``compilation_cache_dir``.
-
-    Repeat runs — and the driver's bench legs — then deserialize compiled
-    executables instead of re-lowering them, which removes the compile wall
-    that made BENCH legs time out (ISSUE 1). A low min-compile-time floor
-    keeps even mid-sized programs cached; disk is the only cost.
-    """
-    cache_dir = str(getattr(args, "compilation_cache_dir", "") or "")
-    if not cache_dir:
-        return
-    import os
-
-    import jax
-
-    os.makedirs(cache_dir, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
-    if "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS" not in os.environ:
-        # don't clobber an explicitly configured floor (e.g. raised to keep
-        # a slow shared cache dir from thrashing on tiny entries)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-    logging.getLogger(__name__).info(
-        "init: persistent XLA compilation cache at %s", cache_dir
-    )
 
 
 def _update_client_id_list(args: Arguments) -> None:

@@ -173,9 +173,6 @@ _SCHEMA: Dict[str, tuple] = {
     # placement; empty = the built-in first-axis/replicated defaults
     "mesh_partition_rules": (str, ""),
     "mesh_state_rules": (str, ""),
-    # persistent XLA compilation cache — repeat runs (and bench legs) skip
-    # the compile wall entirely. Empty = disabled. Wired in fedml.init().
-    "compilation_cache_dir": (str, ""),
     # async traffic plane (fedml_tpu/traffic/ — docs/traffic.md).
     # aggregation_mode: sync keeps the per-round cohort barrier (the
     # reference semantics, bitwise-unchanged); async is FedBuff-style
@@ -494,11 +491,6 @@ def add_args() -> argparse.Namespace:
     parser.add_argument(
         "--silo_device_indices", type=int, nargs="*", default=None,
         help="chips this silo trains over (intra-silo data parallelism)",
-    )
-    parser.add_argument(
-        "--compilation_cache_dir", type=str, default=None,
-        help="persistent XLA compilation cache dir (repeat runs skip the "
-        "compile wall); also settable via YAML common_args",
     )
     # crash-safe rounds (core/runstate.py)
     parser.add_argument(

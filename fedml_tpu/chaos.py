@@ -508,8 +508,9 @@ SIGKILL_RCS = (-9, 137)  # subprocess returncode forms of a SIGKILL death
 
 
 def _run_leg(cmd: List[str], timeout_s: float) -> int:
-    env = dict(os.environ, JAX_PLATFORMS=os.environ.get(
-        "JAX_PLATFORMS", "cpu"))
+    # legs simulate devices: pinned to the CPU so they never fight the
+    # parent for a chip (a chip belongs to one process)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     proc = subprocess.run(
         cmd, timeout=timeout_s, env=env,
         cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),

@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import constants
 from ..core.mlops import telemetry
 from ..parallel.sharding import make_mesh
 from ..parallel.train_step import CheetahTrainer, make_optimizer
@@ -94,6 +95,10 @@ class CheetahRunner:
                 total_steps=self.total_steps,
             ),
             accum_steps=self.accum_steps,
+            # a sequence axis in the mesh means sequence parallelism — left
+            # off, the axis is built and the whole step replicated over it
+            seq_sharded=int(
+                self.mesh.shape[constants.MESH_AXIS_SEQUENCE]) > 1,
         )
         self.dataset = dataset
         self.checkpoint_dir = str(getattr(args, "checkpoint_dir", "") or "")
@@ -189,7 +194,8 @@ class CheetahRunner:
         flops_tok = telemetry.flops_per_token(
             n_params, self.seq_len, self.cfg.n_layers, self.cfg.d_model
         )
-        device_kind = str(getattr(jax.devices()[0], "device_kind", "?"))
+        # None off-TPU (MFU "not measured"); an unknown TPU kind raises
+        peak = telemetry.peak_bf16_flops(jax.devices()[0])
         n_chips = jax.device_count()
         for step in range(start_step, self.total_steps):
             telemetry.on_round_start(step)
@@ -210,10 +216,11 @@ class CheetahRunner:
             if rec is not None and rec.wall_s > 0:
                 tps = tokens.size / rec.wall_s
                 telemetry.gauge_set("cheetah.tokens_per_sec", tps)
-                mfu = telemetry.mfu_estimate(tps, flops_tok, device_kind,
-                                             n_chips)
-                if mfu is not None:
-                    telemetry.gauge_set("cheetah.mfu_estimate", mfu)
+                if peak is not None:
+                    telemetry.gauge_set(
+                        "cheetah.mfu_estimate",
+                        telemetry.mfu_estimate(tps, flops_tok, peak, n_chips),
+                    )
             telemetry.on_round_end(step)
             if every and (step + 1) % every == 0 and self.checkpoint_dir:
                 ckpt.save(state)
@@ -237,9 +244,13 @@ class CheetahRunner:
             "steps": self.total_steps - start_step,
             "tokens_per_sec": tps,
         }
-        mfu = telemetry.mfu_estimate(tps, flops_tok, device_kind, n_chips)
-        if mfu is not None:
-            result["mfu_estimate"] = round(mfu, 4)
+        if peak is not None:
+            result["mfu_estimate"] = round(
+                telemetry.mfu_estimate(tps, flops_tok, peak, n_chips), 4
+            )
+        else:
+            logger.info("cheetah: mfu_estimate not measured (platform %s)",
+                        jax.devices()[0].platform)
         if self.checkpoint_dir:
             ckpt.save(state)
             ckpt.close()  # release orbax worker threads with the run

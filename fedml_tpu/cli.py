@@ -539,20 +539,13 @@ def cmd_agent(args) -> int:
 def cmd_cache(args) -> int:
     """Inspect / clear the persistent XLA compilation cache.
 
-    The cache is what lets repeat runs (and the driver's bench legs) skip
-    the compile wall — wire it into a run with ``--compilation_cache_dir``
-    (or the ``compilation_cache_dir`` YAML key; see fedml_tpu.init).
+    The cache is what lets repeat runs skip the compile wall; every run
+    that starts through ``fedml_tpu.init`` uses the directory
+    ``device.enable_compilation_cache`` decides.
     """
-    from . import constants
+    from .device import enable_compilation_cache
 
-    cache_dir = (
-        args.dir
-        or os.environ.get("JAX_COMPILATION_CACHE_DIR")
-        or os.environ.get("BENCH_COMPILE_CACHE_DIR")
-        # bench.py's default cache — the one the documented bench workflow
-        # actually writes to
-        or constants.BENCH_COMPILE_CACHE_DIR_DEFAULT
-    )
+    cache_dir = args.dir or enable_compilation_cache()
     if not os.path.isdir(cache_dir):
         print(f"compilation cache: {cache_dir} (empty — no directory)")
         _report_cache_telemetry(getattr(args, "run_file", ""))
@@ -848,8 +841,7 @@ def main(argv=None) -> int:
     )
     p_cache.add_argument("--dir", default="",
                          help="cache dir (default: $JAX_COMPILATION_CACHE_DIR,"
-                         " $BENCH_COMPILE_CACHE_DIR, or the bench default "
-                         "/tmp/fedml_tpu_bench_jax_cache)")
+                         " else <checkout>/.jax_cache)")
     p_cache.add_argument("--clear", action="store_true",
                          help="delete every cache entry")
     p_cache.add_argument("--run_file", default="",

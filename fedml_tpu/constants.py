@@ -70,11 +70,3 @@ MESH_AXIS_TENSOR = "tensor"     # Cheetah: tensor parallel (MXU-aligned sharding
 MESH_AXIS_SEQUENCE = "sequence" # Cheetah: sequence/context parallel (ring attention)
 MESH_AXIS_EXPERT = "expert"     # Cheetah: expert parallel (MoE)
 MESH_AXIS_PIPELINE = "pipeline" # Cheetah: pipeline parallel
-
-# ---------------------------------------------------------------------------
-# Persistent XLA compilation cache
-# ---------------------------------------------------------------------------
-# Default cache dir the bench harness writes (bench.py) and the `fedml cache`
-# CLI inspects/clears — one constant so they can never point at different
-# directories.
-BENCH_COMPILE_CACHE_DIR_DEFAULT = "/tmp/fedml_tpu_bench_jax_cache"

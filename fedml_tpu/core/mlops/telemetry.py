@@ -48,9 +48,11 @@ DEFAULT_BUCKETS: Tuple[float, ...] = (
     0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 30.0, 60.0, 120.0,
 )
 
-# peak bf16 FLOPs/s per chip by device kind (public spec sheets) — the MFU
-# denominator for the Cheetah runner's live estimate (bench.py keeps its own
-# copy because its parent process must never import this package's deps)
+# Peak dense bf16 FLOP/s per chip, keyed by ``jax.Device.device_kind`` — the
+# one MFU denominator (runner, bench legs and tools all read it here).
+# Source: Google Cloud TPU documentation, the per-generation "System
+# architecture" pages (v4 275, v5e 197, v5p 459, v6e 918 TFLOP/s). libtpu
+# reports the v5e as "TPU v5 lite" and the v6e as "TPU v6 lite".
 PEAK_BF16_FLOPS = {
     "TPU v4": 275e12,
     "TPU v5 lite": 197e12,
@@ -60,6 +62,21 @@ PEAK_BF16_FLOPS = {
     "TPU v6 lite": 918e12,
     "TPU v6e": 918e12,
 }
+
+
+def peak_bf16_flops(device) -> Optional[float]:
+    """Peak bf16 FLOP/s of ``device`` (a ``jax.Device``). ``None`` off-TPU,
+    where MFU is "not measured"; a TPU kind missing from the table is an
+    error, never a default."""
+    if device.platform != "tpu":
+        return None
+    peak = PEAK_BF16_FLOPS.get(device.device_kind)
+    if peak is None:
+        raise KeyError(
+            f"no peak FLOP/s for TPU device_kind {device.device_kind!r}; "
+            f"add it to telemetry.PEAK_BF16_FLOPS with its source"
+        )
+    return peak
 
 
 class Histogram:
@@ -776,8 +793,7 @@ def flops_per_token(n_params: int, seq_len: int, n_layers: int,
 
 
 def mfu_estimate(tokens_per_sec: float, flops_per_tok: float,
-                 device_kind: str, n_chips: int = 1) -> Optional[float]:
-    peak = PEAK_BF16_FLOPS.get(str(device_kind))
-    if not peak or n_chips <= 0:
-        return None
-    return (tokens_per_sec * flops_per_tok) / (peak * n_chips)
+                 peak_flops: float, n_chips: int = 1) -> float:
+    """Model FLOP/s utilization against ``peak_flops`` per chip
+    (:func:`peak_bf16_flops`)."""
+    return (tokens_per_sec * flops_per_tok) / (peak_flops * n_chips)

@@ -31,10 +31,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
-try:
-    from jax import shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..core.containers import BoundedDict
@@ -126,21 +123,12 @@ def make_silo_dp_train_fn(bundle, args, local_cap: int, mesh, axis=SILO_AXIS):
         return params, metrics
 
     data_spec = P(axis)
-    try:  # jax >= 0.8: check_rep retired (VMA inference handles it)
-        fn = shard_map(
-            device_train,
-            mesh=mesh,
-            in_specs=(P(), data_spec, data_spec, data_spec, P()),
-            out_specs=(P(), P()),
-        )
-    except TypeError:
-        fn = shard_map(
-            device_train,
-            mesh=mesh,
-            in_specs=(P(), data_spec, data_spec, data_spec, P()),
-            out_specs=(P(), P()),
-            check_rep=False,
-        )
+    fn = shard_map(
+        device_train,
+        mesh=mesh,
+        in_specs=(P(), data_spec, data_spec, data_spec, P()),
+        out_specs=(P(), P()),
+    )
     return jax.jit(fn)
 
 

@@ -15,6 +15,7 @@ module is the single place that builds meshes for the three runtimes:
 from __future__ import annotations
 
 import logging
+import os
 from typing import Dict, Optional, Sequence
 
 import jax
@@ -24,6 +25,26 @@ from jax.sharding import Mesh
 from . import constants
 
 logger = logging.getLogger(__name__)
+
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def enable_compilation_cache() -> str:
+    """Place XLA's persistent compilation cache and return its directory.
+
+    The one place that decides. With ``JAX_COMPILATION_CACHE_DIR`` set, JAX
+    reads the variable itself and nothing is set here; otherwise the cache is
+    ``<checkout>/.jax_cache`` (git-ignored). Must run before the first
+    compile: JAX opens the cache once per process. A low min-compile-time
+    floor keeps mid-sized programs cached; disk is the only cost.
+    """
+    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not cache_dir:
+        cache_dir = os.path.join(_CHECKOUT, ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
+    if "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS" not in os.environ:
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+    return cache_dir
 
 
 def device_kind() -> str:

@@ -25,10 +25,14 @@ construction. This module supplies the two missing pieces:
   semantics (device locality, cross-process collectives over the gRPC
   coordinator) are exercised for real without N machines.
 
-The launcher is also the honest emulation story for CI: a 2-process × 4
-virtual-device run has the same global/local device split, the same
-addressable-shard semantics, and the same collective routing as a 2-host
-pod slice — only the wire underneath differs.
+The launcher is a CPU EMULATION, and only that: ``spawn`` always passes a
+``local_device_count`` (``fedml_tpu multihost`` defaults it to 1), and
+``initialize`` with a ``local_device_count`` forces the ``cpu`` platform, so
+no spawned worker ever opens a chip. A 2-process × 4 virtual-device run has
+the same global/local device split, the same addressable-shard semantics and
+the same collective routing as a 2-host pod slice — only the wire underneath
+differs. On real hardware one process drives all chips of a host; a pod runs
+one process per host, each calling ``initialize()`` with no arguments.
 """
 
 from __future__ import annotations
@@ -109,7 +113,9 @@ def spawn(worker_argv: Sequence[str], n_processes: int,
           coordinator_port: Optional[int] = None,
           env: Optional[Dict[str, str]] = None,
           timeout_s: float = 300.0) -> List[subprocess.CompletedProcess]:
-    """Run ``worker_argv`` as N coordinated processes (analog: mpirun -np N).
+    """Run ``worker_argv`` as N coordinated CPU-emulation processes (analog:
+    mpirun -np N); each worker gets ``local_device_count`` virtual CPU
+    devices and never a chip.
 
     Children read the ``FEDML_TPU_*`` env contract and call
     :func:`initialize` (no args) before touching jax. Returns the completed

@@ -87,23 +87,11 @@ def make_mesh(
 
 
 def compat_shard_map(fn, mesh, in_specs, out_specs):
-    """shard_map across jax versions (check_rep/check_vma kwarg churn).
-
-    The single compat point — pipeline, attention kernels, and ring
-    attention all wrap through here so a jax upgrade breaks zero or all of
-    them, never one.
-    """
-    try:
-        from jax import shard_map as _sm
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map as _sm
-    for kw in ({"check_vma": False}, {"check_rep": False}, {}):
-        try:
-            return _sm(fn, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_specs, **kw)
-        except TypeError:
-            continue
-    raise RuntimeError("no compatible shard_map signature")
+    """``jax.shard_map`` without the varying-manual-axes check (the Pallas
+    kernels and the hand-scheduled ring/pipeline bodies do not annotate
+    it). Pipeline, attention kernels and ring attention all wrap here."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def batch_mesh_axes(mesh: Mesh) -> Tuple[str, ...]:

@@ -10,8 +10,8 @@ hand-written NCCL calls to port.
 
 from __future__ import annotations
 
+import contextlib
 import logging
-from functools import partial
 from typing import Any, Optional, Tuple
 
 import jax
@@ -20,6 +20,7 @@ import optax
 from flax import struct
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from .context import mesh_context, sequence_parallelism
 from .sharding import batch_sharding, param_shardings, replicated, unbox
 from .transformer import Transformer, TransformerConfig
 
@@ -292,17 +293,27 @@ class CheetahTrainer:
             shard = self._batch_shard
         return jax.device_put(tokens, shard), jax.device_put(mask, shard)
 
+    @contextlib.contextmanager
+    def _trace_context(self):
+        """The contexts a step must be traced under: the mesh context lets
+        the attention kernels shard_map themselves (Mosaic kernels cannot be
+        auto-partitioned by pjit); the sequence context routes attention
+        through the ring."""
+        with contextlib.ExitStack() as stack:
+            stack.enter_context(self.mesh)
+            stack.enter_context(mesh_context(self.mesh))
+            if self.seq_sharded:
+                stack.enter_context(sequence_parallelism(self.mesh))
+            yield
+
     def train_step(self, state: TrainState, tokens, mask) -> Tuple[TrainState, dict]:
-        from .context import mesh_context
-
         tokens, mask = self.shard_batch(tokens, mask)
-        if self.seq_sharded:
-            from .context import sequence_parallelism
-
-            with self.mesh, mesh_context(self.mesh), \
-                    sequence_parallelism(self.mesh):
-                return self._step_jit(state, tokens, mask)
-        # mesh context lets the attention kernels shard_map themselves
-        # (Mosaic kernels cannot be auto-partitioned by pjit)
-        with self.mesh, mesh_context(self.mesh):
+        with self._trace_context():
             return self._step_jit(state, tokens, mask)
+
+    def lower_step(self, state: TrainState, tokens, mask):
+        """The step lowered (not compiled) exactly as ``train_step`` traces
+        it — for inspecting what the program contains (``.as_text()``)."""
+        tokens, mask = self.shard_batch(tokens, mask)
+        with self._trace_context():
+            return self._step_jit.lower(state, tokens, mask)

@@ -64,12 +64,9 @@ class TransformerConfig:
     moe_top_k: int = 1
     moe_capacity_factor: float = 1.25
     moe_aux_weight: float = 0.01
-    # "auto": Pallas splash attention on TPU (falls back to flash, then XLA),
-    # elsewhere XLA. "splash" / "flash" / "xla" force one. The Pallas kernels
-    # keep the [L, L] score matrix in VMEM tiles (never materialised in HBM)
-    # — measured on the v5e, splash beats the older flash kernel by 5-10x on
-    # fwd+bwd and its backward avoids flash's f32 [B,H,L,128] broadcasts,
-    # which is what keeps the no-remat memory rung viable.
+    # "auto": Pallas splash attention on TPU, XLA elsewhere. "splash" /
+    # "xla" force one. The Pallas kernel keeps the [L, L] score matrix in
+    # VMEM tiles (never materialised in HBM).
     attn_impl: str = "auto"
     # splash kernel tile sizes (None = kernel defaults). The q/kv block pair
     # is the main lever for small head_dim: at hd 128 the defaults leave the
@@ -141,23 +138,14 @@ def apply_rotary(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
 
 
 def _attn_backend(impl: str) -> str:
-    """Resolve cfg.attn_impl to one of {"splash", "flash", "xla"}."""
-    if impl in ("splash", "flash", "xla"):
+    """Resolve cfg.attn_impl to "splash" or "xla". On a TPU "auto" is
+    splash or an error — a back-end that fails to start must not turn into
+    the XLA path."""
+    if impl in ("splash", "xla"):
         return impl
-    import jax as _jax
-
-    try:
-        on_tpu = _jax.devices()[0].platform == "tpu"
-    except Exception:
-        on_tpu = False
-    if not on_tpu:
-        return "xla"
-    try:
-        import jax.experimental.pallas.ops.tpu.splash_attention  # noqa: F401
-
-        return "splash"
-    except ImportError:
-        return "flash"
+    if impl != "auto":
+        raise ValueError(f"attn_impl must be auto|splash|xla, got {impl!r}")
+    return "splash" if jax.devices()[0].platform == "tpu" else "xla"
 
 
 def _splash_blocks(L: int, block_q: int, block_kv: int, head_dim: int):
@@ -252,26 +240,6 @@ def splash_attention_tpu(q: jax.Array, k: jax.Array, v: jax.Array,
     return out.reshape(B, H, L, D).swapaxes(1, 2)
 
 
-def flash_attention_tpu(
-    q: jax.Array, k: jax.Array, v: jax.Array, causal: bool = True
-) -> jax.Array:
-    """Flash attention via the Pallas TPU kernel.
-
-    q/k/v: [B, L, H, D] (Hkv already expanded for GQA) → out [B, L, H, D].
-    The kernel wants [B, H, L, D]; blocks stream through VMEM so the [L, L]
-    score matrix never hits HBM — replaces the XLA path's fp32
-    ``bhlm`` logits tensor (the single biggest HBM consumer at long L).
-    """
-    from jax.experimental.pallas.ops.tpu.flash_attention import (
-        flash_attention as _flash,
-    )
-
-    D = q.shape[-1]
-    qt, kt, vt = (x.swapaxes(1, 2) for x in (q, k, v))
-    out = _flash(qt, kt, vt, causal=causal, sm_scale=float(1.0 / D ** 0.5))
-    return out.swapaxes(1, 2)
-
-
 def _constrain_batch_activations(x: jax.Array) -> jax.Array:
     """Pin [B, L, D] activations to the canonical batch sharding.
 
@@ -335,7 +303,7 @@ def _shard_attn_kernel(fn, q, k, v):
     """Run a Pallas attention kernel under the ambient mesh via shard_map.
 
     pjit cannot partition Mosaic kernels automatically — without this, the
-    splash/flash paths fail to lower whenever the step is jitted over a
+    splash path fails to lower whenever the step is jitted over a
     multi-device mesh (the exact program every fsdp/tp pod runs). Specs are
     the Megatron layout: batch over (data, fsdp), heads over tensor, full
     sequence per shard (the sequence-sharded path uses ring attention
@@ -472,25 +440,19 @@ class Attention(nn.Module):
             )(q, k, v)
         elif (
             mask is None and L >= 128 and L % 128 == 0
-            and _attn_backend(cfg.attn_impl) != "xla"
+            and _attn_backend(cfg.attn_impl) == "splash"
         ):
-            if _attn_backend(cfg.attn_impl) == "splash":
-                # GQA handled natively by the kernel — no K/V expand
-                from functools import partial
+            # GQA handled natively by the kernel — no K/V expand
+            from functools import partial
 
-                out = _shard_attn_kernel(
-                    partial(
-                        splash_attention_tpu,
-                        block_q=cfg.attn_block_q, block_kv=cfg.attn_block_kv,
-                        causal=cfg.causal,
-                    ),
-                    q, k, v,
-                )
-            else:
-                k, v = expand_gqa(k, v, H)
-                out = _shard_attn_kernel(
-                    partial(flash_attention_tpu, causal=cfg.causal), q, k, v
-                )
+            out = _shard_attn_kernel(
+                partial(
+                    splash_attention_tpu,
+                    block_q=cfg.attn_block_q, block_kv=cfg.attn_block_kv,
+                    causal=cfg.causal,
+                ),
+                q, k, v,
+            )
         else:
             out = attention_scores(q, k, v, mask, causal=cfg.causal)
         out = out.reshape(B, L, H * hd)

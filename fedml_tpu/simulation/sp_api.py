@@ -84,13 +84,17 @@ class FedAvgAPI:
 
     @staticmethod
     def _hbm_budget() -> int:
-        try:
-            stats = jax.devices()[0].memory_stats() or {}
-            limit = int(stats.get("bytes_limit", 0))
-            if limit > 0:
-                return int(limit * 0.6)
-        except Exception:
-            pass
+        """60% of the device's memory limit. XLA:CPU reports no limit and
+        gets 4 GB; a TPU that reports none is an error, not a guess."""
+        dev = jax.devices()[0]
+        limit = int((dev.memory_stats() or {}).get("bytes_limit", 0))
+        if limit > 0:
+            return int(limit * 0.6)
+        if dev.platform == "tpu":
+            raise RuntimeError(
+                f"{dev} reports no bytes_limit in memory_stats(); cannot "
+                "size the HBM-resident dataset budget"
+            )
         return 4 * 1024**3
 
     def __init__(self, args, device, dataset, model, client_trainer=None,
@@ -170,6 +174,7 @@ class FedAvgAPI:
             raise ValueError(f"sp_cohort_impl must be vmap|map|auto, got {impl!r}")
         if impl == "map":
             logger.info("sp engine: lax.map cohort (conv-on-CPU fallback)")
+        self.cohort_impl = impl
         if self.fedsgd:
             fn = make_grad_fn(model, args, cap)
             if impl == "map":
@@ -221,7 +226,7 @@ class FedAvgAPI:
         # cohorts there — no per-round host→device transfer. Falls back to
         # host-side gather for datasets too large for HBM. The budget is
         # queried from the device (60% of its memory limit, leaving room for
-        # params/grads/cohort working set); 4 GB if the backend reports none.
+        # params/grads/cohort working set); 4 GB on XLA:CPU, which reports none.
         total_bytes = self.ds.train_x.nbytes + self.ds.train_y.nbytes
         self.hbm_resident = self.hbm_resident_default and bool(
             getattr(args, "hbm_resident", total_bytes < self._hbm_budget())

@@ -505,8 +505,9 @@ class ProcSpawner:
         self.procs: List[subprocess.Popen] = []
 
     def spawn(self, cmd: List[str]) -> subprocess.Popen:
-        env = dict(os.environ,
-                   JAX_PLATFORMS=os.environ.get("JAX_PLATFORMS", "cpu"))
+        # workers simulate devices: pinned to the CPU so they never fight
+        # the parent for a chip (a chip belongs to one process)
+        env = dict(os.environ, JAX_PLATFORMS="cpu")
         proc = subprocess.Popen(cmd, cwd=self.cwd, env=env)
         self.procs.append(proc)
         return proc

@@ -175,7 +175,7 @@ def test_cache_dropped_on_device_kind_mismatch(partial_path, capsys):
     assert len(calls) == 2 * n
     assert "fedavg_cached" not in final
 
-    # unknown kind (wedged tunnel — the insurance case) accepts the cache
+    # unknown kind (wedged backend — the insurance case) accepts the cache
     calls.clear()
     bench.run_legs(budget_s=1e6, ttl_s=1e6, runner=runner,
                    device_prober=lambda: None)
@@ -256,9 +256,9 @@ def test_fedavg_compile_fields_pass_through(partial_path, capsys):
     assert res["fedavg_compile_s"] == 1.5 and res["fedavg_round_fused"] is True
 
 
-def test_unreachable_tunnel_fails_fast_with_parseable_tail(partial_path,
-                                                           capsys):
-    """Tunnel down (probe fails FAST with an error) + empty cache: legs
+def test_unreachable_backend_fails_fast_with_parseable_tail(partial_path,
+                                                            capsys):
+    """Backend down (probe fails FAST with an error) + empty cache: legs
     shrink to the fast-fail timeout and the startup line already carries
     the probe verdict. A probe TIMEOUT must NOT shrink (a slow-but-healthy
     host can blow the probe budget and still serve 900s legs)."""

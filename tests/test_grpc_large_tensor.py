@@ -128,7 +128,7 @@ def test_raw_frames_roundtrip_and_sniffing():
 def test_streamed_payload_past_cap_is_resource_exhausted(monkeypatch):
     """The stream handler must bound reassembly at MAX_MESSAGE_BYTES like
     the unary path does — an over-cap stream aborts RESOURCE_EXHAUSTED
-    instead of growing server memory without limit (ADVICE.md)."""
+    instead of growing server memory without limit."""
     from fedml_tpu.core.distributed import grpc_backend
 
     base = _free_consecutive_ports(4)

@@ -352,9 +352,9 @@ def test_reproduce_baselines_harness_fixture_run(tmp_path):
     def run(*argv):
         p = subprocess.run(
             [sys.executable, os.path.join(repo, "tools",
-                                          "reproduce_baselines.py"),
-             "--platform", "cpu", *argv],
+                                          "reproduce_baselines.py"), *argv],
             capture_output=True, text=True, timeout=540,
+            env=dict(os.environ, JAX_PLATFORMS="cpu"),
         )
         assert p.returncode == 0, p.stderr[-800:]
         return json.loads(p.stdout.strip().splitlines()[-1])

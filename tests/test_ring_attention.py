@@ -7,10 +7,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from fedml_tpu.parallel.ring_attention import make_ring_attention
+from fedml_tpu.parallel.sharding import compat_shard_map as shard_map
 from fedml_tpu.parallel.sharding import make_mesh
 from fedml_tpu.parallel.train_step import CheetahTrainer, make_optimizer
 from fedml_tpu.parallel.transformer import TransformerConfig, attention_scores
@@ -32,7 +32,7 @@ class TestRingAttentionExactness:
         spec = P(None, "sequence", None, None)
         ring_fn = shard_map(
             make_ring_attention(ring, "sequence"), mesh=mesh,
-            in_specs=(spec, spec, spec), out_specs=spec, check_rep=False,
+            in_specs=(spec, spec, spec), out_specs=spec,
         )
         out = jax.jit(ring_fn)(q, k, v)
         np.testing.assert_allclose(np.asarray(out), np.asarray(dense),
@@ -51,7 +51,7 @@ class TestRingAttentionExactness:
         spec = P(None, "sequence", None, None)
         ring_fn = shard_map(
             make_ring_attention(4, "sequence", causal=False), mesh=mesh,
-            in_specs=(spec, spec, spec), out_specs=spec, check_rep=False,
+            in_specs=(spec, spec, spec), out_specs=spec,
         )
         out = jax.jit(ring_fn)(q, k, v)
         np.testing.assert_allclose(np.asarray(out), np.asarray(dense),
@@ -126,7 +126,6 @@ class TestRingBackwardExactness:
         ring_fn = shard_map(
             make_ring_attention(ring, "sequence", causal=causal),
             mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-            check_rep=False,
         )
 
         def ring_loss(q, k, v):
@@ -165,7 +164,6 @@ class TestRingKernelPathInterpret:
                     block_q=128, block_kv=128, interpret=use_kernel,
                 ),
                 mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-                check_rep=False,
             )
 
             def loss(q, k, v):

@@ -533,7 +533,7 @@ class TestRegistryResume:
 
 
 # ---------------------------------------------------------------------------
-# 7. wire-format satellites (ADVICE.md): frame validation + array contract
+# 7. wire-format satellites: frame validation + array contract
 # ---------------------------------------------------------------------------
 
 

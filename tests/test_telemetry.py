@@ -256,6 +256,27 @@ class TestMetricsRegistry:
         assert summary[0]["metrics"]["counters"]["rounds.total"] == 4.0
 
 
+class TestPeakFlops:
+    """One peak table, keyed by device_kind: known kinds resolve, an unknown
+    TPU kind raises, and off-TPU there is no peak (MFU "not measured")."""
+
+    @staticmethod
+    def _dev(platform, kind):
+        import types
+
+        return types.SimpleNamespace(platform=platform, device_kind=kind)
+
+    def test_known_unknown_and_cpu(self):
+        assert telemetry.peak_bf16_flops(
+            self._dev("tpu", "TPU v5 lite")) == 197e12
+        with pytest.raises(KeyError, match="TPU v99"):
+            telemetry.peak_bf16_flops(self._dev("tpu", "TPU v99"))
+        assert telemetry.peak_bf16_flops(jax.devices()[0]) is None
+        # 100k tokens/s at 3e9 FLOPs/token on one v5e
+        assert telemetry.mfu_estimate(1e5, 3e9, 197e12) == pytest.approx(
+            3e14 / 197e12)
+
+
 class TestCommCounters:
     def test_payload_store_counts_puts_hits_gets(self, tmp_path):
         from fedml_tpu.core.distributed.payload_store import PayloadStore

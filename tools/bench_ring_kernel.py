@@ -31,14 +31,6 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
 
-def _sync(x):
-    import numpy as np
-
-    import jax
-
-    return float(np.asarray(jax.tree.leaves(x)[0]).ravel()[0])
-
-
 def measure_inner(B, Lb, H, D, steps, interpret=False) -> dict:
     """Einsum vs kernel inner engines at one block shape + grad parity."""
     import numpy as np
@@ -75,12 +67,11 @@ def measure_inner(B, Lb, H, D, steps, interpret=False) -> dict:
             )(q, k, v)
 
         def timeit(f):
-            r = f(q, k, v)
-            _sync(r)
+            r = jax.block_until_ready(f(q, k, v))
             t0 = time.perf_counter()
             for _ in range(steps):
                 r = f(q, k, v)
-            _sync(r)
+            jax.block_until_ready(r)
             return (time.perf_counter() - t0) / steps, r
 
         dt_f, _ = timeit(fwd)
@@ -160,7 +151,7 @@ def measure_train_step(seq, batch, steps, smoke=False) -> dict:
             try:
                 state = tr.init_state(jax.random.PRNGKey(0))
                 state, m = tr.train_step(state, tok, mask)
-                _sync(m["loss"])
+                jax.block_until_ready(m)
             except Exception as e:
                 last = f"{type(e).__name__}: {e}"[:300]
                 state = tr = None
@@ -171,21 +162,21 @@ def measure_train_step(seq, batch, steps, smoke=False) -> dict:
         n_params = sum(int(p.size) for p in jax.tree.leaves(state.params))
         for _ in range(2):
             state, m = tr.train_step(state, tok, mask)
-        _sync(m["loss"])
+        jax.block_until_ready(m)
         t0 = time.perf_counter()
         for _ in range(steps):
             state, m = tr.train_step(state, tok, mask)
-        _sync(m["loss"])
+        jax.block_until_ready(m)
         dt = (time.perf_counter() - t0) / steps
         tok_s = batch * seq / dt
         res = {"train_step_ms": round(dt * 1e3, 1),
                "tokens_per_sec": round(tok_s),
                "remat": cfg.remat_policy if cfg.remat else "none",
                "loss": round(float(m["loss"]), 4)}
-        from bench import TPU_PEAK_FLOPS
+        from fedml_tpu.core.mlops.telemetry import peak_bf16_flops
 
-        peak = TPU_PEAK_FLOPS.get(jax.devices()[0].device_kind)
-        if peak:
+        peak = peak_bf16_flops(jax.devices()[0])
+        if peak is not None:
             fpt = 6.0 * n_params + 12.0 * seq * cfg.n_layers * cfg.d_model
             res["mfu"] = round(tok_s * fpt / peak, 4)
         return res
@@ -215,9 +206,6 @@ def main() -> None:
                                                   "RING_KERNEL_BENCH.json"))
     a = ap.parse_args()
 
-    from bench import _maybe_force_platform
-
-    _maybe_force_platform()
     import jax
 
     on_tpu = jax.devices()[0].platform == "tpu"

@@ -19,7 +19,7 @@ track_dir=$(mktemp -d /tmp/fedml_bench_smoke_track.XXXXXX)
 trap 'rm -rf "$track_dir"' EXIT
 
 out=$(timeout -k 10 240 env \
-    BENCH_PLATFORM=cpu \
+    JAX_PLATFORMS=cpu \
     BENCH_SMOKE=1 \
     BENCH_LEGS=fedavg,fedavg_million_client,fedavg_compressed_round,fedavg_wire \
     BENCH_REGISTRY_N=20000 \

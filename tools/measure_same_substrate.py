@@ -54,11 +54,10 @@ def measure_ours(model: str, rounds: int) -> float:
 
     jax.config.update("jax_platforms", "cpu")
     # persistent compile cache: XLA:CPU compiles of conv models take tens
-    # of minutes on this one-core host; pay once (same dir as conftest)
-    cache = os.environ.get("JAX_COMPILATION_CACHE_DIR",
-                           "/tmp/fedml_tpu_jax_cache")
-    os.makedirs(cache, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", cache)
+    # of minutes; pay once (same dir as conftest)
+    from fedml_tpu.device import enable_compilation_cache
+
+    enable_compilation_cache()
 
     import numpy as np
 

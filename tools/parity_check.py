@@ -537,12 +537,10 @@ def main():
         # float-comparable to torch CPU for the exact-trajectory legs
         jax.config.update("jax_platforms", "cpu")
     # persistent compile cache: the ResNet-56 leg's XLA:CPU compile is many
-    # minutes on one core; pay it once (same cache the test suite uses)
-    cache = os.environ.get("JAX_COMPILATION_CACHE_DIR",
-                           "/tmp/fedml_tpu_jax_cache")
-    os.makedirs(cache, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", cache)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+    # minutes; pay it once (same cache the test suite uses)
+    from fedml_tpu.device import enable_compilation_cache
+
+    enable_compilation_cache()
 
     fed = make_federation(n_clients=a.clients)
     init = _torch_linear_init(seed=0)
