@@ -1,0 +1,10 @@
+"""Idle share of the device over the traced window, in percent: 1 minus the
+union of the ``XLA Ops`` intervals over the span from the first to the last
+device op, on the chip that idles most. The traced loop runs with the
+program's tracking on. Layer: device. Moves ``tokens_per_s_per_chip``."""
+
+from benchmark import trace_reduce as tr
+
+
+def read(run):
+    return None if run.trace is None else 100.0 * tr.idle_share(run.trace)
