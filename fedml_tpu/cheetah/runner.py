@@ -190,52 +190,66 @@ class CheetahRunner:
         every = int(getattr(self.args, "checkpoint_every_rounds", 0) or 0)
         # per-step telemetry denominators (the Cheetah "round" is a step):
         # model FLOPs/token for the live MFU gauge, chip peak by device kind
-        n_params = sum(int(p.size) for p in jax.tree.leaves(state.params))
+        cfg = self.cfg
         flops_tok = telemetry.flops_per_token(
-            n_params, self.seq_len, self.cfg.n_layers, self.cfg.d_model
+            cfg.d_model, cfg.n_layers, cfg.n_heads, cfg.n_kv_heads, cfg.d_ff,
+            cfg.vocab_size, self.seq_len,
         )
         # None off-TPU (MFU "not measured"); an unknown TPU kind raises
         peak = telemetry.peak_bf16_flops(jax.devices()[0])
         n_chips = jax.device_count()
         for step in range(start_step, self.total_steps):
-            telemetry.on_round_start(step)
-            rec = telemetry.begin_round(step)
+            # every statement of an iteration lies in a telemetry span:
+            # tracked, the spans tile the step (docs/telemetry.md)
+            with telemetry.phase("hooks"):
+                telemetry.on_round_start(step)
+                rec = telemetry.begin_round(step, unit="step")
             with telemetry.phase("data"):
                 tokens = next(gen)
                 mask = np.ones_like(tokens)
+            with telemetry.phase("h2d"):
+                tokens_dev, mask_dev = jnp.asarray(tokens), jnp.asarray(mask)
+            t_dispatch = time.perf_counter()
             with telemetry.phase("step"):
                 state, metrics = self.trainer.train_step(
-                    state, jnp.asarray(tokens), jnp.asarray(mask)
+                    state, tokens_dev, mask_dev
                 )
             with telemetry.phase("loss_sync"):
                 losses.append(float(metrics["loss"]))
-            tokens_done += tokens.size
-            if rec is not None:
-                rec.lazy["examples"] = tokens.size
-            telemetry.end_round(rec, train_loss=losses[-1])
-            if rec is not None and rec.wall_s > 0:
-                tps = tokens.size / rec.wall_s
-                telemetry.gauge_set("cheetah.tokens_per_sec", tps)
-                if peak is not None:
-                    telemetry.gauge_set(
-                        "cheetah.mfu_estimate",
-                        telemetry.mfu_estimate(tps, flops_tok, peak, n_chips),
-                    )
-            telemetry.on_round_end(step)
-            if every and (step + 1) % every == 0 and self.checkpoint_dir:
-                ckpt.save(state)
-            if guard is not None and guard.requested() \
-                    and step + 1 < self.total_steps:
-                from ..core.runstate import PreemptionError
-
-                # drain commit: this step completed — persist it NOW (even
-                # off the checkpoint cadence) so the restart resumes at
-                # exactly step + 1 instead of re-training the window
-                if ckpt.latest_step() != int(state.step):
+            with telemetry.phase("record"):
+                tokens_done += tokens.size
+                if rec is not None:
+                    rec.dispatch_latency_s = time.perf_counter() - t_dispatch
+                    rec.lazy["examples"] = tokens.size
+                telemetry.end_round(rec, train_loss=losses[-1])
+                if rec is not None and rec.wall_s > 0:
+                    tps = tokens.size / rec.wall_s
+                    telemetry.gauge_set("cheetah.tokens_per_sec", tps)
+                    if peak is not None:
+                        telemetry.gauge_set(
+                            "cheetah.mfu_estimate",
+                            telemetry.mfu_estimate(tps, flops_tok, peak,
+                                                   n_chips),
+                        )
+                telemetry.on_round_end(step)
+            with telemetry.phase("checkpoint"):
+                if every and (step + 1) % every == 0 and self.checkpoint_dir:
                     ckpt.save(state)
-                ckpt.close()
-                telemetry.counter_inc("run.preemptions")
-                raise PreemptionError(step)
+                if guard is not None and guard.requested() \
+                        and step + 1 < self.total_steps:
+                    from ..core.runstate import PreemptionError
+
+                    # drain commit: this step completed — persist it NOW
+                    # (even off the checkpoint cadence) so the restart
+                    # resumes at exactly step + 1 instead of re-training the
+                    # window
+                    if ckpt.latest_step() != int(state.step):
+                        ckpt.save(state)
+                    ckpt.close()
+                    telemetry.counter_inc("run.preemptions")
+                    telemetry.drain_records()
+                    raise PreemptionError(step)
+        telemetry.drain_records()  # every step's record is in the sink
         jax.block_until_ready(state.params)
         dt = time.perf_counter() - t0
         tps = tokens_done / max(dt, 1e-9)

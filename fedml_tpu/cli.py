@@ -128,9 +128,10 @@ def cmd_top(args) -> int:
         return 1
 
     phases = {}
-    # dispatch→ready latency overlaps the dispatch+device_wait spans, so it
-    # stays OUT of the phase table (whose % wall must not double-count) and
-    # is summarised separately below
+    # dispatch→loss latency (the loops that wait: sp unfused, Cheetah)
+    # overlaps their train/step + loss_sync spans, so it stays OUT of the
+    # phase table (whose % wall must not double-count) and is summarised
+    # separately below
     dispatch_lat = []
     for r in records:
         for name, dur in (r.get("phases") or {}).items():

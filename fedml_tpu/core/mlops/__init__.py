@@ -8,8 +8,8 @@ reference: ``core/mlops/`` (2,217 LoC) — MLOpsProfilerEvent emitting
 
 TPU re-design: the platform plane (open.fedml.ai MQTT/HTTP agents) is
 replaced by pluggable local sinks — python logging, a JSONL event file, and
-wandb when importable — plus ``jax.profiler`` trace capture for device-level
-profiling. Event names used by the runtimes are kept from the reference
+wandb when importable — plus ``--profile_rounds`` windows of the
+``jax.profiler`` (``telemetry.ProfilerWindow``) for device-level profiling. Event names used by the runtimes are kept from the reference
 (train / agg / comm_c2s / server.wait) so dashboards translate 1:1.
 """
 
@@ -129,7 +129,11 @@ def close() -> None:
 
 def flush() -> None:
     """Drain the write-behind buffer to disk now (shutdown paths, readers
-    of the live file, and the flight recorder's post-mortem flush)."""
+    of the live file, and the flight recorder's post-mortem flush). Round
+    records still waiting for their device scalars are realized first."""
+    from . import telemetry
+
+    telemetry.drain_records()
     with MLOpsStore._sink_lock:
         _flush_locked()
 
@@ -261,14 +265,6 @@ class MLOpsProfilerEvent:
         event(self.name, event_started=False,
               event_value=f"{time.perf_counter() - self.t0:.6f}s")
         return False
-
-
-def profile_trace(log_dir: str):
-    """Device-level profiling: jax.profiler trace context (the TPU-native
-    replacement for the reference's wandb latency spans)."""
-    import jax
-
-    return jax.profiler.trace(log_dir)
 
 
 def read_events(path: Optional[str] = None) -> List[Dict[str, Any]]:

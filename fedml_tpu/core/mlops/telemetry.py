@@ -5,8 +5,8 @@ MLOpsMetrics, SysStats) whose unit of observation is a *message* — fine for
 an actor federation, blind for this port where PR 1 collapsed a whole FedAvg
 round into one donated XLA dispatch. The unit of observation here is the
 **round** (or the Cheetah step): where inside it time goes (sample / gather /
-train / aggregate / device wait), how long dispatch→ready takes on the fused
-path, how HBM grows, and how often XLA recompiles.
+prep / dispatch, or data / h2d / step / loss_sync), how many rounds the host
+runs ahead of the device, how HBM grows, and how often XLA recompiles.
 
 Three layers, all process-wide:
 
@@ -16,24 +16,29 @@ Three layers, all process-wide:
   run is tracked). Rendered as Prometheus text exposition to
   ``--metrics_file``.
 - **RoundRecord** — one structured JSONL event per round: phase span
-  durations, dispatch→``block_until_ready`` latency (fused path), HBM
-  used/peak from :func:`device_stats`, examples processed, a rounds/s EMA,
-  and compile events (via ``jax.monitoring`` listeners, which also count
-  persistent-compilation-cache hits/misses).
+  durations, the spans themselves on the profiler's clock (``spans``: name,
+  ``ts_ns``, ``dur_ns``, ``parent``), HBM used/peak from
+  :func:`device_stats`, examples processed, a rounds/s EMA, and compile
+  events (via ``jax.monitoring`` listeners, which also count
+  persistent-compilation-cache hits/misses and name each compiled program
+  in a ``compile`` event).
 - **Profiler windows** — ``--profile_rounds N:M`` opens a ``jax.profiler``
   trace for rounds [N, M) and closes it after, no code changes in the run.
 
 Zero-cost contract: with tracking disabled, :func:`begin_round` returns
-``None`` after one boolean check, :func:`phase` returns a shared no-op
-context manager, and the fused round path performs NO extra host sync
-(``block_until_ready`` only runs under an active record) — pinned by
-``tests/test_telemetry.py``.
+``None`` after one boolean check and :func:`phase` returns a shared no-op
+context manager. Tracking on or off, the fused round path performs NO host
+sync: a closed record waits on a pending queue and its device scalars are
+read only once ``is_ready()`` says they are there (or at the loop's end) —
+pinned by ``tests/test_telemetry.py``.
 """
 
 from __future__ import annotations
 
 import bisect
+import collections
 import dataclasses
+import itertools
 import threading
 import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
@@ -319,17 +324,30 @@ class _State:
     metrics_file: Optional[str] = None
     profiler: Optional["ProfilerWindow"] = None
     ema_rounds_per_sec: Optional[float] = None
+    # (perf_counter, rounds opened) of the last begin_round: the EMA's period
+    last_begin: Optional[Tuple[float, int]] = None
     last_metrics_write: float = 0.0
     metrics_write_interval_s: float = 2.0
 
 
-_TLS = threading.local()  # .record — the in-flight RoundRecord, if any
+# per thread: .record — the in-flight RoundRecord, if any; .last — the record
+# that closed last, which still takes the spans that run between rounds;
+# .stack — the open spans, innermost last
+_TLS = threading.local()
 
 # guards _State's mutable run-state (EMA, metrics-file throttle) and the
 # metrics tmp-file write: cross-silo rounds close on a comm receive thread
 # while close()/atexit and the sys-perf sampler touch the same state
 # (graftlint G005)
 _STATE_LOCK = threading.Lock()
+
+# closed records whose device scalars are not realized yet, oldest first.
+# ``deque`` appends and pops are atomic; _DRAIN_LOCK keeps one drainer at a
+# time so that records reach the sink in round order
+_PENDING: "collections.deque[RoundRecord]" = collections.deque()
+_DRAIN_LOCK = threading.RLock()
+
+_SPAN_IDS = itertools.count(1)
 
 
 def enabled() -> bool:
@@ -344,10 +362,13 @@ def set_enabled(flag: bool) -> None:
 def init(args) -> None:
     """Configure the plane from a run's args (called by ``mlops.init``)."""
     _State.enabled = bool(getattr(args, "enable_tracking", False))
+    drain_records()  # what an earlier run in this process left pending
     _State.metrics_file = str(getattr(args, "metrics_file", "") or "") or None
     _State.ema_rounds_per_sec = None
+    _State.last_begin = None
     _State.last_metrics_write = 0.0
     _TLS.record = None
+    _TLS.last = None
     spec = str(getattr(args, "profile_rounds", "") or "")
     if spec:
         log_dir = (str(getattr(args, "profile_dir", "") or "")
@@ -368,6 +389,7 @@ def close() -> None:
     prof = _State.profiler
     if prof is not None and prof.active:
         prof.force_stop()
+    drain_records()
     if _State.enabled:
         from . import _emit
 
@@ -436,6 +458,8 @@ def install_jax_listeners() -> bool:
         if event == "/jax/core/compile/backend_compile_duration":
             _REG.inc("jax.compiles")
             _REG.observe("jax.compile.seconds", duration_secs)
+            if _State.enabled:
+                _emit_compile(str(kw.get("fun_name", "")), duration_secs)
         elif event == "/jax/compilation_cache/compile_time_saved_sec":
             _REG.inc("jax.compilation_cache.time_saved_s", duration_secs)
 
@@ -446,6 +470,30 @@ def install_jax_listeners() -> bool:
         monitoring.register_event_duration_secs_listener(on_duration)
         _LISTENERS_INSTALLED = True
     return True
+
+
+_COMPILE_SEEN = {"hits": 0.0, "misses": 0.0}
+
+
+def _emit_compile(fun_name: str, seconds: float) -> None:
+    """One ``compile`` event per backend compile: the program's name as JAX
+    passes it to the listener (``jit(_train_step_raw)``), its seconds, and
+    how far the persistent cache's counters moved since the previous compile,
+    so a recompile or a cache miss names its program."""
+    from . import _emit
+
+    hits = _REG.counter("jax.compilation_cache.hits")
+    misses = _REG.counter("jax.compilation_cache.misses")
+    with _STATE_LOCK:
+        seen = _COMPILE_SEEN
+        # a registry reset (tests) starts the counters again from zero
+        d_hits = hits - (seen["hits"] if hits >= seen["hits"] else 0.0)
+        d_misses = misses - (seen["misses"] if misses >= seen["misses"]
+                             else 0.0)
+        seen["hits"], seen["misses"] = hits, misses
+    _emit({"kind": "compile", "fun_name": fun_name,
+           "seconds": round(float(seconds), 6),
+           "cache_hits": int(d_hits), "cache_misses": int(d_misses)})
 
 
 # ---------------------------------------------------------------------------
@@ -466,37 +514,79 @@ class _NullSpan:
 _NULL_SPAN = _NullSpan()
 
 
+def _span_stack() -> list:
+    stack = getattr(_TLS, "stack", None)
+    if stack is None:
+        stack = _TLS.stack = []
+    return stack
+
+
 class _Span:
-    __slots__ = ("name", "t0", "record")
+    """One open span: a duration for ``phases`` and the histogram, a
+    ``spans`` entry on the epoch clock (the clock of the xplane's
+    ``profile_start_time``), and a ``TraceAnnotation`` so that a
+    ``--profile_rounds`` trace shows it beside the device lines."""
+
+    __slots__ = ("name", "record", "span_id", "parent", "ts_ns", "t0_ns",
+                 "_annotation")
 
     def __init__(self, name: str, record: bool = True):
         self.name = name
-        self.t0 = 0.0
         self.record = record
+        self.span_id = next(_SPAN_IDS)
+        self.parent: Optional[int] = None
+        self.ts_ns = self.t0_ns = 0
+        self._annotation = None
 
     def __enter__(self):
-        self.t0 = time.perf_counter()
+        import jax
+
+        stack = _span_stack()
+        self.parent = stack[-1].span_id if stack else None
+        stack.append(self)
+        rec = getattr(_TLS, "record", None) or getattr(_TLS, "last", None)
+        self._annotation = jax.profiler.TraceAnnotation(
+            self.name, unit=-1 if rec is None else rec.round_idx)
+        self._annotation.__enter__()
+        self.ts_ns = time.time_ns()
+        self.t0_ns = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc):
-        dt = time.perf_counter() - self.t0
-        if self.record:
-            rec = getattr(_TLS, "record", None)
-            if rec is not None:
-                rec.phases[self.name] = rec.phases.get(self.name, 0.0) + dt
-        _REG.observe(f"phase.{self.name}.seconds", dt)
+        dur_ns = time.perf_counter_ns() - self.t0_ns
+        self._annotation.__exit__(*exc)
+        stack = _span_stack()
+        if self in stack:
+            stack.remove(self)
+        rec = getattr(_TLS, "record", None)
+        if rec is not None:
+            if self.record:
+                rec.phases[self.name] = (rec.phases.get(self.name, 0.0)
+                                         + dur_ns * 1e-9)
+        else:
+            # between rounds (evaluation, logging, checkpoint): the record
+            # that closed last takes the span until the next one opens
+            rec = getattr(_TLS, "last", None)
+        if rec is not None and not rec.emitted:
+            rec.spans.append({"name": self.name, "span": self.span_id,
+                              "parent": self.parent, "ts_ns": self.ts_ns,
+                              "dur_ns": dur_ns})
+        _REG.observe(f"phase.{self.name}.seconds", dur_ns * 1e-9)
         return False
 
 
 def phase(name: str, record: bool = True):
-    """Span context manager: attributes its duration to the in-flight
-    RoundRecord (if any) and the ``phase.<name>.seconds`` histogram.
-    A shared no-op when tracking is disabled.
+    """Span context manager, the one way the loops open a span: its duration
+    goes to the in-flight RoundRecord's ``phases`` and to the
+    ``phase.<name>.seconds`` histogram, the span itself (``ts_ns``,
+    ``dur_ns``, ``parent``) to the record's ``spans``. A span that closes
+    while no record is open lands in the ``spans`` of the record that closed
+    last. A shared no-op when tracking is disabled.
 
-    ``record=False`` keeps the histogram but stays out of the RoundRecord —
-    for sub-spans nested inside a recorded phase (the mesh engine's
-    placement spans run inside the sp base's sample/prep spans), whose
-    double-counted time would push a record's phase sum past its wall."""
+    ``record=False`` keeps the span and the histogram but stays out of
+    ``phases`` — for sub-spans nested inside a recorded phase (the mesh
+    engine's placement spans run inside the sp base's sample/prep spans),
+    whose double-counted time would push a record's phase sum past its wall."""
     if not _State.enabled:
         return _NULL_SPAN
     return _Span(name, record)
@@ -515,27 +605,42 @@ class RoundRecord:
     fused: bool = False
     superround: bool = False
     phases: Dict[str, float] = dataclasses.field(default_factory=dict)
+    # every span that closed under this record or after it, before the next
+    # record opened: {name, span, parent, ts_ns (epoch), dur_ns}
+    spans: List[Dict[str, Any]] = dataclasses.field(default_factory=list)
     wall_s: float = 0.0
-    dispatch_latency_s: Optional[float] = None  # dispatch → block_until_ready
+    time: float = 0.0  # epoch seconds at end_round: time - wall_s is the start
+    # where the loop itself waits for the device (unfused path, Cheetah's
+    # loss_sync): dispatch → loss on the host. Null on the fused path
+    dispatch_latency_s: Optional[float] = None
+    # earlier rounds dispatched and not yet ready when this one opened
+    in_flight: int = 0
     examples: Optional[float] = None
     train_loss: Optional[float] = None
     rounds_per_sec_ema: Optional[float] = None
     hbm_used_mb: Optional[float] = None
     hbm_peak_mb: Optional[float] = None
     compiles: int = 0
-    # lazy device scalars realized at end_round (one sync, tracking-on only)
+    # device scalars realized when the record leaves the pending queue
     lazy: Dict[str, Any] = dataclasses.field(default_factory=dict)
-    t0: float = 0.0
+    emitted: bool = False
+    t0_ns: int = 0
     _compiles0: float = 0.0
+    _step_annotation: Any = None
 
     def to_event(self) -> Dict[str, Any]:
-        d = dataclasses.asdict(self)
-        d.pop("lazy", None)
-        d.pop("t0", None)
-        d.pop("_compiles0", None)
+        skip = ("lazy", "emitted", "t0_ns", "_compiles0", "_step_annotation")
+        d = {f.name: getattr(self, f.name) for f in dataclasses.fields(self)
+             if f.name not in skip}
         d["phases"] = {k: round(v, 6) for k, v in self.phases.items()}
+        d["spans"] = list(self.spans)
         d["wall_s"] = round(self.wall_s, 6)
         return {"kind": "round_record", **d}
+
+    def ready(self) -> bool:
+        """Are the record's device scalars on the host side already?"""
+        return all(getattr(v, "is_ready", lambda: True)()
+                   for v in self.lazy.values())
 
 
 def current_record() -> Optional[RoundRecord]:
@@ -543,33 +648,47 @@ def current_record() -> Optional[RoundRecord]:
 
 
 def record_lazy(name: str, value: Any) -> None:
-    """Stash a device scalar on the in-flight record; realized (ONE host
-    sync) at :func:`end_round`. No-op without an active record."""
+    """Stash a device scalar on the in-flight record; realized when the
+    record is emitted. No-op without an active record."""
     rec = getattr(_TLS, "record", None)
     if rec is not None:
         rec.lazy[name] = value
 
 
 def begin_round(round_idx: int, fused: bool = False,
-                superround: bool = False) -> Optional[RoundRecord]:
-    """Open a RoundRecord; ``None`` (after one bool check) when disabled."""
+                superround: bool = False,
+                unit: str = "round") -> Optional[RoundRecord]:
+    """Open a RoundRecord; ``None`` (after one bool check) when disabled.
+
+    Emits the earlier records whose device scalars are ready, in order, and
+    never waits for one. Called inside a span (the loops' ``hooks``), the
+    record starts where that span started, so the span lies within it. The
+    round or step runs under a ``StepTraceAnnotation`` named ``unit``."""
     if not _State.enabled:
         return None
+    import jax
+
+    now = time.perf_counter()
+    _TLS.last = None  # the previous record takes no more spans
+    drain_records(block=False)
     rec = RoundRecord(round_idx=int(round_idx), fused=fused,
                       superround=superround)
-    rec.t0 = time.perf_counter()
+    stack = _span_stack()
+    rec.t0_ns = stack[-1].t0_ns if stack else time.perf_counter_ns()
+    rec.in_flight = len(_PENDING)
     rec._compiles0 = _REG.counter("jax.compiles")
+    with _STATE_LOCK:  # read-modify-write shared with comm-thread rounds
+        if _State.last_begin is not None:
+            t_prev, units = _State.last_begin
+            rate, prev = units / max(now - t_prev, 1e-9), _State.ema_rounds_per_sec
+            _State.ema_rounds_per_sec = (
+                rate if prev is None else 0.9 * prev + 0.1 * rate)
+        _State.last_begin = (now, 1)
+    rec._step_annotation = jax.profiler.StepTraceAnnotation(
+        unit, step_num=rec.round_idx)
+    rec._step_annotation.__enter__()
     _TLS.record = rec
     return rec
-
-
-def _update_ema(inst_rounds_per_sec: float) -> float:
-    with _STATE_LOCK:  # read-modify-write shared with comm-thread rounds
-        prev = _State.ema_rounds_per_sec
-        ema = (inst_rounds_per_sec if prev is None
-               else 0.9 * prev + 0.1 * inst_rounds_per_sec)
-        _State.ema_rounds_per_sec = ema
-        return ema
 
 
 def _hbm_fields(rec: RoundRecord) -> None:
@@ -592,65 +711,99 @@ def _realize(value: Any) -> Optional[float]:
         return None
 
 
-def end_round(rec: Optional[RoundRecord],
-              train_loss: Any = None, wall_s: Optional[float] = None) -> None:
-    """Close a RoundRecord: realize lazy device scalars (the one host sync
-    tracking buys), stamp HBM + EMA + compile count, emit the JSONL event,
-    bump registry counters, and maybe refresh the metrics file."""
-    if rec is None:
-        return
-    from . import _emit
-
-    rec.wall_s = (time.perf_counter() - rec.t0) if wall_s is None else wall_s
-    rec.train_loss = _realize(train_loss if train_loss is not None
-                              else rec.lazy.get("train_loss"))
-    rec.examples = _realize(rec.lazy.get("examples"))
+def _stamp(rec: RoundRecord, wall_s: Optional[float] = None) -> None:
+    """The host side of closing a record; touches no device value."""
+    rec.wall_s = ((time.perf_counter_ns() - rec.t0_ns) * 1e-9
+                  if wall_s is None else wall_s)
+    rec.time = time.time()
+    if rec._step_annotation is not None:
+        rec._step_annotation.__exit__(None, None, None)
+        rec._step_annotation = None
     rec.compiles = int(_REG.counter("jax.compiles") - rec._compiles0)
-    rec.rounds_per_sec_ema = _update_ema(1.0 / max(rec.wall_s, 1e-9))
+    rec.rounds_per_sec_ema = _State.ema_rounds_per_sec
     _hbm_fields(rec)
     _TLS.record = None
+
+
+def end_round(rec: Optional[RoundRecord],
+              train_loss: Any = None, wall_s: Optional[float] = None) -> None:
+    """Close a RoundRecord on the host side: stamp wall, epoch time, HBM and
+    compile count, and queue it. No host sync: its device scalars
+    (``train_loss``, ``examples``) are realized, and the record emitted, by a
+    later :func:`begin_round` once they are ready, or by
+    :func:`drain_records` at the loop's end. Until the next record opens it
+    still takes the spans that close (evaluation, logging, checkpoint)."""
+    if rec is None:
+        return
+    _stamp(rec, wall_s)
+    if train_loss is not None:
+        rec.lazy["train_loss"] = train_loss
+    _TLS.last = rec
+    _PENDING.append(rec)
+
+
+def _emit_record(rec: RoundRecord) -> None:
+    from . import _emit
+
+    rec.train_loss = _realize(rec.lazy.get("train_loss"))
+    rec.examples = _realize(rec.lazy.get("examples"))
+    rec.lazy.clear()
+    rec.emitted = True
     _REG.inc("rounds.total")
     if rec.examples:
         _REG.inc("examples.total", rec.examples)
     _REG.observe("round.wall.seconds", rec.wall_s)
     _emit(rec.to_event())
+
+
+def drain_records(block: bool = True) -> None:
+    """Emit the pending records, oldest first. ``block=False`` stops at the
+    first whose device scalars are not ready (``begin_round``); the default
+    waits for each (the end of ``train()`` / ``run()``, ``mlops.flush()``,
+    ``close()``). A drained record takes no further spans."""
+    if not _PENDING:
+        return
+    with _DRAIN_LOCK:
+        while _PENDING and (block or _PENDING[0].ready()):
+            _emit_record(_PENDING.popleft())
+    if block:
+        with _STATE_LOCK:
+            _State.last_begin = None  # the next loop starts a new period
     write_metrics_file()
 
 
-def emit_superround(start_round: int, k: int, wall_s: float,
-                    scan_metrics: Dict[str, Any]) -> None:
-    """One RoundRecord per scanned round, unpacked host-side from the scan's
-    stacked per-round outputs (``train_loss[k]``, ``examples[k]``). The scan
-    is one device program, so per-round wall/phase attribution is the scan
-    wall divided evenly — honest about what a fused superround can know."""
-    if not _State.enabled:
+def end_superround(rec: Optional[RoundRecord], k: int,
+                   scan_metrics: Dict[str, Any]) -> None:
+    """Close the record of a K-round scan as K records, unpacked host-side
+    from the scan's stacked per-round outputs (``train_loss[k]``,
+    ``examples[k]``): the one wait a tracked scan adds. The scan is one device
+    program, so its wall is divided evenly over the rounds — honest about
+    what a fused superround can know. ``rec`` (opened at the scan's first
+    round) keeps the spans that closed under it; the last of the K takes the
+    spans that follow."""
+    if rec is None:
         return
     import numpy as np
 
-    from . import _emit
-
-    losses = np.asarray(scan_metrics.get("train_loss"))
+    losses = np.asarray(scan_metrics.get("train_loss"))  # waits for the scan
     ex = scan_metrics.get("examples")
     ex = None if ex is None else np.asarray(ex)
-    per = wall_s / max(k, 1)
-    hbm_probe = RoundRecord(round_idx=-1)
-    _hbm_fields(hbm_probe)
+    _stamp(rec)
+    per = rec.wall_s / max(k, 1)
     for j in range(k):
-        rec = RoundRecord(round_idx=start_round + j, fused=True,
-                          superround=True)
-        rec.wall_s = per
-        rec.phases = {"superround_scan": per}
-        rec.train_loss = float(losses[j]) if losses.shape else float(losses)
-        rec.examples = None if ex is None else float(ex[j])
-        rec.rounds_per_sec_ema = _update_ema(1.0 / max(per, 1e-9))
-        rec.hbm_used_mb = hbm_probe.hbm_used_mb
-        rec.hbm_peak_mb = hbm_probe.hbm_peak_mb
-        _REG.inc("rounds.total")
-        if rec.examples:
-            _REG.inc("examples.total", rec.examples)
-        _REG.observe("round.wall.seconds", per)
-        _emit(rec.to_event())
-    write_metrics_file()
+        r = rec if j == 0 else dataclasses.replace(
+            rec, round_idx=rec.round_idx + j, spans=[], in_flight=0,
+            compiles=0)
+        r.wall_s = per
+        r.time = rec.time - (k - 1 - j) * per
+        r.phases = {"superround_scan": per}
+        r.lazy = {"train_loss": float(losses[j] if losses.shape else losses),
+                  "examples": None if ex is None else float(ex[j])}
+        _PENDING.append(r)
+        _TLS.last = r
+    with _STATE_LOCK:
+        if _State.last_begin is not None:
+            _State.last_begin = (_State.last_begin[0], k)
 
 
 # ---------------------------------------------------------------------------
@@ -786,10 +939,22 @@ def start_sys_perf_sampler(args) -> Optional[SysPerfSampler]:
 # ---------------------------------------------------------------------------
 
 
-def flops_per_token(n_params: int, seq_len: int, n_layers: int,
-                    d_model: int) -> float:
-    """Model FLOPs per token, fwd+bwd (PaLM appendix B convention)."""
-    return 6.0 * n_params + 12.0 * seq_len * n_layers * d_model
+def flops_per_token(d_model: int, n_layers: int, n_heads: int,
+                    n_kv_heads: int, d_ff: int, vocab_size: int,
+                    seq_len: int) -> float:
+    """Model FLOPs per token, forward + backward, of a pre-norm GQA + SwiGLU
+    decoder: what ``benchmark/flops/transformer.py`` counts, from the shapes.
+    Per token forward, one multiply-add = 2 FLOPs: q, k, v, o projections,
+    gate, up, down, causal attention scores and values (a query sees
+    ``(seq_len + 1) / 2`` keys on average) and the output head. The embedding
+    table is a row gather and costs none; backward is twice the forward;
+    recomputation under remat is not counted."""
+    head_dim = d_model // n_heads
+    proj = 2 * d_model * head_dim * (2 * n_heads + 2 * n_kv_heads)
+    ffn = 2 * 3 * d_model * d_ff
+    attn = 2 * 2 * n_heads * head_dim * (seq_len + 1) / 2
+    head = 2 * d_model * vocab_size
+    return 3.0 * (n_layers * (proj + ffn + attn) + head)
 
 
 def mfu_estimate(tokens_per_sec: float, flops_per_tok: float,

@@ -577,6 +577,7 @@ def read_trace(paths: Sequence[str]) -> Tuple[List[dict], List[dict]]:
     run never double-counts."""
     spans: Dict[Tuple, dict] = {}
     clocks: List[dict] = []
+    loop: List[dict] = []  # this file's loop spans, until its pid is known
 
     def take(rec: dict) -> None:
         kind = rec.get("kind")
@@ -585,8 +586,26 @@ def read_trace(paths: Sequence[str]) -> Tuple[List[dict], List[dict]]:
                 (rec.get("rank"), rec.get("pid"), rec["span"]), rec)
         elif kind == CLOCK_KIND:
             clocks.append(rec)
+        elif kind == "round_record":
+            loop.extend(_loop_spans(rec))
+
+    def place_loop_spans(wire_before: int) -> None:
+        """A RoundRecord names no process and its spans carry the epoch
+        clock only: they take the pid and the ``ts - mono`` anchor of the
+        wire spans the same process wrote into the same file (0 where there
+        are none, which the wall-clock fallback of align_clocks rebases)."""
+        wire = list(spans.values())[wire_before:]
+        pid = wire[0].get("pid", 0) if wire else 0
+        anchors = sorted(float(w["ts"]) - float(w["mono"]) for w in wire
+                         if "ts" in w and "mono" in w)
+        anchor = anchors[len(anchors) // 2] if anchors else 0.0
+        for rec in loop:
+            rec["pid"], rec["mono"] = pid, rec["ts"] - anchor
+            spans.setdefault((rec["rank"], pid, rec["span"]), rec)
+        loop.clear()
 
     for path in paths:
+        wire_before = len(spans)
         try:
             if path.endswith(".jsonl"):
                 with open(path, encoding="utf-8") as f:
@@ -612,12 +631,35 @@ def read_trace(paths: Sequence[str]) -> Tuple[List[dict], List[dict]]:
                     take(rec)
         except (OSError, ValueError):
             continue
+        finally:
+            place_loop_spans(wire_before)
     ordered = sorted(spans.values(),
                      key=lambda r: (r.get("rank", 0), r.get("pid", 0),
                                     r.get("mono", 0.0), r.get("span", "")))
     clocks.sort(key=lambda r: (r.get("rank", 0), r.get("pid", 0),
                                r.get("n", 0)))
     return ordered, clocks
+
+
+def _loop_spans(record: dict) -> List[dict]:
+    """A RoundRecord's ``spans`` (the round loop's phases, telemetry.py) as
+    span records, so that ``fedml_tpu trace --chrome`` puts the loop and the
+    wire on one timeline. They carry ``round`` -1, which keeps them out of
+    the critical-path analysis (that follows wire causality); the round or
+    step is the annotation ``unit``."""
+    rank = int(record.get("edge_id", 0) or 0)
+    out = []
+    for s in record.get("spans") or ():
+        out.append({
+            "kind": SPAN_KIND, "v": TRACE_VERSION, "run": record.get("run_id"),
+            "rank": rank, "span": f"loop-{s['span']}",
+            "parent": (None if s.get("parent") is None
+                       else f"loop-{s['parent']}"),
+            "name": s["name"], "round": -1, "ts": s["ts_ns"] * 1e-9,
+            "dur": s["dur_ns"] * 1e-9,
+            "annot": {"unit": record.get("round_idx")},
+        })
+    return out
 
 
 def _proc_key(rec: dict) -> Tuple[int, int]:

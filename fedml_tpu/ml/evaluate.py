@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..core.mlops.scopes import evaluate_scope
 from .losses import get_loss_fn
 
 
@@ -24,8 +25,9 @@ def make_eval_fn(bundle, batch_size: int = 256):
 
     @partial(jax.jit, static_argnums=())
     def eval_batch(params, bx, by, bmask):
-        logits = bundle.apply(params, bx, train=False)
-        loss, metrics = loss_fn_raw(logits, by, bmask)
+        with evaluate_scope("evaluate"):
+            logits = bundle.apply(params, bx, train=False)
+            loss, metrics = loss_fn_raw(logits, by, bmask)
         return (
             (metrics["loss_sum"]).sum(),
             metrics["correct"],

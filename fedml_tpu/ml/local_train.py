@@ -25,6 +25,9 @@ import jax
 import jax.numpy as jnp
 import optax
 
+# names for what runs outside the flax model: the clients' loss and optax
+# update, inside ``local_train``
+from ..core.mlops.scopes import round_scope as _scope
 from .losses import get_loss_fn
 from .optimizer import create_client_optimizer
 
@@ -72,18 +75,21 @@ def make_local_train_fn(
             if jnp.issubdtype(bx.dtype, jnp.floating):
                 bx = bx.astype(jnp.bfloat16)
         logits = bundle.apply(params, bx, train=True, rngs={"dropout": rng})
-        logits = logits.astype(jnp.float32)
-        loss, metrics = loss_fn_raw(logits, by, bmask)
-        if fedprox_mu > 0.0:
-            sq = sum(
-                jnp.sum((p - g) ** 2)
-                for p, g in zip(jax.tree.leaves(params), jax.tree.leaves(global_params))
-            )
-            loss = loss + 0.5 * fedprox_mu * sq
+        with _scope("loss"):
+            logits = logits.astype(jnp.float32)
+            loss, metrics = loss_fn_raw(logits, by, bmask)
+            if fedprox_mu > 0.0:
+                sq = sum(
+                    jnp.sum((p - g) ** 2)
+                    for p, g in zip(jax.tree.leaves(params),
+                                    jax.tree.leaves(global_params))
+                )
+                loss = loss + 0.5 * fedprox_mu * sq
         return loss, metrics
 
     grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
 
+    @_scope("local_train")
     def local_train(global_params, x, y, n, rng, c_global=None, c_local=None):
         """x [cap, ...], y [cap, ...], n = true sample count (scalar)."""
         opt_state = opt.init(global_params)
@@ -107,15 +113,17 @@ def make_local_train_fn(
                 (loss, _), grads = grad_fn(
                     params, bx, by, bmask, brng, global_params
                 )
-                if scaffold:
-                    grads = jax.tree.map(
-                        lambda g, cg, cl: g + cg - cl, grads, c_global, c_local
-                    )
-                # guard fully-padded batches: freeze params there
-                has_data = (bmask.sum() > 0).astype(jnp.float32)
-                grads = jax.tree.map(lambda g: g * has_data, grads)
-                updates, opt_state = opt.update(grads, opt_state, params)
-                params = optax.apply_updates(params, updates)
+                with _scope("optimizer"):
+                    if scaffold:
+                        grads = jax.tree.map(
+                            lambda g, cg, cl: g + cg - cl,
+                            grads, c_global, c_local,
+                        )
+                    # guard fully-padded batches: freeze params there
+                    has_data = (bmask.sum() > 0).astype(jnp.float32)
+                    grads = jax.tree.map(lambda g: g * has_data, grads)
+                    updates, opt_state = opt.update(grads, opt_state, params)
+                    params = optax.apply_updates(params, updates)
                 return (params, opt_state), loss
 
             (params, opt_state), losses = jax.lax.scan(
@@ -151,11 +159,13 @@ def make_grad_fn(bundle, args, cap: int):
 
     def loss_fn(params, x, y, mask, rng):
         logits = bundle.apply(params, x, train=True, rngs={"dropout": rng})
-        loss, _ = loss_fn_raw(logits, y, mask)
+        with _scope("loss"):
+            loss, _ = loss_fn_raw(logits, y, mask)
         return loss
 
     grad = jax.value_and_grad(loss_fn)
 
+    @_scope("local_train")
     def client_grad(global_params, x, y, n, rng):
         mask = (jnp.arange(cap) < n).astype(jnp.float32)
         loss, g = grad(global_params, x, y, mask, rng)

@@ -20,6 +20,8 @@ import optax
 from flax import struct
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+# names for the step's device work outside the flax modules
+from ..core.mlops.scopes import train_step_scope as _scope
 from .context import mesh_context, sequence_parallelism
 from .sharding import batch_sharding, param_shardings, replicated, unbox
 from .transformer import Transformer, TransformerConfig
@@ -27,6 +29,18 @@ from .transformer import Transformer, TransformerConfig
 logger = logging.getLogger(__name__)
 
 PyTree = Any
+
+
+def _scoped(name: str, tx: optax.GradientTransformation):
+    """``tx`` with its update traced under the scope ``name``: the same
+    computation and the same state tree (``optax.named_chain`` would turn the
+    chain's state into a dict and orphan every checkpoint)."""
+
+    def update(updates, state, params=None):
+        with _scope(name):
+            return tx.update(updates, state, params)
+
+    return optax.GradientTransformation(tx.init, update)
 
 
 @struct.dataclass
@@ -52,7 +66,7 @@ def make_optimizer(
         0.0, learning_rate, warmup_steps, max(total_steps, warmup_steps + 1)
     )
     return optax.chain(
-        optax.clip_by_global_norm(grad_clip),
+        _scoped("clip", optax.clip_by_global_norm(grad_clip)),
         optax.adamw(
             schedule, b1=b1, b2=b2, weight_decay=weight_decay,
             mu_dtype=mu_dtype,
@@ -225,21 +239,25 @@ class CheetahTrainer:
                 mutable=mutable,
             )
             hidden, var_col = out if moe else (out, {})
-            loss = lm_loss_chunked(
-                hidden, params["w_lm_head"], tokens, mask, self.loss_chunk
-            )
+            with _scope("loss"):
+                loss = lm_loss_chunked(
+                    hidden, params["w_lm_head"], tokens, mask,
+                    self.loss_chunk
+                )
         else:
             out = self.model.apply(
                 {"params": params}, tokens, mask=None, mutable=mutable
             )
             logits, var_col = out if moe else (out, {})
-            loss = lm_loss(logits, tokens, mask)
+            with _scope("loss"):
+                loss = lm_loss(logits, tokens, mask)
         if moe:
-            aux = sum(
-                jnp.sum(jnp.asarray(v))
-                for v in jax.tree.leaves(var_col.get("losses", {}))
-            )
-            loss = loss + self.cfg.moe_aux_weight * aux
+            with _scope("loss"):
+                aux = sum(
+                    jnp.sum(jnp.asarray(v))
+                    for v in jax.tree.leaves(var_col.get("losses", {}))
+                )
+                loss = loss + self.cfg.moe_aux_weight * aux
         return loss
 
     def _train_step_raw(self, state: TrainState, tokens, mask):
@@ -258,21 +276,27 @@ class CheetahTrainer:
                     jax.tree.map(jnp.add, acc_grads, grads),
                 ), None
 
-            zero = jax.tree.map(jnp.zeros_like, state.params)
-            (loss_sum, grads), _ = jax.lax.scan(
-                micro, (jnp.zeros(()), zero), (tokens, mask)
-            )
-            loss = loss_sum / self.accum_steps
-            grads = jax.tree.map(lambda g: g / self.accum_steps, grads)
+            with _scope("grad_accum"):
+                zero = jax.tree.map(jnp.zeros_like, state.params)
+                (loss_sum, grads), _ = jax.lax.scan(
+                    micro, (jnp.zeros(()), zero), (tokens, mask)
+                )
+                loss = loss_sum / self.accum_steps
+                grads = jax.tree.map(lambda g: g / self.accum_steps, grads)
         else:
             loss, grads = jax.value_and_grad(self._loss_fn)(
                 state.params, tokens, mask
             )
-        updates, opt_state = self.opt.update(grads, state.opt_state, state.params)
-        params = optax.apply_updates(state.params, updates)
+        with _scope("optimizer"):
+            updates, opt_state = self.opt.update(
+                grads, state.opt_state, state.params
+            )
+            params = optax.apply_updates(state.params, updates)
+        with _scope("metrics"):
+            metrics = {"loss": loss, "grad_norm": optax.global_norm(grads)}
         return (
             TrainState(step=state.step + 1, params=params, opt_state=opt_state),
-            {"loss": loss, "grad_norm": optax.global_norm(grads)},
+            metrics,
         )
 
     def shard_batch(self, tokens, mask):

@@ -26,6 +26,9 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
+# names for what no flax module wraps; the modules name the rest
+from ..core.mlops.scopes import train_step_scope as _scope
+
 logger = logging.getLogger(__name__)
 
 # Logical axis names (mapped to mesh axes by sharding.LOGICAL_RULES)
@@ -397,8 +400,9 @@ class Attention(nn.Module):
         q = q.reshape(B, L, H, hd)
         k = k.reshape(B, L, Hkv, hd)
         v = v.reshape(B, L, Hkv, hd)
-        q = apply_rotary(q, cos, sin)
-        k = apply_rotary(k, cos, sin)
+        with _scope("rope"):
+            q = apply_rotary(q, cos, sin)
+            k = apply_rotary(k, cos, sin)
 
         from .context import get_seq_context
 
@@ -523,10 +527,11 @@ class Transformer(nn.Module):
         # fsdp) and then hits an "[SPMD] Involuntary full rematerialization"
         # transition to the batch-sharded activation layout (r4 VERDICT
         # weak #5, reproduced on the fsdp×tensor×sequence fedllm mesh)
-        x = _constrain_batch_activations(
-            jnp.take(_constrain_lookup_table(embed), tokens, axis=0)
-            .astype(cfg.dtype)
-        )
+        with _scope("embed"):
+            x = _constrain_batch_activations(
+                jnp.take(_constrain_lookup_table(embed), tokens, axis=0)
+                .astype(cfg.dtype)
+            )
         if positions is None:
             positions = jnp.arange(tokens.shape[1])[None, :]
         if cfg.pos_emb == "learned":
@@ -539,17 +544,20 @@ class Transformer(nn.Module):
             )
             # positions may be [1, L] (broadcast) or [B, L] (per-example,
             # same contract as the rotary branch)
-            x = x + jnp.take(
-                _constrain_lookup_table(pos_table, shard_rows=False),
-                positions, axis=0,
-            ).astype(cfg.dtype)
+            with _scope("embed"):
+                x = x + jnp.take(
+                    _constrain_lookup_table(pos_table, shard_rows=False),
+                    positions, axis=0,
+                ).astype(cfg.dtype)
             # identity rotation: attention runs position-free
             ang = jnp.zeros(positions.shape + (cfg.head_dim // 2,),
                             jnp.float32)
-            cos, sin = jnp.cos(ang), jnp.sin(ang)
+            with _scope("rope"):
+                cos, sin = jnp.cos(ang), jnp.sin(ang)
         else:
-            cos, sin = rotary_embedding(positions, cfg.head_dim,
-                                        cfg.rope_theta)
+            with _scope("rope"):
+                cos, sin = rotary_embedding(positions, cfg.head_dim,
+                                            cfg.rope_theta)
         x = _constrain_batch_activations(x)
 
         if cfg.remat:

@@ -47,6 +47,10 @@ from ..core.aggregate import (
     pseudo_gradient,
     weighted_average,
 )
+# names for the round's device work outside the flax model; ``local_train``
+# with its ``loss`` and ``optimizer`` is named where it is built
+# (ml/local_train.py)
+from ..core.mlops.scopes import round_scope as _scope
 from ..utils.tree import tree_flatten_to_vector, tree_unflatten_from_vector
 
 PyTree = Any
@@ -86,14 +90,17 @@ def build_round_core(api, n_cohort: int, n_valid: int):
     cohort_fn = api.cohort_fn
     client_num = api.ds.client_num
 
+    @_scope("aggregate")
     def aggregate(gp, stacked, weights, rng):
         # mirror of FedAvgAPI._aggregate minus the unfusable paths (custom
         # aggregator, FL-WBC) which are excluded by _fusion_blockers
         if dp is not None and dp.dp_type == "ldp":
-            keys = jax.random.split(jax.random.fold_in(rng, 3), n_cohort)
-            stacked = jax.vmap(dp.randomize)(stacked, keys)
+            with _scope("dp"):
+                keys = jax.random.split(jax.random.fold_in(rng, 3), n_cohort)
+                stacked = jax.vmap(dp.randomize)(stacked, keys)
         elif dp is not None and dp.dp_type == "cdp":
-            stacked = dp.clip_client_updates(stacked, gp)
+            with _scope("dp"):
+                stacked = dp.clip_client_updates(stacked, gp)
 
         needs_flat = attacker.is_model_attack() or defender.is_defense_enabled()
         if not needs_flat:
@@ -106,63 +113,74 @@ def build_round_core(api, n_cohort: int, n_valid: int):
         flat = jax.vmap(lambda t: tree_flatten_to_vector(t)[0])(stacked)
         gvec, _, _ = tree_flatten_to_vector(gp)
         if attacker.is_model_attack():
-            flat = attacker.attack_model(
-                flat, weights, jax.random.fold_in(rng, 1)
-            )
+            with _scope("attack"):
+                flat = attacker.attack_model(
+                    flat, weights, jax.random.fold_in(rng, 1)
+                )
         if defender.is_defense_enabled():
-            agg_vec = defender.defend(
-                flat, weights, gvec, jax.random.fold_in(rng, 2),
-                client_ids=None,
-            )
+            with _scope("defense"):
+                agg_vec = defender.defend(
+                    flat, weights, gvec, jax.random.fold_in(rng, 2),
+                    client_ids=None,
+                )
         else:
             w = weights / jnp.maximum(weights.sum(), 1e-12)
             agg_vec = (w[:, None] * flat).sum(0)
         return tree_unflatten_from_vector(agg_vec, treedef, shapes)
 
-    def core(state: RoundState, cohort_idx, cx, cy, cn, rngs, wmask,
-             round_rng) -> Tuple[RoundState, Dict[str, jax.Array]]:
-        gp = state["global_params"]
-        if attacker.is_data_attack():
-            cx, cy = attacker.attack_data(cx, cy, n_valid)
-
-        if fedsgd:
-            grads, metrics = cohort_fn(gp, cx, cy, cn, rngs)
-            weights = (metrics["num_samples"] if wmask is None
-                       else metrics["num_samples"] * wmask)
-            agg_grad = aggregate(gp, grads, weights, round_rng)
-            updates, opt_state = server_opt.update(
-                agg_grad, state["server_opt_state"], gp
-            )
-            gp = optax.apply_updates(gp, updates)
-            new_state = dict(state, global_params=gp,
-                             server_opt_state=opt_state)
-            # (the unfused path applies no central-DP noise on FedSGD either)
-            return new_state, {
+    def round_metrics(metrics, weights, wmask):
+        with _scope("metrics"):
+            return {
                 "train_loss": _masked_mean(metrics["train_loss"], wmask),
                 # on-device round counter: telemetry RoundRecords realize it
                 # host-side AFTER the round (no sync on the dispatch path)
                 "examples": weights.sum(),
             }
 
+    def core(state: RoundState, cohort_idx, cx, cy, cn, rngs, wmask,
+             round_rng) -> Tuple[RoundState, Dict[str, jax.Array]]:
+        gp = state["global_params"]
+        if attacker.is_data_attack():
+            with _scope("attack"):
+                cx, cy = attacker.attack_data(cx, cy, n_valid)
+
+        if fedsgd:
+            grads, metrics = cohort_fn(gp, cx, cy, cn, rngs)
+            weights = (metrics["num_samples"] if wmask is None
+                       else metrics["num_samples"] * wmask)
+            agg_grad = aggregate(gp, grads, weights, round_rng)
+            with _scope("server_update"):
+                updates, opt_state = server_opt.update(
+                    agg_grad, state["server_opt_state"], gp
+                )
+                gp = optax.apply_updates(gp, updates)
+            new_state = dict(state, global_params=gp,
+                             server_opt_state=opt_state)
+            # (the unfused path applies no central-DP noise on FedSGD either)
+            return new_state, round_metrics(metrics, weights, wmask)
+
         if scaffold:
-            c_cohort = jax.tree.map(lambda x: x[cohort_idx], state["c_locals"])
+            with _scope("select_cohort"):
+                c_cohort = jax.tree.map(lambda x: x[cohort_idx],
+                                        state["c_locals"])
             stacked, metrics, new_c = cohort_fn(
                 gp, cx, cy, cn, rngs, state["c_global"], c_cohort
             )
-            real = cohort_idx[:n_valid]
-            new_c_r = jax.tree.map(lambda x: x[:n_valid], new_c)
-            c_cohort_r = jax.tree.map(lambda x: x[:n_valid], c_cohort)
-            delta_c = jax.tree.map(
-                lambda n, o: (n - o).mean(0), new_c_r, c_cohort_r
-            )
-            scale = n_valid / client_num
-            c_global = jax.tree.map(
-                lambda cg, d: cg + scale * d, state["c_global"], delta_c
-            )
-            c_locals = jax.tree.map(
-                lambda all_c, nc: all_c.at[real].set(nc),
-                state["c_locals"], new_c_r,
-            )
+            with _scope("server_update"):
+                real = cohort_idx[:n_valid]
+                new_c_r = jax.tree.map(lambda x: x[:n_valid], new_c)
+                c_cohort_r = jax.tree.map(lambda x: x[:n_valid], c_cohort)
+                delta_c = jax.tree.map(
+                    lambda n, o: (n - o).mean(0), new_c_r, c_cohort_r
+                )
+                scale = n_valid / client_num
+                c_global = jax.tree.map(
+                    lambda cg, d: cg + scale * d, state["c_global"], delta_c
+                )
+                c_locals = jax.tree.map(
+                    lambda all_c, nc: all_c.at[real].set(nc),
+                    state["c_locals"], new_c_r,
+                )
             state = dict(state, c_global=c_global, c_locals=c_locals)
         else:
             stacked, metrics = cohort_fn(gp, cx, cy, cn, rngs)
@@ -171,30 +189,32 @@ def build_round_core(api, n_cohort: int, n_valid: int):
                    else metrics["num_samples"] * wmask)
 
         if fednova:
+            # w_new = w_g - tau_eff * sum_i p_i (w_g - w_i) / tau_i
             tau = metrics["tau"]
-            p = weights / jnp.maximum(weights.sum(), 1e-12)
-            tau_eff = (p * tau).sum()
-            norm_dir = fednova_normalized_direction(gp, stacked, tau)
-            d = weighted_average(norm_dir, weights)
-            gp = jax.tree.map(lambda g, dd: g - tau_eff * dd, gp, d)
+            with _scope("aggregate"):
+                norm_dir = fednova_normalized_direction(gp, stacked, tau)
+                d = weighted_average(norm_dir, weights)
+            with _scope("server_update"):
+                p = weights / jnp.maximum(weights.sum(), 1e-12)
+                tau_eff = (p * tau).sum()
+                gp = jax.tree.map(lambda g, dd: g - tau_eff * dd, gp, d)
         elif fedopt:
             w_agg = aggregate(gp, stacked, weights, round_rng)
-            pg = pseudo_gradient(gp, w_agg)
-            updates, opt_state = server_opt.update(
-                pg, state["server_opt_state"], gp
-            )
-            gp = optax.apply_updates(gp, updates)
+            with _scope("server_update"):
+                pg = pseudo_gradient(gp, w_agg)
+                updates, opt_state = server_opt.update(
+                    pg, state["server_opt_state"], gp
+                )
+                gp = optax.apply_updates(gp, updates)
             state = dict(state, server_opt_state=opt_state)
         else:
             gp = aggregate(gp, stacked, weights, round_rng)
 
         if dp is not None and dp.dp_type == "cdp":
-            gp = dp.randomize_global(gp, jax.random.fold_in(round_rng, 7))
+            with _scope("dp"):
+                gp = dp.randomize_global(gp, jax.random.fold_in(round_rng, 7))
         new_state = dict(state, global_params=gp)
-        return new_state, {
-            "train_loss": _masked_mean(metrics["train_loss"], wmask),
-            "examples": weights.sum(),
-        }
+        return new_state, round_metrics(metrics, weights, wmask)
 
     return core
 
@@ -241,17 +261,19 @@ def make_superround_step(api, k: int, n_cohort: int):
     def superround(state: RoundState, start_round):
         def body(st, r):
             rkey = jax.random.fold_in(root_rng, r)
-            if eng is not None:  # registry K-of-N → backing shard rows
-                cohort = jnp.take(reg_ptrs, reg_sample(r), axis=0)
-            elif total == per:  # full participation: matches the host path
-                cohort = jnp.arange(per, dtype=jnp.int32)
-            else:
-                cohort = jax.random.choice(
-                    jax.random.fold_in(rkey, 13), total, (per,), replace=False
-                ).astype(jnp.int32)
-            cx = jnp.take(dev_x, cohort, axis=0)
-            cy = jnp.take(dev_y, cohort, axis=0)
-            cn = jnp.take(dev_counts, cohort, axis=0)
+            with _scope("select_cohort"):
+                if eng is not None:  # registry K-of-N → backing shard rows
+                    cohort = jnp.take(reg_ptrs, reg_sample(r), axis=0)
+                elif total == per:  # full participation: as the host path
+                    cohort = jnp.arange(per, dtype=jnp.int32)
+                else:
+                    cohort = jax.random.choice(
+                        jax.random.fold_in(rkey, 13), total, (per,),
+                        replace=False,
+                    ).astype(jnp.int32)
+                cx = jnp.take(dev_x, cohort, axis=0)
+                cy = jnp.take(dev_y, cohort, axis=0)
+                cn = jnp.take(dev_counts, cohort, axis=0)
             rngs = jax.random.split(rkey, per)
             st, metrics = core(st, cohort, cx, cy, cn, rngs, None, rkey)
             return st, {"train_loss": metrics["train_loss"],

@@ -450,21 +450,24 @@ class FedAvgAPI:
         allows it, the legacy multi-dispatch ``_train_round`` otherwise.
 
         With ``--enable_tracking`` each round opens a telemetry RoundRecord
-        (phase spans, dispatch latency, HBM, compile events) and may open or
-        close a ``--profile_rounds`` jax.profiler window. Disabled, both are
-        one boolean check."""
+        (phase spans on the profiler's clock, rounds in flight, HBM, compile
+        events) and may open or close a ``--profile_rounds`` jax.profiler
+        window. Disabled, both are one boolean check. Neither waits for the
+        device: the record's loss is read once it is ready."""
         if not self._fusion_ready:
             self._setup_round_fusion()
-        telemetry.on_round_start(round_idx)
-        rec = telemetry.begin_round(
-            round_idx, fused=self._round_step is not None
-        )
+        with telemetry.phase("hooks"):
+            telemetry.on_round_start(round_idx)
+            rec = telemetry.begin_round(
+                round_idx, fused=self._round_step is not None
+            )
         if self._round_step is None:
             out = self._train_round(round_idx)
         else:
             out = self._train_round_fused(round_idx)
-        telemetry.end_round(rec, train_loss=out.get("train_loss"))
-        telemetry.on_round_end(round_idx)
+        with telemetry.phase("record"):
+            telemetry.end_round(rec, train_loss=out.get("train_loss"))
+            telemetry.on_round_end(round_idx)
         return out
 
     def run_rounds(self, start_round: int, k: int) -> Dict[str, Any]:
@@ -475,28 +478,28 @@ class FedAvgAPI:
         if not self._fusion_ready:
             self._setup_round_fusion()
         if self._superround_step is not None and k == self._superround_k:
-            telemetry.on_round_start(start_round)
-            tracked = telemetry.enabled()
-            t0 = time.perf_counter() if tracked else 0.0
-            self._prepare_round()
-            state, scan_metrics = self._superround_step(
-                self._place_state(self._round_state()), jnp.int32(start_round)
-            )
-            self._set_round_state(state)
-            if self.cohort_engine is not None:
-                # the scan sampled rounds [start, start+k) on device with
-                # the registry's own sampler; replay them host-side so the
-                # participation/staleness counters stay truthful
-                self.cohort_engine.note_rounds(start_round, k)
-            if tracked:
+            with telemetry.phase("hooks"):
+                telemetry.on_round_start(start_round)
+                rec = telemetry.begin_round(start_round, fused=True,
+                                            superround=True)
+            with telemetry.phase("dispatch"):
+                self._prepare_round()
+                state, scan_metrics = self._superround_step(
+                    self._place_state(self._round_state()),
+                    jnp.int32(start_round),
+                )
+                self._set_round_state(state)
+                if self.cohort_engine is not None:
+                    # the scan sampled rounds [start, start+k) on device with
+                    # the registry's own sampler; replay them host-side so
+                    # the participation/staleness counters stay truthful
+                    self.cohort_engine.note_rounds(start_round, k)
+            with telemetry.phase("record"):
                 # one record per scanned round, unpacked from the scan's
                 # stacked on-device counters (the only host sync tracking
                 # adds — the untracked path stays fully asynchronous)
-                jax.block_until_ready(state)
-                telemetry.emit_superround(
-                    start_round, k, time.perf_counter() - t0, scan_metrics
-                )
-            telemetry.on_round_end(start_round + k - 1)
+                telemetry.end_superround(rec, k, scan_metrics)
+                telemetry.on_round_end(start_round + k - 1)
             return {"train_loss": scan_metrics["train_loss"]}
         return {"train_loss": [
             self.run_round(start_round + j)["train_loss"] for j in range(k)
@@ -505,12 +508,10 @@ class FedAvgAPI:
     def _train_round_fused(self, round_idx: int) -> Dict[str, float]:
         """One round as ONE donated device program (round_engine.py).
 
-        Returns train_loss as a DEVICE scalar — no host sync. train() keeps
-        dispatch asynchronous: while the device executes round r, the host
-        already samples and gathers round r+1's cohort. Only under an active
-        telemetry record does the round block for dispatch→ready latency.
+        Returns train_loss as a DEVICE scalar — no host sync, tracked or
+        not. train() keeps dispatch asynchronous: while the device executes
+        round r, the host already samples and gathers round r+1's cohort.
         """
-        rec = telemetry.current_record()
         with telemetry.phase("sample"):
             self._prepare_round()
             cohort, wmask = self._pad_cohort(self._client_sampling(round_idx))
@@ -522,17 +523,12 @@ class FedAvgAPI:
             wm = None if wmask is None else self._place(jnp.asarray(wmask))
             cohort_idx = jnp.asarray(cohort, jnp.int32)
             st = self._place_state(self._round_state())
-        t_dispatch = time.perf_counter()
         with telemetry.phase("dispatch"):
             state, metrics = self._round_step(
                 st, cohort_idx, cx, cy, cn, rngs, wm, round_rng,
             )
-        self._set_round_state(state)
-        if rec is not None:
-            rec.lazy["examples"] = metrics.get("examples")
-            with telemetry.phase("device_wait"):
-                jax.block_until_ready(state)
-            rec.dispatch_latency_s = time.perf_counter() - t_dispatch
+            self._set_round_state(state)
+            telemetry.record_lazy("examples", metrics.get("examples"))
         return {"train_loss": metrics["train_loss"]}
 
     # -- one round (legacy multi-dispatch path; kept as the numerical
@@ -545,12 +541,13 @@ class FedAvgAPI:
             n_valid = len(cohort) if wmask is None else int(wmask.sum())
         with telemetry.phase("gather"):
             cx, cy, cn = self._gather_cohort(cohort)
-        if self.attacker.is_data_attack():
-            cx, cy = self.attacker.attack_data(cx, cy, n_valid)
-
-        round_rng = jax.random.fold_in(self.root_rng, round_idx)
-        rngs = self._place(jax.random.split(round_rng, len(cohort)))
-        wm = None if wmask is None else self._place(jnp.asarray(wmask))
+        with telemetry.phase("prep"):
+            if self.attacker.is_data_attack():
+                cx, cy = self.attacker.attack_data(cx, cy, n_valid)
+            round_rng = jax.random.fold_in(self.root_rng, round_idx)
+            rngs = self._place(jax.random.split(round_rng, len(cohort)))
+            wm = None if wmask is None else self._place(jnp.asarray(wmask))
+        t_dispatch = time.perf_counter()
 
         if self.fedsgd:
             with telemetry.phase("train"):
@@ -565,8 +562,8 @@ class FedAvgAPI:
             import optax
 
             self.global_params = optax.apply_updates(self.global_params, updates)
-            with telemetry.phase("loss_sync"):
-                return {"train_loss": _masked_mean(metrics["train_loss"], wm)}
+            return {"train_loss": self._loss_sync(
+                metrics["train_loss"], wm, rec, t_dispatch)}
 
         if self.scaffold:
             c_cohort = jax.tree.map(lambda x: x[cohort], self.c_locals)
@@ -625,10 +622,19 @@ class FedAvgAPI:
             self.global_params = self.dp.randomize_global(
                 self.global_params, jax.random.fold_in(round_rng, 7)
             )
+        return {"train_loss": self._loss_sync(
+            metrics.get("train_loss"), wm, rec, t_dispatch)}
+
+    @staticmethod
+    def _loss_sync(values, wm, rec, t_dispatch: float) -> float:
+        """The unfused round's one wait: ``_masked_mean`` pulls a host float,
+        so this span absorbs the device time of everything dispatched since
+        ``t_dispatch``, and the record notes it as its dispatch latency."""
         with telemetry.phase("loss_sync"):
-            # _masked_mean pulls a host float, so this span absorbs the
-            # device wait for everything dispatched above
-            return {"train_loss": _masked_mean(metrics.get("train_loss"), wm)}
+            loss = _masked_mean(values, wm)
+        if rec is not None:
+            rec.dispatch_latency_s = time.perf_counter() - t_dispatch
+        return loss
 
     # -- aggregation with trust hooks ---------------------------------------
     def _aggregate(
@@ -825,82 +831,97 @@ class FedAvgAPI:
                                     every if ckpt is not None else 0)
                 self.args.round_idx = round_idx + k - 1
                 t0 = time.perf_counter()
+                # every statement below lies in a telemetry span (hooks,
+                # sample .. record inside run_round(s), then log, eval,
+                # ledger, checkpoint): tracked, the spans tile the iteration
                 if k > 1:
                     # superround: K rounds in one donated scan program;
                     # per-round losses come back stacked [K]
-                    with mlops.MLOpsProfilerEvent("train"):
-                        losses = self.run_rounds(round_idx, k)["train_loss"]
-                    dt = time.perf_counter() - t0
-                    for j in range(k):
-                        mlops.log_round_info(round_idx + j, rounds)
-                        self.history.append({
-                            "round": round_idx + j, "round_time_s": dt / k,
-                            "train_loss": losses[j],
-                        })
+                    losses = self.run_rounds(round_idx, k)["train_loss"]
+                    with telemetry.phase("log"):
+                        dt = time.perf_counter() - t0
+                        for j in range(k):
+                            mlops.log_round_info(round_idx + j, rounds)
+                            self.history.append({
+                                "round": round_idx + j,
+                                "round_time_s": dt / k,
+                                "train_loss": losses[j],
+                            })
                 else:
-                    mlops.log_round_info(round_idx, rounds)
-                    with mlops.MLOpsProfilerEvent("train"):
-                        train_metrics = self.run_round(round_idx)
-                    dt = time.perf_counter() - t0
-                    self.history.append({
-                        "round": round_idx, "round_time_s": dt,
-                        **train_metrics,
-                    })
+                    with telemetry.phase("log"):
+                        mlops.log_round_info(round_idx, rounds)
+                    train_metrics = self.run_round(round_idx)
+                    with telemetry.phase("log"):
+                        dt = time.perf_counter() - t0
+                        self.history.append({
+                            "round": round_idx, "round_time_s": dt,
+                            **train_metrics,
+                        })
                 last_round = round_idx + k - 1
                 entry = self.history[-1]
                 if last_round % freq == 0 or last_round == rounds - 1:
-                    # runs BETWEEN rounds (the round's record is already
-                    # closed): registry histogram only, never a record phase
-                    with telemetry.phase("eval", record=False):
+                    # runs BETWEEN rounds: the span lands on the record that
+                    # closed last, never in a record's phases
+                    with telemetry.phase("eval"):
                         last_eval = self.evaluate(
                             self.global_params, self.ds.test_x, self.ds.test_y
                         )
-                    entry.update(last_eval)
-                    mlops.log({"round": last_round, **last_eval},
-                              step=last_round)
-                    logger.info(
-                        "round %d: loss=%.4f acc=%.4f (%.3fs)",
-                        last_round, last_eval["test_loss"],
-                        last_eval["test_acc"], dt / k,
-                    )
+                    with telemetry.phase("log"):
+                        entry.update(last_eval)
+                        mlops.log({"round": last_round, **last_eval},
+                                  step=last_round)
+                        logger.info(
+                            "round %d: loss=%.4f acc=%.4f (%.3fs)",
+                            last_round, last_eval["test_loss"],
+                            last_eval["test_acc"], dt / k,
+                        )
                 if ledger is not None:
                     # cohorts are host-sampled per round except under a
                     # superround scan (on-device sampling) — deterministic
                     # either way, but only the host path is recordable
-                    for j in range(round_idx, last_round + 1):
-                        pending.append((
-                            j,
-                            None if k > 1
-                            else [int(c) for c in self._client_sampling(j)],
-                        ))
-                if ckpt is not None and (
-                    (last_round + 1) % every == 0 or last_round == rounds - 1
-                ):
-                    step = ckpt.save(self._ckpt_state(), step=last_round)
-                    for r, cohort in pending:
-                        ledger.commit_round(r, ckpt_step=step, cohort=cohort)
-                    pending.clear()
+                    with telemetry.phase("ledger"):
+                        for j in range(round_idx, last_round + 1):
+                            pending.append((
+                                j,
+                                None if k > 1 else
+                                [int(c) for c in self._client_sampling(j)],
+                            ))
                 round_idx += k
-                if guard is not None and guard.requested() \
-                        and round_idx < rounds:
-                    from ..core.runstate import PreemptionError
-
-                    # drain commit: the chunk above completed; persist its
-                    # state NOW (even off the checkpoint cadence) so the
-                    # restart resumes exactly here instead of re-training
-                    if ckpt.latest_step() != last_round:
+                with telemetry.phase("checkpoint"):
+                    if ckpt is not None and (
+                        (last_round + 1) % every == 0
+                        or last_round == rounds - 1
+                    ):
                         step = ckpt.save(self._ckpt_state(), step=last_round)
                         for r, cohort in pending:
                             ledger.commit_round(r, ckpt_step=step,
                                                 cohort=cohort)
                         pending.clear()
-                    telemetry.counter_inc("run.preemptions")
-                    raise PreemptionError(last_round)
+                    if guard is not None and guard.requested() \
+                            and round_idx < rounds:
+                        from ..core.runstate import PreemptionError
+
+                        # drain commit: the chunk above completed; persist
+                        # its state NOW (even off the checkpoint cadence) so
+                        # the restart resumes exactly here instead of
+                        # re-training
+                        if ckpt.latest_step() != last_round:
+                            step = ckpt.save(self._ckpt_state(),
+                                             step=last_round)
+                            for r, cohort in pending:
+                                ledger.commit_round(r, ckpt_step=step,
+                                                    cohort=cohort)
+                            pending.clear()
+                        telemetry.counter_inc("run.preemptions")
+                        raise PreemptionError(last_round)
         finally:
             if ckpt is not None:  # release Orbax threads even on a crash
                 ckpt.close()
             if self.cohort_engine is not None:
                 self.cohort_engine.close()
+            # the records still waiting for their device scalars: all are in
+            # the sink, in round order, when train() returns
+            telemetry.drain_records()
             self._finalize_history()
         return last_eval
 

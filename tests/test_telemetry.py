@@ -85,16 +85,26 @@ class TestRoundRecords:
         assert [r["round_idx"] for r in recs] == [0, 1, 2, 3]
         for r in recs:
             assert r["fused"] is True
-            assert r["dispatch_latency_s"] is not None
+            # the fused loop waits nowhere, so there is no dispatch latency
+            # to note and no device_wait phase; the record says instead how
+            # many earlier rounds were still on the device when it opened
+            assert r["dispatch_latency_s"] is None
+            assert "device_wait" not in r["phases"]
+            assert 0 <= r["in_flight"] <= r["round_idx"]
             assert r["examples"] and r["examples"] > 0
             assert np.isfinite(r["train_loss"])
-            assert r["rounds_per_sec_ema"] > 0
-            assert {"sample", "gather", "prep", "dispatch",
-                    "device_wait"} <= set(r["phases"])
-            # phase spans never exceed the round wall and cover its bulk
-            # (sub-ms CPU lr rounds leave some span-bookkeeping remainder)
+            assert {"hooks", "sample", "gather", "prep",
+                    "dispatch"} <= set(r["phases"])
+            # phase spans never exceed the round wall
             assert sum(r["phases"].values()) <= r["wall_s"] + 1e-6
-            assert sum(r["phases"].values()) >= 0.3 * r["wall_s"]
+        # ... and cover its bulk: in the typical round (sub-ms on the CPU,
+        # so one round of four may hold a scheduler hiccup between spans)
+        coverage = [sum(r["phases"].values()) / r["wall_s"] for r in recs]
+        assert _median(coverage) >= 0.5, coverage
+        # the EMA is the period between successive begin_rounds: the first
+        # round of a loop has none yet
+        assert recs[0]["rounds_per_sec_ema"] is None
+        assert all(r["rounds_per_sec_ema"] > 0 for r in recs[1:])
 
     def test_unfused_rounds_emit_records_with_loop_phases(self, tmp_path):
         api = make_api(tmp_path, "unfused", round_fusion="off")
@@ -106,6 +116,8 @@ class TestRoundRecords:
             assert {"sample", "gather", "train", "aggregate",
                     "loss_sync"} <= set(r["phases"])
             assert r["examples"] and r["examples"] > 0
+            # the unfused loop itself waits for the loss: dispatch -> host
+            assert r["dispatch_latency_s"] >= r["phases"]["loss_sync"]
 
     def test_superround_scan_unpacks_one_record_per_round(self, tmp_path):
         api = make_api(tmp_path, "sup", comm_round=9, superround_k=4)
@@ -143,6 +155,342 @@ class TestRoundRecords:
         # compile wall, steady-state rounds compile nothing
         assert recs[0]["compiles"] > 0
         assert all(r["compiles"] == 0 for r in recs[2:])
+
+
+# ---------------------------------------------------------------------------
+# Spans on the profiler's clock (ISSUE 26)
+# ---------------------------------------------------------------------------
+
+
+def sink_records():
+    """The round records in the sink as it stands: no flush, so nothing
+    pending is realized by looking."""
+    with mlops.MLOpsStore._sink_lock:
+        buffered = list(mlops.MLOpsStore._buffer)
+    with open(mlops.MLOpsStore.jsonl_path) as f:
+        events = [json.loads(ln) for ln in list(f) + buffered]
+    return [e for e in events if e.get("kind") == "round_record"]
+
+
+def _iterations(recs):
+    """Per record but the first and last: (span names, gap share) of the
+    iteration that starts with the record's first top-level span and ends
+    where the next record's begins. Top-level spans are siblings; the gap
+    share is the time between consecutive ones over the iteration."""
+    tops = [[s for s in r["spans"] if s["parent"] is None] for r in recs]
+    for spans in tops:
+        spans.sort(key=lambda s: s["ts_ns"])
+    out = []
+    for spans, following in zip(tops[1:-1], tops[2:]):
+        end = following[0]["ts_ns"]
+        total = end - spans[0]["ts_ns"]
+        edges = [(s["ts_ns"], s["ts_ns"] + s["dur_ns"]) for s in spans]
+        edges.append((end, end))
+        gaps = sum(max(b[0] - a[1], 0) for a, b in zip(edges, edges[1:]))
+        out.append(([s["name"] for s in spans], gaps / total))
+    return out
+
+
+def _median(values):
+    return sorted(values)[len(values) // 2]
+
+
+def _cheetah_runner(tmp_path, run_id, **kw):
+    from fedml_tpu.runner import FedMLRunner
+
+    base = dict(training_type="distributed", dataset="synthetic",
+                model="transformer", model_size="tiny", total_steps=6,
+                batch_size=8, seq_len=128, enable_tracking=True,
+                tracking_dir=str(tmp_path), run_id=run_id)
+    base.update(kw)
+    args = fedml.init(Arguments(overrides=base), should_init_logs=False)
+    return FedMLRunner(args, fedml.get_device(args), None, None)
+
+
+class TestSpans:
+    def test_spans_carry_the_epoch_clock_and_their_parent(self, tmp_path):
+        t_before = time.time_ns()
+        api = make_api(tmp_path, "spans")
+        api.train()
+        t_after = time.time_ns()
+        for r in round_records():
+            assert r["spans"], "a tracked round records its spans"
+            ids = {s["span"] for s in r["spans"]}
+            assert len(ids) == len(r["spans"])
+            for s in r["spans"]:
+                assert set(s) == {"name", "span", "parent", "ts_ns", "dur_ns"}
+                assert t_before <= s["ts_ns"] <= t_after
+                assert s["dur_ns"] >= 0
+                assert s["parent"] is None or isinstance(s["parent"], int)
+            # time - wall_s is the record's start on the same clock: no span
+            # that ran under the record starts before it
+            start_ns = (r["time"] - r["wall_s"]) * 1e9
+            inside = [s for s in r["spans"] if s["name"] in r["phases"]]
+            assert min(s["ts_ns"] for s in inside) >= start_ns - 50_000
+            # phases stays the sum of the durations of the spans by name
+            for name, total in r["phases"].items():
+                assert total == pytest.approx(
+                    1e-9 * sum(s["dur_ns"] for s in inside
+                               if s["name"] == name), abs=2e-6)
+
+    def test_nested_span_names_its_parent(self, tmp_path):
+        make_api(tmp_path, "nest")
+        rec = telemetry.begin_round(0)
+        with telemetry.phase("outer") as outer:
+            with telemetry.phase("inner", record=False):
+                pass
+        telemetry.end_round(rec)
+        telemetry.drain_records()
+        spans = {s["name"]: s for s in round_records()[-1]["spans"]}
+        assert spans["inner"]["parent"] == outer.span_id == spans["outer"]["span"]
+        assert spans["outer"]["parent"] is None
+        assert "inner" not in round_records()[-1]["phases"]
+
+    def test_fedavg_spans_tile_the_iteration(self, tmp_path):
+        api = make_api(tmp_path, "tile", comm_round=8,
+                       frequency_of_the_test=1,
+                       checkpoint_dir=str(tmp_path / "ck"),
+                       checkpoint_every_rounds=1)
+        api.train()
+        its = _iterations(round_records())
+        assert len(its) == 6
+        for names, _ in its:
+            assert names[:6] == ["hooks", "sample", "gather", "prep",
+                                 "dispatch", "record"]
+            assert set(names[6:]) == {"log", "eval", "ledger", "checkpoint"}
+        # the typical iteration: a loaded test host may stall one between spans
+        assert _median([gap for _, gap in its]) < 0.02, its
+
+    def test_cheetah_spans_tile_the_iteration(self, tmp_path):
+        _cheetah_runner(tmp_path, "ctile").run()
+        recs = round_records()
+        assert [r["round_idx"] for r in recs] == list(range(6))
+        its = _iterations(recs)
+        for names, _ in its:
+            assert names == ["hooks", "data", "h2d", "step", "loss_sync",
+                             "record", "checkpoint"]
+        assert _median([gap for _, gap in its]) < 0.02, its
+        for r in recs:
+            assert r["dispatch_latency_s"] >= r["phases"]["loss_sync"]
+            assert r["examples"] == 8 * 128
+
+    def test_between_rounds_span_lands_on_the_record_that_closed_last(
+            self, tmp_path):
+        api = make_api(tmp_path, "between", comm_round=3,
+                       frequency_of_the_test=1)
+        api.train()
+        recs = round_records()
+        for r in recs:
+            names = [s["name"] for s in r["spans"]]
+            assert "eval" in names and "log" in names
+            assert "eval" not in r["phases"]  # never in a record's phases
+            ev = next(s for s in r["spans"] if s["name"] == "eval")
+            assert ev["ts_ns"] >= r["time"] * 1e9 - 50_000  # after it closed
+        # and before the next record opened
+        for r, nxt in zip(recs, recs[1:]):
+            ev = next(s for s in r["spans"] if s["name"] == "eval")
+            assert ev["ts_ns"] + ev["dur_ns"] <= \
+                (nxt["time"] - nxt["wall_s"]) * 1e9 + 50_000
+
+    def test_span_after_the_loop_is_dropped_not_misfiled(self, tmp_path):
+        api = make_api(tmp_path, "after", comm_round=2)
+        api.train()  # drained: the last record is in the sink
+        with telemetry.phase("late"):
+            pass
+        assert all(s["name"] != "late"
+                   for r in round_records() for s in r["spans"])
+
+
+class TestLoopSpansOnTheWireTimeline:
+    def test_trace_chrome_shows_loop_spans_beside_wire_spans(
+            self, tmp_path, capsys):
+        """``fedml_tpu trace --chrome`` reads the loop's spans out of the
+        round records and places them on the timeline of the wire spans the
+        same process wrote: same clock, no critical-path role."""
+        api = make_api(tmp_path, "loopwire", comm_round=3)
+        api.train()
+        recs = round_records()
+        first = min(s["ts_ns"] for r in recs for s in r["spans"])
+        wire = {"kind": "trace_span", "v": 1, "run": "loopwire", "rank": 0,
+                "pid": 4242, "span": "w1", "parent": None, "name": "dispatch",
+                "round": 0, "ts": first * 1e-9 - 1.0, "mono": 100.0,
+                "dur": 0.5}
+        mlops._emit(dict(wire))
+        path = mlops.MLOpsStore.jsonl_path
+        mlops.close()
+        from fedml_tpu.cli import main as cli_main
+
+        chrome = tmp_path / "loop.chrome.json"
+        assert cli_main(["trace", os.path.dirname(path), "--run_id",
+                         "loopwire", "--json", "--chrome", str(chrome)]) == 0
+        out = json.loads(capsys.readouterr().out)
+        n_loop = sum(len(r["spans"]) for r in recs)
+        assert out["spans"] == n_loop + 1 and out["orphans"] == []
+        assert out["rounds"] == [0]  # the loop's spans join no round's path
+        events = [e for e in json.load(open(chrome))["traceEvents"]
+                  if e["ph"] == "X"]
+        by_name = {}
+        for e in events:
+            by_name.setdefault(e["name"], []).append(e)
+        assert {"hooks", "sample", "gather", "prep", "record"} <= set(by_name)
+        assert len(by_name["sample"]) == 3
+        assert sorted(e["args"]["unit"] for e in by_name["sample"]) == [0, 1, 2]
+        # one process, one timeline: the wire span started 1 s before the
+        # first loop span, on the wire span's clock
+        wire_ev = next(e for e in by_name["dispatch"] if e["args"]["span"] == "w1")
+        loop_first = min(e["ts"] for e in events if e is not wire_ev)
+        assert loop_first - wire_ev["ts"] == pytest.approx(1e6, abs=50)
+        assert {e["tid"] for e in events} == {4242}
+
+
+class TestNoHostSyncWhenTracked:
+    def test_tracked_fused_loop_never_waits_for_the_device(
+            self, tmp_path, monkeypatch):
+        """Tracking on: no block_until_ready anywhere in the loop, _realize
+        only ever sees values that are ready until train() drains at its
+        end, and every record is in the sink, in order, on return."""
+        api = make_api(tmp_path, "nosync", comm_round=12)
+        blocks, unready = [], []
+        in_loop = {"on": True}
+        orig_block, orig_realize = jax.block_until_ready, telemetry._realize
+        monkeypatch.setattr(
+            jax, "block_until_ready",
+            lambda x: (blocks.append(1), orig_block(x))[1])
+
+        def realize(value):
+            if in_loop["on"] and hasattr(value, "is_ready") \
+                    and not value.is_ready():
+                unready.append(value)
+            return orig_realize(value)
+
+        def drain(block=True):
+            if block:
+                in_loop["on"] = False  # the loop's end (or a flush)
+            return orig_drain(block)
+
+        orig_drain = telemetry.drain_records
+        monkeypatch.setattr(telemetry, "_realize", realize)
+        monkeypatch.setattr(telemetry, "drain_records", drain)
+        api.train()
+        assert not blocks and not unready
+        recs = sink_records()
+        assert [r["round_idx"] for r in recs] == list(range(12))
+        assert all(np.isfinite(r["train_loss"]) and r["examples"] > 0
+                   for r in recs)
+        assert not telemetry._PENDING
+
+    def test_begin_round_leaves_an_unready_record_pending(self, tmp_path):
+        class Later:
+            ready = False
+
+            def is_ready(self):
+                return self.ready
+
+            def __array__(self, dtype=None, copy=None):
+                return np.asarray(2.5)
+
+        make_api(tmp_path, "pending")
+        loss = Later()
+        telemetry.end_round(telemetry.begin_round(0), train_loss=loss)
+        rec1 = telemetry.begin_round(1)
+        assert rec1.in_flight == 1 and not sink_records()
+        telemetry.end_round(rec1, train_loss=1.0)
+        rec2 = telemetry.begin_round(2)  # 1 is ready, but waits its turn
+        assert rec2.in_flight == 2 and not sink_records()
+        telemetry.end_round(rec2, train_loss=0.5)
+        loss.ready = True
+        rec3 = telemetry.begin_round(3)  # all ready now: emitted in order
+        assert rec3.in_flight == 0
+        assert [(r["round_idx"], r["train_loss"], r["in_flight"])
+                for r in sink_records()] == [(0, 2.5, 0), (1, 1.0, 1),
+                                             (2, 0.5, 2)]
+        telemetry.end_round(rec3, train_loss=0.25)
+        mlops.flush()  # flush realizes what is left
+        assert [r["round_idx"] for r in sink_records()] == [0, 1, 2, 3]
+
+
+class TestProfilerAnnotations:
+    def test_disabled_builds_no_annotation(self, monkeypatch):
+        built = []
+        monkeypatch.setattr(jax.profiler, "TraceAnnotation",
+                            lambda *a, **k: built.append(a))
+        monkeypatch.setattr(jax.profiler, "StepTraceAnnotation",
+                            lambda *a, **k: built.append(a))
+        telemetry.set_enabled(False)
+        assert telemetry.phase("x") is telemetry._NULL_SPAN
+        with telemetry.phase("x"):
+            pass
+        assert telemetry.begin_round(0) is None
+        assert not built
+
+    def test_host_plane_holds_the_span_names(self, tmp_path):
+        from jax.profiler import ProfileData
+
+        runner = _cheetah_runner(tmp_path, "anno", total_steps=3)
+        trace_dir = tmp_path / "trace"
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0  # the annotations are host TraceMes
+        jax.profiler.start_trace(str(trace_dir), profiler_options=options)
+        try:
+            runner.run()
+        finally:
+            jax.profiler.stop_trace()
+        (path,) = trace_dir.glob("plugins/profile/*/*.xplane.pb")
+        data = ProfileData.from_file(str(path))
+        host = next(p for p in data.planes if p.name == "/host:CPU")
+        seen = {}
+        for line in host.lines:
+            for e in line.events:
+                seen.setdefault(e.name, []).append(dict(e.stats))
+        for name in ("hooks", "data", "h2d", "step", "loss_sync", "record",
+                     "checkpoint"):
+            assert name in seen, sorted(seen)
+        assert sorted(int(st["unit"]) for st in seen["loss_sync"]) == [0, 1, 2]
+        # each step ran under a StepTraceAnnotation
+        assert sorted(int(st["step_num"]) for st in seen["step"]
+                      if "step_num" in st) == [0, 1, 2]
+
+
+class TestCompileEvents:
+    def test_each_backend_compile_names_its_program(self, tmp_path):
+        api = make_api(tmp_path, "compile_ev")
+        api.train()
+        events = [e for e in mlops.read_events() if e.get("kind") == "compile"]
+        assert events, "round 0 compiled at least the round program"
+        names = [e["fun_name"] for e in events]
+        assert "jit(core)" in names, names
+        for e in events:
+            assert e["seconds"] >= 0
+            assert e["cache_hits"] >= 0 and e["cache_misses"] >= 0
+        counters = telemetry.registry().snapshot()["counters"]
+        assert len(events) == counters["jax.compiles"]
+        assert sum(e["cache_hits"] for e in events) == \
+            counters.get("jax.compilation_cache.hits", 0)
+        assert sum(e["cache_misses"] for e in events) == \
+            counters.get("jax.compilation_cache.misses", 0)
+
+
+class TestFlopsPerToken:
+    def test_agrees_with_the_benchmarks_shape_function(self):
+        """telemetry.flops_per_token counts what benchmark/flops/
+        transformer.py counts (no embedding gather, causal attention):
+        3.605 GFLOP a token at the mistral_7b_v0.1_l2 shapes."""
+        from benchmark.flops import transformer
+
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        with open(os.path.join(root, "benchmark", "configs",
+                               "mistral_7b_v0.1_l2.json")) as f:
+            config = json.load(f)
+        want = transformer.train_flops_per_token(config, 4096)
+        got = telemetry.flops_per_token(
+            d_model=config["hidden_size"],
+            n_layers=config["num_hidden_layers"],
+            n_heads=config["num_attention_heads"],
+            n_kv_heads=config["num_key_value_heads"],
+            d_ff=config["intermediate_size"],
+            vocab_size=config["vocab_size"], seq_len=4096)
+        assert got == pytest.approx(want, rel=0.01)
+        assert got == pytest.approx(3.605e9, rel=1e-3)
 
 
 # ---------------------------------------------------------------------------
