@@ -193,6 +193,14 @@ def log_round_info(round_index: int, total_rounds: int) -> None:
            "total_rounds": total_rounds})
 
 
+def log_cheetah_init(mesh: Dict[str, int],
+                     loss_head_gathers_per_step: int) -> None:
+    """What a Cheetah trainer decided from its mesh at trace time, once a
+    run (docs/telemetry.md, ``cheetah_init``)."""
+    _emit({"kind": "cheetah_init", "mesh": mesh,
+           "loss_head_gathers_per_step": loss_head_gathers_per_step})
+
+
 def log_training_status(status: str) -> None:
     _emit({"kind": "client_status", "status": status})
 

@@ -19,6 +19,14 @@ Parameter sharding follows the standard recipe:
 - norms: replicated
 
 Activations: batch over (data, fsdp), sequence over (sequence).
+
+XLA places every collective these shardings imply but one: the chunked loss
+(``train_step.lm_loss_chunked``) runs per batch shard in a ``shard_map`` and
+moves the LM head itself: one all-gather over ``fsdp`` of the head's compute
+dtype cast before the chunk scan, one reduce-scatter of its gradient after
+the backward scan (the partitioner put both inside every chunk). Under
+``tensor`` > 1 the gathered head keeps its vocabulary shard and the scan is
+vocabulary-parallel: per chunk only [B, chunk] reductions over ``tensor``.
 """
 
 from __future__ import annotations

@@ -5,7 +5,8 @@ Replaces what the reference delegates to torch DDP + NCCL (SURVEY.md §2.5
 never had. Everything is one compiled program: forward, backward, gradient
 accumulation (``lax.scan`` over microbatches), optimizer update. XLA inserts
 the reduce-scatter/all-gather collectives implied by the shardings — no
-hand-written NCCL calls to port.
+hand-written NCCL calls to port; the one exception is the LM head in the
+chunked loss, which ``lm_loss_chunked`` gathers itself, once a step.
 """
 
 from __future__ import annotations
@@ -20,11 +21,23 @@ import optax
 from flax import struct
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..core import mlops
+
 # names for the step's device work outside the flax modules
 from ..core.mlops.scopes import train_step_scope as _scope
-from .context import mesh_context, sequence_parallelism
-from .sharding import batch_sharding, param_shardings, replicated, unbox
-from .transformer import Transformer, TransformerConfig
+from .context import get_mesh_context, mesh_context, sequence_parallelism
+from .sharding import (
+    FSDP,
+    TENSOR,
+    batch_mesh_axes,
+    batch_sharding,
+    compat_shard_map,
+    logical_to_mesh_spec,
+    param_shardings,
+    replicated,
+    unbox,
+)
+from .transformer import EMBED, VOCAB, Transformer, TransformerConfig
 
 logger = logging.getLogger(__name__)
 
@@ -93,11 +106,69 @@ def lm_loss_chunked(
 ) -> jax.Array:
     """Fused head-matmul + next-token CE, chunked over the sequence.
 
-    ``hidden``: [B, L, D] (bf16), ``w_head``: [D, V]. The full [B, L, V]
-    fp32 logits tensor (≈1 GB at B=4, L=2k, V=32k) is never materialised:
-    each lax.scan step computes one [B, chunk, V] slice, reduces it to CE
-    sums, and discards it — HBM-bandwidth-bound CE becomes MXU-bound.
+    ``hidden``: [B, L, D] (bf16), ``w_head``: [D, V]. Each lax.scan step
+    computes one [B, chunk, V] fp32 logits slice and reduces it to CE sums:
+    HBM-bandwidth-bound CE becomes MXU-bound. (The scan still keeps each
+    chunk's fp32 exponentials for its backward.)
+
+    Under an ambient mesh (``context.mesh_context``) whose batch axes
+    (``data``, ``fsdp``) have a combined extent above 1 the scan runs per
+    batch shard, inside a ``shard_map`` that is manual over the whole mesh:
+    the head, cast to the compute dtype on its shards, is all-gathered over
+    ``fsdp`` ONCE before the scan; every shard scans its own sequences with
+    no collective on the head in the loop; numerator and denominator of the
+    masked mean are summed over the batch axes once after it. The head's
+    gradient therefore leaves the backward scan as one reduce-scatter over
+    ``fsdp`` (the transpose of the gather). Left to the SPMD partitioner the
+    same scan gathers the 262 MB head and reduce-scatters its gradient in
+    every chunk (PERF.md, PR 27).
+
+    Where ``tensor`` shards the vocabulary each shard holds ``V / tensor``
+    columns of the gathered head and the body is vocabulary-parallel
+    (Megatron's CE): the row maximum, the sum of exponentials and the
+    target's logit, picked by the shard's column offset, are reduced over
+    ``tensor`` in each chunk ([B, chunk] floats, no head-sized collective),
+    and the hidden states' cotangent is summed over ``tensor`` once after
+    the scan. That is why the wrap is manual over ``tensor`` too: left to
+    the partitioner (``axis_names={data, fsdp}``) it gathers the head over
+    ``tensor`` as well and every tensor shard computes the whole vocabulary.
+    With batch extent 1 nothing is wrapped and the program is the plain
+    scan.
     """
+    mesh = get_mesh_context()  # None on one device
+    batch_axes = batch_mesh_axes(mesh) if mesh is not None else ()
+    if not batch_axes:
+        num, den = _chunked_ce_sums(hidden, w_head, tokens, mask, chunk)
+        return num / jnp.maximum(den, 1.0)
+
+    tensor = TENSOR if int(mesh.shape[TENSOR]) > 1 else None
+
+    def per_shard(h, w, tok, msk):
+        if int(mesh.shape[FSDP]) > 1:
+            # the gather may not start before the hidden states exist: left
+            # free, the scheduler starts it inside the last block's forward
+            # and the 262 MB head lies across the step's memory peak (step
+            # temporaries 2.83 GB against 2.16 GB with the barrier, and
+            # 2.60 GB before this wrap; compiled for v5e:2x2, PR 27)
+            w, h = jax.lax.optimization_barrier((w, h))
+            w = jax.lax.all_gather(w, FSDP, axis=0, tiled=True)
+        num, den = _chunked_ce_sums(h, w, tok, msk, chunk, vocab_axis=tensor)
+        return jax.lax.psum((num, den), batch_axes)
+
+    rows = P(batch_axes)
+    num, den = compat_shard_map(
+        per_shard, mesh,
+        in_specs=(rows, logical_to_mesh_spec((EMBED, VOCAB)), rows, rows),
+        out_specs=(P(), P()),
+    )(hidden, w_head.astype(hidden.dtype), tokens, mask)
+    return num / jnp.maximum(den, 1.0)
+
+
+def _chunked_ce_sums(hidden, w, tokens, mask, chunk, vocab_axis=None):
+    """(sum of masked next-token CE, sum of the mask) over ``hidden``'s rows,
+    scanned in ``chunk`` positions at a time. ``w`` is the [D, V] head or,
+    inside a shard_map that names ``vocab_axis``, this shard's
+    [D, V / extent] columns of it."""
     B, L, D = hidden.shape
     h = hidden[:, :-1]
     targets = tokens[:, 1:]
@@ -113,16 +184,31 @@ def lm_loss_chunked(
     h = h.reshape(B, steps, chunk, D).swapaxes(0, 1)
     targets = targets.reshape(B, steps, chunk).swapaxes(0, 1)
     m = m.reshape(B, steps, chunk).swapaxes(0, 1)
-    w = w_head.astype(hidden.dtype)
+    w = w.astype(hidden.dtype)
+
+    def ce(logits, tc):
+        if vocab_axis is None:
+            return optax.softmax_cross_entropy_with_integer_labels(logits, tc)
+        # log-sum-exp over the whole vocabulary less the target's logit; the
+        # maximum only stabilises, so no gradient flows through it
+        top = jax.lax.pmax(
+            jax.lax.stop_gradient(logits).max(-1), vocab_axis)
+        sum_exp = jax.lax.psum(
+            jnp.exp(logits - top[..., None]).sum(-1), vocab_axis)
+        local = tc - jax.lax.axis_index(vocab_axis) * logits.shape[-1]
+        mine = (local >= 0) & (local < logits.shape[-1])
+        picked = jnp.take_along_axis(
+            logits, jnp.where(mine, local, 0)[..., None], axis=-1)[..., 0]
+        target = jax.lax.psum(jnp.where(mine, picked, 0.0), vocab_axis)
+        return jnp.log(sum_exp) + top - target
 
     def body(acc, xs):
         hc, tc, mc = xs
         logits = (hc @ w).astype(jnp.float32)
-        per = optax.softmax_cross_entropy_with_integer_labels(logits, tc)
-        return acc + (per * mc).sum(), None
+        return acc + (ce(logits, tc) * mc).sum(), None
 
     total, _ = jax.lax.scan(body, jnp.zeros(()), (h, targets, m))
-    return total / jnp.maximum(m.sum(), 1.0)
+    return total, m.sum()
 
 
 class CheetahTrainer:
@@ -148,6 +234,15 @@ class CheetahTrainer:
         self.loss_chunk = 0 if seq_sharded else int(loss_chunk)
         self._batch_shard = batch_sharding(mesh, seq_sharded)
         self._repl = replicated(mesh)
+        # all-gathers of the LM head in one step's chunked loss, decided at
+        # trace time from the mesh as lm_loss_chunked decides it: one a
+        # microbatch where fsdp shards the head, none where it does not or
+        # the step does not run the chunked loss (docs/telemetry.md)
+        self.loss_head_gathers_per_step = (
+            self.accum_steps
+            if self.loss_chunk > 0 and FSDP in batch_mesh_axes(mesh)
+            else 0
+        )
 
         dummy = jnp.zeros((1, 8), jnp.int32)
         boxed_abstract = jax.eval_shape(
@@ -190,8 +285,14 @@ class CheetahTrainer:
         opt_state = self._commit_replicated(opt_state)
         n_params = sum(int(p.size) for p in jax.tree.leaves(params))
         logger.info(
-            "cheetah init: %.1fM params over mesh %s",
+            "cheetah init: %.1fM params over mesh %s, "
+            "loss_head_gathers_per_step %d",
             n_params / 1e6, dict(self.mesh.shape),
+            self.loss_head_gathers_per_step,
+        )
+        mlops.log_cheetah_init(
+            {k: int(v) for k, v in self.mesh.shape.items()},
+            self.loss_head_gathers_per_step,
         )
         # step must be committed to the mesh (replicated) — a default-device
         # scalar breaks jit after checkpoint restore (mixed device sets)
@@ -260,33 +361,34 @@ class CheetahTrainer:
                 loss = loss + self.cfg.moe_aux_weight * aux
         return loss
 
+    def _loss_and_grads(self, params, tokens, mask):
+        """Loss and gradients of one step's batch: the mean over the
+        microbatches where ``accum_steps > 1``."""
+        if self.accum_steps == 1:
+            return jax.value_and_grad(self._loss_fn)(params, tokens, mask)
+
+        def micro(carry, xs):
+            tok, msk = xs
+            loss, grads = jax.value_and_grad(self._loss_fn)(params, tok, msk)
+            acc_loss, acc_grads = carry
+            return (
+                acc_loss + loss,
+                jax.tree.map(jnp.add, acc_grads, grads),
+            ), None
+
+        with _scope("grad_accum"):
+            zero = jax.tree.map(jnp.zeros_like, params)
+            (loss_sum, grads), _ = jax.lax.scan(
+                micro, (jnp.zeros(()), zero), (tokens, mask)
+            )
+            loss = loss_sum / self.accum_steps
+            grads = jax.tree.map(lambda g: g / self.accum_steps, grads)
+        return loss, grads
+
     def _train_step_raw(self, state: TrainState, tokens, mask):
         """tokens/mask: [accum, micro_batch, L] when accum_steps > 1,
         else [B, L]."""
-        if self.accum_steps > 1:
-
-            def micro(carry, xs):
-                tok, msk = xs
-                loss, grads = jax.value_and_grad(self._loss_fn)(
-                    state.params, tok, msk
-                )
-                acc_loss, acc_grads = carry
-                return (
-                    acc_loss + loss,
-                    jax.tree.map(jnp.add, acc_grads, grads),
-                ), None
-
-            with _scope("grad_accum"):
-                zero = jax.tree.map(jnp.zeros_like, state.params)
-                (loss_sum, grads), _ = jax.lax.scan(
-                    micro, (jnp.zeros(()), zero), (tokens, mask)
-                )
-                loss = loss_sum / self.accum_steps
-                grads = jax.tree.map(lambda g: g / self.accum_steps, grads)
-        else:
-            loss, grads = jax.value_and_grad(self._loss_fn)(
-                state.params, tokens, mask
-            )
+        loss, grads = self._loss_and_grads(state.params, tokens, mask)
         with _scope("optimizer"):
             updates, opt_state = self.opt.update(
                 grads, state.opt_state, state.params

@@ -2,6 +2,9 @@
 sharded train step on the 8-device virtual mesh, and the driver entry points.
 """
 
+import dataclasses
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -122,6 +125,179 @@ class TestShardedTraining:
         s2 = tr2.init_state(jax.random.PRNGKey(0))
         s2, m2 = tr2.train_step(s2, toks2, mask2)
         assert float(m1["loss"]) == pytest.approx(float(m2["loss"]), rel=1e-3)
+
+
+# -- the chunked loss under a mesh (train_step.lm_loss_chunked) ---------------
+
+_COLLECTIVE = re.compile(
+    r"\s(all-gather|all-reduce|reduce-scatter|all-to-all|collective-permute)"
+    r"(-start)?\(")
+_CALLEE = re.compile(
+    r"(?:body|condition|to_apply|calls|true_computation|false_computation)"
+    r"=%?([\w.\-]+)|branch_computations=\{([^}]*)\}")
+_LOSS_SCOPE = re.compile(r'op_name="[^"]*[/(]loss[/)"]')
+
+
+def _computations(hlo_text):
+    """name -> instruction lines of every computation in a compiled HLO."""
+    comps, lines = {}, None
+    for line in hlo_text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{\s*$", line)
+        if head:
+            lines = comps[head.group(1)] = []
+        elif line.rstrip() == "}":
+            lines = None
+        elif lines is not None:
+            lines.append(line)
+    return comps
+
+
+def _reachable(comps, root):
+    """Lines of ``root`` and of every computation it calls, transitively."""
+    seen, todo, lines = set(), [root], []
+    while todo:
+        name = todo.pop()
+        if name in seen or name not in comps:
+            continue
+        seen.add(name)
+        lines += comps[name]
+        for line in comps[name]:
+            for one, many in _CALLEE.findall(line):
+                todo += [one] if one else [
+                    c.strip().lstrip("%") for c in many.split(",")]
+    return seen, lines
+
+
+def _collectives(lines):
+    """(opcode, line) of the collectives that cross devices: a group of one
+    (shard_map's psum over the mesh axes of extent 1) moves nothing."""
+    out = []
+    for line in lines:
+        op = _COLLECTIVE.search(line)
+        groups = re.search(r"replica_groups=(\{\{[^}]*\}(?:,\{[^}]*\})*\}"
+                           r"|\[[\d,]+\]<=)", line)
+        alone = groups and (
+            re.fullmatch(r"\{(\{\d+\},?)+\}", groups.group(1))
+            or re.fullmatch(r"\[\d+,1\]<=", groups.group(1)))
+        if op and not alone:
+            out.append((op.group(1), line))
+    return out
+
+
+def _loss_loop_collectives(hlo_text):
+    """(collectives inside each ``while`` body that holds ops of the ``loss``
+    scope, collectives outside every ``while`` body)."""
+    comps = _computations(hlo_text)
+    inside, in_loops = [], set()
+    for lines in list(comps.values()):
+        for line in lines:
+            body = re.search(r"\swhile\(.*body=%?([\w.\-]+)", line)
+            if not body:
+                continue
+            names, ops = _reachable(comps, body.group(1))
+            in_loops |= names
+            if any(_LOSS_SCOPE.search(op) for op in ops):
+                inside.append(_collectives(ops))
+    outside = _collectives(
+        [l for name, ls in comps.items() if name not in in_loops for l in ls])
+    return inside, outside
+
+
+def _shaped(collectives, opcode, shape):
+    return [l for op, l in collectives
+            if op == opcode and re.search(r"\[%s\]" % shape, l.split(opcode)[0])]
+
+
+@pytest.fixture(scope="module")
+def fp32_cfg(tiny_cfg):
+    return dataclasses.replace(tiny_cfg, dtype=jnp.float32)
+
+
+def _ragged_batch(accum):
+    rng = np.random.RandomState(0)
+    toks = rng.randint(0, 255, (8, 33)).astype(np.int32)
+    ragged = np.ones((8, 33), np.int32)
+    ragged[:, 20:] = 0
+    ragged[3, 5:] = 0
+    shape = (accum, 8 // accum, 33) if accum > 1 else (8, 33)
+    return toks.reshape(shape), ragged.reshape(shape)
+
+
+def _loss_and_grads(cfg, mesh, accum, ragged):
+    # chunks of 8 positions: 4 steps of the loss scan
+    tr = CheetahTrainer(cfg, mesh, accum_steps=accum, loss_chunk=8)
+    params = tr.init_state(jax.random.PRNGKey(0)).params
+    toks, mask = _ragged_batch(accum)
+    toks, mask = tr.shard_batch(
+        jnp.asarray(toks), jnp.asarray(mask if ragged else np.ones_like(mask)))
+    with tr._trace_context():
+        loss, grads = jax.jit(tr._loss_and_grads)(params, toks, mask)
+    return float(loss), jax.device_get(grads)
+
+
+@pytest.fixture(scope="module")
+def one_device_reference(fp32_cfg):
+    """(accum, ragged) -> loss and gradients on a one-device mesh."""
+    cache = {}
+
+    def get(accum, ragged):
+        if (accum, ragged) not in cache:
+            mesh = make_mesh({"fsdp": 1}, devices=jax.devices()[:1])
+            cache[accum, ragged] = _loss_and_grads(fp32_cfg, mesh, accum, ragged)
+        return cache[accum, ragged]
+
+    return get
+
+
+class TestChunkedLossUnderMesh:
+    @pytest.mark.parametrize("accum,ragged", [(1, False), (1, True), (2, True)],
+                             ids=["full_mask", "ragged_mask", "accum2"])
+    @pytest.mark.parametrize("mesh_shape", [
+        {"fsdp": 4}, {"data": 2, "fsdp": 2},
+        {"data": 2, "fsdp": 2, "tensor": 2},
+    ], ids=["fsdp4", "data2_fsdp2", "data2_fsdp2_tensor2"])
+    def test_matches_one_device(self, fp32_cfg, one_device_reference,
+                                mesh_shape, accum, ragged):
+        """Per batch shard, head gathered once, vocabulary-parallel under
+        ``tensor``: the same loss and the same gradient on every leaf."""
+        n = int(np.prod(list(mesh_shape.values())))
+        mesh = make_mesh(mesh_shape, devices=jax.devices()[:n])
+        loss, grads = _loss_and_grads(fp32_cfg, mesh, accum, ragged)
+        want_loss, want = one_device_reference(accum, ragged)
+        assert loss == pytest.approx(want_loss, rel=1e-6)
+        for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(grads),
+                                jax.tree.leaves(want)):
+            rel = np.linalg.norm(g - w) / np.linalg.norm(w)
+            assert rel < 1e-5, (jax.tree_util.keystr(path), rel)
+
+    @pytest.mark.parametrize("mesh_shape,gathers", [
+        ({"fsdp": 4}, 1), ({"fsdp": 1}, 0),
+    ], ids=["fsdp4", "one_device"])
+    def test_head_collectives_in_compiled_step(self, tiny_cfg, mesh_shape,
+                                               gathers):
+        """Under ``fsdp`` the compiled step moves the head once each way and
+        has no collective in the loss scan; on one device it has none at all
+        and no shard_map; ``loss_head_gathers_per_step`` says which."""
+        n = int(np.prod(list(mesh_shape.values())))
+        mesh = make_mesh(mesh_shape, devices=jax.devices()[:n])
+        tr = CheetahTrainer(tiny_cfg, mesh, loss_chunk=8)
+        state = tr.init_state(jax.random.PRNGKey(0))
+        toks = jnp.zeros((8, 33), jnp.int32)
+        hlo = tr.lower_step(
+            state, toks, jnp.ones_like(toks)).compile().as_text()
+        inside, outside = _loss_loop_collectives(hlo)
+        assert tr.loss_head_gathers_per_step == gathers
+        assert len(inside) >= 2  # the forward scan and the backward scan
+        assert all(not loop for loop in inside), inside
+        D, V = tiny_cfg.d_model, tiny_cfg.vocab_size
+        assert len(_shaped(outside, "all-gather", f"{D},{V}")) == gathers
+        scattered = (_shaped(outside, "reduce-scatter", f"{D // n},{V}")
+                     + _shaped(outside, "all-reduce", f"{D},{V}"))
+        assert len(scattered) == gathers
+        # op names carry the name stack: .../loss/shard_map/all_gather
+        assert ("/shard_map/" in hlo) == bool(gathers)
+        if not gathers:
+            assert not outside
 
 
 class TestGraftEntry:

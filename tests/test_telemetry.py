@@ -274,6 +274,24 @@ class TestSpans:
             assert r["dispatch_latency_s"] >= r["phases"]["loss_sync"]
             assert r["examples"] == 8 * 128
 
+    @pytest.mark.parametrize("mesh_shape,accum,gathers", [
+        ("fsdp:8", 1, 1), ("data:8", 1, 0), ("fsdp:4,tensor:2", 2, 2),
+    ])
+    def test_cheetah_init_counts_the_head_gathers(self, tmp_path, mesh_shape,
+                                                  accum, gathers):
+        """One ``cheetah_init`` event a run: what the trainer decided from
+        its mesh, one gather of the head a microbatch where fsdp shards it."""
+        _cheetah_runner(tmp_path, "cinit", total_steps=2, seq_len=32,
+                        mesh_shape=mesh_shape, accum_steps=accum).run()
+        inits = [e for e in mlops.read_events()
+                 if e.get("kind") == "cheetah_init"]
+        assert len(inits) == 1
+        assert inits[0]["loss_head_gathers_per_step"] == gathers
+        want = dict(p.split(":") for p in mesh_shape.split(","))
+        assert {k: v for k, v in inits[0]["mesh"].items() if v > 1} == {
+            k: int(v) for k, v in want.items()}
+        assert len(round_records()) == 2
+
     def test_between_rounds_span_lands_on_the_record_that_closed_last(
             self, tmp_path):
         api = make_api(tmp_path, "between", comm_round=3,
