@@ -21,7 +21,7 @@ from .. import constants
 from ..core.mlops import telemetry
 from ..parallel.sharding import make_mesh
 from ..parallel.train_step import CheetahTrainer, make_optimizer
-from ..parallel.transformer import TransformerConfig
+from ..parallel.transformer import TransformerConfig, train_flops_per_token
 
 logger = logging.getLogger(__name__)
 
@@ -57,23 +57,28 @@ def config_from_args(args) -> TransformerConfig:
             d_ff=int(getattr(args, "d_ff", 2816)),
             max_seq_len=int(getattr(args, "seq_len", 1024)),
         )
-    # knobs beyond the shape: splash kernel blocks (the hd128 MFU lever —
-    # tools/mfu_sweep.py), MoE routing, remat, positional scheme — all
-    # YAML-reachable, applied to EVERY size (the one place the args→config
-    # mapping lives; bundle factories must not re-plumb knobs). Only keys
-    # the config actually carries are passed through, so the
-    # TransformerConfig dataclass defaults stay the single source of truth.
+    # every other field of TransformerConfig is an argument of the same name
+    # (attention kind and its ranks, YaRN, the layer list, the expert layer's
+    # routing and capacity rules, hyper-connections, MTP, norm_eps,
+    # rope_theta, splash blocks, remat ...), applied to EVERY size: the one
+    # place the args→config mapping lives, and the dataclass defaults stay
+    # the single source of truth. A field's default gives the cast.
     import dataclasses as _dc
 
     extra = {}
-    for name, cast in (("attn_block_q", int), ("attn_block_kv", int),
-                       ("moe_experts", int), ("moe_top_k", int),
-                       ("moe_capacity_factor", float),
-                       ("remat", _parse_bool), ("remat_policy", str),
-                       ("pos_emb", str)):
-        if getattr(args, name, None) is not None:
-            extra[name] = cast(getattr(args, name))
+    for field in _dc.fields(TransformerConfig):
+        value = getattr(args, field.name, None)
+        if value is None or field.name in _SHAPE_FIELDS:
+            continue
+        cast = _parse_bool if isinstance(field.default, bool) else type(
+            field.default)
+        extra[field.name] = cast(value)
     return _dc.replace(cfg, **extra) if extra else cfg
+
+
+# set from arguments under other names above (or not from arguments at all)
+_SHAPE_FIELDS = ("vocab_size", "d_model", "n_layers", "n_heads", "n_kv_heads",
+                 "d_ff", "max_seq_len", "dtype", "param_dtype")
 
 
 class CheetahRunner:
@@ -189,12 +194,9 @@ class CheetahRunner:
         tokens_done = 0
         every = int(getattr(self.args, "checkpoint_every_rounds", 0) or 0)
         # per-step telemetry denominators (the Cheetah "round" is a step):
-        # model FLOPs/token for the live MFU gauge, chip peak by device kind
-        cfg = self.cfg
-        flops_tok = telemetry.flops_per_token(
-            cfg.d_model, cfg.n_layers, cfg.n_heads, cfg.n_kv_heads, cfg.d_ff,
-            cfg.vocab_size, self.seq_len,
-        )
+        # model FLOPs/token of this configuration (its attention kind, layer
+        # list and experts held) for the live MFU gauge, chip peak by kind
+        flops_tok = train_flops_per_token(self.cfg, self.seq_len)
         # None off-TPU (MFU "not measured"); an unknown TPU kind raises
         peak = telemetry.peak_bf16_flops(jax.devices()[0])
         n_chips = jax.device_count()
@@ -221,6 +223,10 @@ class CheetahRunner:
                 if rec is not None:
                     rec.dispatch_latency_s = time.perf_counter() - t_dispatch
                     rec.lazy["examples"] = tokens.size
+                    # the step's routing counters: device scalars, realized
+                    # with the loss when the record is emitted (no sync here)
+                    rec.lazy.update((k, v) for k, v in metrics.items()
+                                    if k.startswith("moe_"))
                 telemetry.end_round(rec, train_loss=losses[-1])
                 if rec is not None and rec.wall_s > 0:
                     tps = tokens.size / rec.wall_s

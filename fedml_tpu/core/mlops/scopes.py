@@ -21,6 +21,19 @@ from typing import Tuple
 
 import jax
 
+# parallel/transformer.py, parallel/moe.py: what a configuration adds to the
+# blocks of ``_train_step_raw``; absent from a step whose configuration has
+# no latent attention, hyper-connections, experts or MTP module
+TRAIN_STEP_BLOCKS: Tuple[str, ...] = (
+    "mla",            # LatentAttention_N: low-rank projections, norms, scores
+    "mhc",            # hyper-connection maps, stream reads and writes
+    "sinkhorn",       # inside mhc: the Sinkhorn-Knopp sweeps of H_res
+    "moe_route",      # router scores, top-k, the sort by expert
+    "moe_experts",    # dispatch gather, grouped products, combine
+    "shared_expert",  # the expert every token passes (FeedForward "shared")
+    "mtp",            # the multi-token-prediction module and its loss
+)
+
 # parallel/train_step.py: the jitted ``_train_step_raw``
 TRAIN_STEP: Tuple[str, ...] = (
     "embed",        # token (and learned position) table gathers
@@ -30,7 +43,7 @@ TRAIN_STEP: Tuple[str, ...] = (
     "optimizer",    # opt.update + apply_updates
     "clip",         # inside optimizer: make_optimizer's global-norm clip
     "metrics",      # grad_norm and what else the step reports
-)
+) + TRAIN_STEP_BLOCKS
 
 # simulation/round_engine.py: the jitted ``core`` and the superround scan
 ROUND: Tuple[str, ...] = (

@@ -617,6 +617,9 @@ class RoundRecord:
     in_flight: int = 0
     examples: Optional[float] = None
     train_loss: Optional[float] = None
+    # further device scalars of the unit, by name (a Cheetah step's routing
+    # counters); realized with the two above
+    counters: Dict[str, float] = dataclasses.field(default_factory=dict)
     rounds_per_sec_ema: Optional[float] = None
     hbm_used_mb: Optional[float] = None
     hbm_peak_mb: Optional[float] = None
@@ -745,8 +748,9 @@ def end_round(rec: Optional[RoundRecord],
 def _emit_record(rec: RoundRecord) -> None:
     from . import _emit
 
-    rec.train_loss = _realize(rec.lazy.get("train_loss"))
-    rec.examples = _realize(rec.lazy.get("examples"))
+    rec.train_loss = _realize(rec.lazy.pop("train_loss", None))
+    rec.examples = _realize(rec.lazy.pop("examples", None))
+    rec.counters = {k: _realize(v) for k, v in rec.lazy.items()}
     rec.lazy.clear()
     rec.emitted = True
     _REG.inc("rounds.total")
@@ -937,24 +941,6 @@ def start_sys_perf_sampler(args) -> Optional[SysPerfSampler]:
 # ---------------------------------------------------------------------------
 # MFU estimate (Cheetah)
 # ---------------------------------------------------------------------------
-
-
-def flops_per_token(d_model: int, n_layers: int, n_heads: int,
-                    n_kv_heads: int, d_ff: int, vocab_size: int,
-                    seq_len: int) -> float:
-    """Model FLOPs per token, forward + backward, of a pre-norm GQA + SwiGLU
-    decoder: what ``benchmark/flops/transformer.py`` counts, from the shapes.
-    Per token forward, one multiply-add = 2 FLOPs: q, k, v, o projections,
-    gate, up, down, causal attention scores and values (a query sees
-    ``(seq_len + 1) / 2`` keys on average) and the output head. The embedding
-    table is a row gather and costs none; backward is twice the forward;
-    recomputation under remat is not counted."""
-    head_dim = d_model // n_heads
-    proj = 2 * d_model * head_dim * (2 * n_heads + 2 * n_kv_heads)
-    ffn = 2 * 3 * d_model * d_ff
-    attn = 2 * 2 * n_heads * head_dim * (seq_len + 1) / 2
-    head = 2 * d_model * vocab_size
-    return 3.0 * (n_layers * (proj + ffn + attn) + head)
 
 
 def mfu_estimate(tokens_per_sec: float, flops_per_tok: float,

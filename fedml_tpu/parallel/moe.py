@@ -2,23 +2,43 @@
 
 New-capability work (SURVEY.md §2.5 "Expert parallelism / MoE" — the
 reference has no MoE at all; the ``expert`` mesh axis existed here as a
-constant only). Switch-Transformer-style design, TPU-native:
+constant only). One layer, a routing rule and a capacity rule:
 
-- router: one [D, E] matmul → top-1 (Switch) or top-2 (GShard/Mixtral,
-  ``cfg.moe_top_k=2``) experts per token, with the Switch load-balancing
-  auxiliary loss; top-2 gates renormalised over the chosen pair, second
-  choices fill whatever capacity first choices left
-- capacity-factor dispatch (GShard semantics) via static-shape
-  scatter/gather: each token computes its expert slot with an O(T·E)
-  cumsum and scatter-adds into the [E, capacity, D] buffer (unique
-  destinations — no collisions), combine is a gather; over-capacity tokens
-  drop (pass through the residual unchanged). The r3 one-hot dispatch
-  einsum was O(T·E·C) memory and could not allocate at flagship scale.
-- expert FFNs are ONE stacked param tree [E, ...] vmapped over the expert
-  axis; the logical ``expert`` axis maps to the ``expert`` mesh axis
-  (sharding.LOGICAL_RULES), so under pjit the dispatch/combine einsums
-  lower to the all-to-alls of expert parallelism — no hand-written
-  collectives.
+- **routing** (``cfg.moe_router``). ``softmax``: Switch top-1 or GShard /
+  Mixtral top-2 over softmax scores with the Switch load-balancing auxiliary
+  loss; a single choice keeps its raw probability as gate. ``sigmoid``
+  (DeepSeek-V3's ``noaux_tc``): scores ``s = sigmoid(x W_r)`` in float32,
+  the top ``cfg.moe_top_k`` of ``s + b`` selected, where the bias ``b`` is
+  state no gradient reaches (the ``router_state`` collection; the trainer
+  moves it after each step from the expert loads), gates from ``s`` alone,
+  renormalised over the chosen and scaled by ``cfg.moe_routed_scale``; no
+  auxiliary loss. More than one choice renormalises under either rule.
+- **capacity** (``cfg.moe_capacity_factor``). Above 0: GShard slots,
+  ``factor * k * T / E`` an expert in an ``[E, capacity, D]`` buffer,
+  over-capacity assignments dropped (they pass through the residual
+  unchanged), second choices before first and later tokens before earlier.
+  0: no capacity and no drop: the assignments are sorted by expert and the
+  experts run as grouped matrix products (``jax.lax.ragged_dot``, on a TPU a
+  Mosaic kernel whose grid follows the group sizes) over the tokens that
+  arrived; the buffer is the worst case ``k * T`` rows, the work is not.
+- **the experts held** (``cfg.moe_experts_held``, ``cfg.moe_expert_offset``):
+  expert parallelism seen from one chip. The router keeps its width and
+  routes over all ``cfg.moe_experts``; this program holds the weights of
+  experts ``[offset, offset + held)`` and adds their part of the result.
+  What the absent experts would have added is left out, and nothing stands
+  in for their chips or the exchange.
+- ``cfg.moe_shared_experts`` experts of the same width that every token
+  passes, added to the routed result.
+
+Either way dispatch and combine are row gathers over assignments sorted by
+expert (stable, so GShard's priority order survives inside each group); the
+only scatters are the ones autodiff inserts for the gathers' transposes.
+Expert weights are ONE stacked param tree ``[held, ...]``; the logical
+``expert`` axis maps to the ``expert`` mesh axis (sharding.LOGICAL_RULES).
+
+The layer sows what a step reports into the ``moe_stats`` collection:
+``load`` (assignments to each of the ``E`` experts, before any drop),
+``dropped`` (held assignments over capacity).
 """
 
 from __future__ import annotations
@@ -29,17 +49,44 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
-from .transformer import EMBED, MLP, TransformerConfig
+from ..core.mlops.scopes import train_step_scope as _scope
+from .transformer import EMBED, MLP, FeedForward, TransformerConfig
 
 EXPERT_AXIS = "expert_dim"  # logical name for the stacked-expert axis
 
 
+def route(cfg: TransformerConfig, scores_in: jax.Array, bias=None):
+    """Router logits [T, E] float32 -> (expert [T, k] int32, gate [T, k]
+    float32, auxiliary loss). Choice ``j`` of every token is column ``j``."""
+    E, k = cfg.moe_experts, int(cfg.moe_top_k)
+    if cfg.moe_router == "sigmoid":
+        scores = jax.nn.sigmoid(scores_in)
+        select = scores if bias is None else scores + bias
+        _, expert = jax.lax.top_k(select, k)
+        aux = jnp.zeros((), jnp.float32)
+    else:
+        if k not in (1, 2):
+            raise ValueError(
+                f"the softmax router takes moe_top_k 1 or 2, got {k}")
+        scores = jax.nn.softmax(scores_in, axis=-1)
+        _, expert = jax.lax.top_k(scores, k)
+        # Switch aux loss: E * sum_e frac_e * mean_prob_e. The load fraction
+        # is over ALL k assignments (second-choice hot-spotting is visible
+        # to the regularizer), normalised by k so a balanced router scores 1
+        frac = jax.nn.one_hot(expert, E, dtype=jnp.float32).sum(1).mean(0) / k
+        aux = E * jnp.sum(frac * scores.mean(0))
+    gate = jnp.take_along_axis(scores, expert, axis=-1)
+    if k > 1:  # renormalised over the chosen
+        gate = gate / jnp.maximum(gate.sum(-1, keepdims=True), 1e-9)
+    return expert.astype(jnp.int32), gate * cfg.moe_routed_scale, aux
+
+
 class MoEFeedForward(nn.Module):
-    """Drop-in replacement for the dense FeedForward when cfg.moe_experts>1.
+    """Drop-in replacement for the dense FeedForward in an expert layer.
 
     Returns ``(y, aux_loss)`` — the caller adds ``aux_loss`` (scaled by
-    ``cfg.moe_aux_weight``) to the task loss; without it the router
-    collapses onto one expert.
+    ``cfg.moe_aux_weight``) to the task loss; under the softmax router the
+    router collapses onto one expert without it. Zero under ``sigmoid``.
     """
 
     cfg: TransformerConfig
@@ -47,17 +94,11 @@ class MoEFeedForward(nn.Module):
     @nn.compact
     def __call__(self, x) -> Tuple[jax.Array, jax.Array]:
         cfg = self.cfg
-        E = cfg.moe_experts
-        D, F = cfg.d_model, cfg.d_ff
+        E, held, offset = cfg.moe_experts, cfg.experts_held, cfg.moe_expert_offset
+        D, F = cfg.d_model, cfg.expert_d_ff
         B, L, _ = x.shape
         T = B * L
-        top_k = int(getattr(cfg, "moe_top_k", 1))
-        if top_k not in (1, 2):
-            raise ValueError(f"moe_top_k must be 1 or 2, got {top_k}")
-        # capacity scales with k (GShard/Mixtral): top-2 makes 2T route
-        # assignments, so unscaled capacity would drop most second choices
-        # even under a perfectly balanced router
-        capacity = max(int(cfg.moe_capacity_factor * top_k * T / E), 1)
+        k = int(cfg.moe_top_k)
         init = nn.initializers.normal(0.02)
 
         w_router = self.param(
@@ -67,112 +108,174 @@ class MoEFeedForward(nn.Module):
         w_gate_up = self.param(
             "w_gate_up",
             nn.with_partitioning(init, (EXPERT_AXIS, EMBED, MLP)),
-            (E, D, 2 * F), cfg.param_dtype,
+            (held, D, 2 * F), cfg.param_dtype,
         )
         w_down = self.param(
             "w_down",
             nn.with_partitioning(init, (EXPERT_AXIS, MLP, EMBED)),
-            (E, F, D), cfg.param_dtype,
+            (held, F, D), cfg.param_dtype,
         )
+        bias = None
+        if cfg.moe_router == "sigmoid":
+            # the selection bias: state, not a parameter (no gradient, no
+            # weight decay; train_step moves it from the loads)
+            bias = self.variable(
+                "router_state", "bias", jnp.zeros, (E,), jnp.float32).value
 
         xt = x.reshape(T, D)
-        # routing in fp32 (tiny, numerically sensitive)
-        logits = xt.astype(jnp.float32) @ w_router  # [T, E]
-        probs = jax.nn.softmax(logits, axis=-1)
-        expert_idx = jnp.argmax(probs, axis=-1)  # [T] first choice
-        expert_prob = jnp.take_along_axis(
-            probs, expert_idx[:, None], axis=-1
-        )[:, 0]
+        with _scope("moe_route"):
+            # routing in fp32 (tiny, numerically sensitive): a default
+            # float32 product on a TPU rounds its inputs to bfloat16
+            expert, gate, aux_loss = route(
+                cfg, jnp.matmul(xt.astype(jnp.float32), w_router,
+                                precision=jax.lax.Precision.HIGHEST), bias)
+            # the flat assignment order is (all first choices in token
+            # order, then all second choices, ...): GShard's priority
+            flat_expert = expert.T.reshape(k * T)
+            # a compare-and-sum, not a bincount: no scatter on the device
+            load = (flat_expert[:, None] == jnp.arange(E)).sum(0)  # [E]
+            counts = load[offset:offset + held].astype(jnp.int32)
+            # experts this program holds are 0..held-1, the others `held`
+            local = flat_expert - offset
+            local = jnp.where((local >= 0) & (local < held), local, held)
+            order = jnp.argsort(local, stable=True).astype(jnp.int32)  # [kT]
+            sorted_local = local[order]
+            if cfg.moe_capacity_factor > 0:
+                kept_sorted, slot = _capacity_slots(cfg, sorted_local, counts)
+            else:
+                kept_sorted, slot = sorted_local < held, None
+            # the three index maps of dispatch and combine; an index past the
+            # end reads as a row of zeros: row -> its token, row -> its flat
+            # assignment, (choice, token) -> its row
+            token = jnp.where(kept_sorted, order % T, T)
+            back = jnp.where(kept_sorted, order, k * T)
+            inv = jnp.argsort(order, stable=True).astype(jnp.int32)
+            src = jnp.where(kept_sorted[inv], inv, k * T).reshape(k, T)
+        self.sow("moe_stats", "load", load)
+        self.sow("moe_stats", "dropped",
+                 counts.sum() - kept_sorted.sum().astype(jnp.int32))
 
-        one_hot = jax.nn.one_hot(expert_idx, E, dtype=jnp.float32)  # [T, E]
-        if top_k == 2:
-            # second choice: argmax with the first masked out
-            probs2 = probs * (1.0 - one_hot)
-            idx2 = jnp.argmax(probs2, axis=-1)
-            prob2 = jnp.take_along_axis(probs2, idx2[:, None], axis=-1)[:, 0]
-            one_hot2 = jax.nn.one_hot(idx2, E, dtype=jnp.float32)
-            # GShard/Mixtral-style aux loss: load fraction over ALL k
-            # assignments (second-choice hot-spotting is visible to the
-            # regularizer), normalised by k so a balanced router still
-            # scores 1.0
-            frac = (one_hot + one_hot2).mean(0) / top_k
-        else:
-            one_hot2 = None
-            # Switch aux loss: E * Σ_e frac_e * mean_prob_e over first choices
-            frac = one_hot.mean(0)
-        mean_prob = probs.mean(0)
-        aux_loss = E * jnp.sum(frac * mean_prob)
+        with _scope("moe_experts"):
+            rows = _dispatch(xt.astype(cfg.dtype), token, src)       # [kT, D]
+            if slot is None:
+                out = _grouped_experts(cfg, rows, w_gate_up, w_down, counts)
+            else:
+                out = _slotted_experts(cfg, rows, w_gate_up, w_down, slot)
+            # each choice's row back at its token, weighed by its gate;
+            # dropped and absent assignments add nothing
+            chosen = _combine(out, src, back)                        # [k, T, D]
+            y32 = sum(gate[:, c, None] * chosen[c].astype(jnp.float32)
+                      for c in range(k))
+        y = y32.astype(cfg.dtype).reshape(B, L, D)
+        if cfg.moe_shared_experts:
+            with _scope("shared_expert"):
+                y = y + FeedForward(
+                    cfg, d_ff=F * cfg.moe_shared_experts, name="shared")(x)
+        return y, aux_loss
 
-        # -- sort-based grouped dispatch (r5; VERDICT r4 #4) ----------------
-        # The r4 path scatter-added token rows into the [E·C, D] buffer —
-        # two row-scatters of [T, D] per layer, which TPUs serialize; MoE
-        # measured 40.1% MFU vs the 75.8% dense bar. Sorting the (up to) k·T
-        # assignments by expert makes every group contiguous, so dispatch,
-        # combine, and un-sort are all row-GATHERS (MXU-friendly), with the
-        # only scatters left the unavoidable ones autodiff inserts for the
-        # gather transposes in backward. Priority semantics are unchanged
-        # from GShard: the flat assignment order is (all first choices in
-        # token order, then all second choices), and the stable sort
-        # preserves it within each expert group, so over capacity second
-        # choices drop before first and later tokens before earlier —
-        # byte-identical keep sets to the r4 cumsum dispatch.
-        kT = top_k * T
-        if top_k == 2:
-            flat_expert = jnp.concatenate([expert_idx, idx2]).astype(jnp.int32)
-        else:
-            flat_expert = expert_idx.astype(jnp.int32)
-        order = jnp.argsort(flat_expert, stable=True)      # [kT]
-        sorted_expert = flat_expert[order]
-        sorted_token = (order % T).astype(jnp.int32)       # assignment → token
-        counts = jnp.bincount(flat_expert, length=E)       # [E]
-        group_start = (jnp.cumsum(counts) - counts).astype(jnp.int32)
-        pos_sorted = jnp.arange(kT, dtype=jnp.int32) - group_start[sorted_expert]
-        keep_sorted = pos_sorted < capacity
 
-        xt_c = xt.astype(cfg.dtype)
-        # dispatch: slot (e, c) is filled by sorted assignment
-        # group_start[e] + c when c < counts[e]; one gather, no scatter
-        slot_src = group_start[:, None] + jnp.arange(capacity,
-                                                     dtype=jnp.int32)[None, :]
-        slot_valid = jnp.arange(capacity)[None, :] < counts[:, None]  # [E, C]
-        tok_for_slot = sorted_token[jnp.clip(slot_src, 0, kT - 1)]
-        expert_in = jnp.where(
-            slot_valid[..., None], xt_c[tok_for_slot], 0
-        )  # [E, C, D]
+def _rows(x, index):
+    """``x[index]`` along axis 0, a row of zeros where ``index`` is past the
+    end."""
+    return jnp.take(x, index, axis=0, mode="fill", fill_value=0)
 
-        def ffn(gu_w, down_w, h):
-            gu = jnp.einsum("cd,df->cf", h, gu_w.astype(cfg.dtype))
-            gate, up = jnp.split(gu, 2, axis=-1)
-            return jnp.einsum(
-                "cf,fd->cd", nn.silu(gate) * up, down_w.astype(cfg.dtype)
-            )
 
-        expert_out = jax.vmap(ffn)(w_gate_up, w_down, expert_in)  # [E, C, D]
+@jax.custom_vjp
+def _dispatch(x, token, src):
+    """Tokens [T, D] -> the assignments' rows [kT, D], sorted by expert:
+    row ``p`` is token ``token[p]`` (zeros where the assignment is dropped or
+    its expert held elsewhere). Its transpose is :func:`_combine` summed over
+    the choices, so the backward pass gathers too: autodiff's own transpose
+    of a gather is a scatter-add, which a TPU serialises."""
+    del src
+    return _rows(x, token)
 
-        # combine: gather each sorted assignment's slot output, un-sort via
-        # the inverse permutation (another gather), and gate-weight per
-        # choice; dropped assignments (keep=0) contribute nothing and pass
-        # through the residual unchanged
-        flat_out = expert_out.reshape(E * capacity, D)
-        slot_of_sorted = jnp.clip(
-            sorted_expert * capacity + pos_sorted, 0, E * capacity - 1
-        )
-        out_sorted = (
-            flat_out[slot_of_sorted].astype(jnp.float32)
-            * keep_sorted[:, None]
-        )  # [kT, D]
-        inv = jnp.argsort(order, stable=True)
-        out_flat = out_sorted[inv]          # original assignment order
-        keep_flat = keep_sorted[inv]
-        if top_k == 2:
-            keep1, keep2 = keep_flat[:T], keep_flat[T:]
-            # renormalised pair gates (Mixtral: softmax over the chosen two)
-            denom = jnp.maximum(expert_prob + prob2, 1e-9)
-            gate1 = (expert_prob / denom) * keep1
-            gate2 = (prob2 / denom) * keep2
-            y32 = out_flat[:T] * gate1[:, None] + out_flat[T:] * gate2[:, None]
-        else:
-            gate1 = expert_prob * keep_flat
-            y32 = out_flat * gate1[:, None]
-        y = y32.astype(cfg.dtype)
-        return y.reshape(B, L, D), aux_loss
+
+def _dispatch_fwd(x, token, src):
+    return _rows(x, token), src
+
+
+def _dispatch_bwd(src, g):
+    return (_rows(g, src).astype(jnp.float32).sum(0).astype(g.dtype),
+            None, None)
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _combine(rows, src, back):
+    """The experts' output rows [kT, D] -> [k, T, D]: choice ``c`` of token
+    ``t`` is row ``src[c, t]`` (zeros where it has none). Rows that belong
+    to no kept assignment are never read, whatever the kernel left there.
+    Its transpose reads cotangent ``back[p]`` (a flat ``c * T + t``) for row
+    ``p``: a gather again."""
+    del back
+    return _rows(rows, src)
+
+
+def _combine_fwd(rows, src, back):
+    return _rows(rows, src), back
+
+
+def _combine_bwd(back, g):
+    return _rows(g.reshape(-1, g.shape[-1]), back), None, None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+def _swiglu(h):
+    gate, up = jnp.split(h, 2, axis=-1)
+    return nn.silu(gate) * up
+
+
+def _capacity_slots(cfg, sorted_local, counts):
+    """GShard's capacity: which sorted assignments are kept (their expert is
+    held here and they are among its first ``capacity``), and the slot
+    ``expert * capacity + position`` of each in the ``[held, capacity]``
+    buffer."""
+    held, kT = counts.shape[0], sorted_local.shape[0]
+    # capacity scales with k (GShard/Mixtral): top-2 makes 2T route
+    # assignments, so unscaled capacity would drop most second choices
+    # even under a perfectly balanced router
+    capacity = max(int(cfg.moe_capacity_factor * kT / cfg.moe_experts), 1)
+    group_start = (jnp.cumsum(counts) - counts).astype(jnp.int32)
+    group = jnp.minimum(sorted_local, held - 1)
+    pos = jnp.arange(kT, dtype=jnp.int32) - group_start[group]
+    kept = (sorted_local < held) & (pos < capacity)
+    return kept, jnp.where(kept, group * capacity + pos, held * capacity)
+
+
+def _slotted_experts(cfg, rows, w_gate_up, w_down, slot):
+    """Each held expert computes ``capacity`` rows, whatever arrived: the
+    sorted rows go to their slots of an ``[held, capacity, D]`` buffer (a
+    gather by the slots' inverse: a kept row's slot is unique), through the
+    vmapped SwiGLU, and back."""
+    held = w_gate_up.shape[0]
+    kT, D = rows.shape
+    capacity = max(int(cfg.moe_capacity_factor * kT / cfg.moe_experts), 1)
+    n_slots = held * capacity
+    # slot -> the row that fills it (kT where none does)
+    filler = jnp.full((n_slots + 1,), kT, jnp.int32).at[slot].set(
+        jnp.arange(kT, dtype=jnp.int32), mode="drop")[:n_slots]
+    expert_in = _rows(rows, filler).reshape(held, capacity, D)
+
+    def ffn(gu_w, down_w, h):
+        gu = jnp.einsum("cd,df->cf", h, gu_w.astype(cfg.dtype))
+        return jnp.einsum("cf,fd->cd", _swiglu(gu), down_w.astype(cfg.dtype))
+
+    expert_out = jax.vmap(ffn)(w_gate_up, w_down, expert_in)
+    return _rows(expert_out.reshape(n_slots, D), slot)
+
+
+def _grouped_experts(cfg, rows, w_gate_up, w_down, counts):
+    """No capacity: the sorted rows, held experts first, go through two
+    grouped products whose groups are the experts' arrivals. Rows past the
+    last group (assignments to experts held elsewhere) belong to no group:
+    the kernel computes nothing for them, and :func:`_combine` never reads
+    what it leaves there."""
+    h = jax.lax.ragged_dot(rows, w_gate_up.astype(cfg.dtype), counts,
+                           preferred_element_type=cfg.dtype)
+    return jax.lax.ragged_dot(_swiglu(h), w_down.astype(cfg.dtype), counts,
+                              preferred_element_type=cfg.dtype)

@@ -16,7 +16,12 @@ Parameter sharding follows the standard recipe:
 - MLP down     [ff, d]:        (tensor, fsdp)
 - embedding    [vocab, d]:     (tensor, fsdp) — vocab-parallel
 - lm head      [d, vocab]:     (fsdp, tensor)
-- norms: replicated
+- latent attention: down-projections [d, rank] (fsdp, -), up-projections
+  [rank, heads*hd] (-, tensor), out [heads*v, d] (tensor, fsdp)
+- hyper-connection maps [streams*d, 2n + n^2]: (fsdp, -)
+- experts [held, d, 2f] / [held, f, d]: (expert, fsdp, tensor) /
+  (expert, tensor, fsdp); router [d, E]: (fsdp, -)
+- norms, biases, gains: replicated
 
 Activations: batch over (data, fsdp), sequence over (sequence).
 
@@ -40,7 +45,7 @@ from flax import linen as nn
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .. import constants
-from .transformer import BATCH, EMBED, HEADS, KV, LENGTH, MLP, VOCAB
+from .transformer import BATCH, EMBED, HEADS, KV, LENGTH, LORA, MLP, VOCAB
 
 logger = logging.getLogger(__name__)
 
@@ -62,6 +67,7 @@ LOGICAL_RULES = (
     (VOCAB, TENSOR),
     (HEADS, TENSOR),
     (KV, None),
+    (LORA, None),   # the low-rank side of a latent projection
     (MLP, TENSOR),
     (BATCH, (DATA, FSDP)),
     (LENGTH, SEQUENCE),

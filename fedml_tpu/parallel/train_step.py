@@ -61,6 +61,10 @@ class TrainState:
     step: jax.Array
     params: PyTree
     opt_state: PyTree
+    # what the model carries from step to step that the optimizer does not
+    # own: the expert layers' selection bias (flax collection
+    # ``router_state``). Empty for a model that has none
+    model_state: PyTree = struct.field(default_factory=dict)
 
 
 def make_optimizer(
@@ -211,6 +215,54 @@ def _chunked_ce_sums(hidden, w, tokens, mask, chunk, vocab_axis=None):
     return total, m.sum()
 
 
+def _model_state(variables) -> PyTree:
+    """The collections of ``model.init``'s variables that the step carries
+    and the optimizer does not own, as ``{collection: tree}``."""
+    return {k: unbox(v) for k, v in variables.items() if k == "router_state"}
+
+
+def _sown(tree, name):
+    """Every value sown under ``name``, in module order, whatever the depth
+    (``sow`` keeps a tuple per call site)."""
+    found = []
+    for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]:
+        if any(getattr(k, "key", None) == name for k in path):
+            found.append(leaf)
+    return found
+
+
+def _routing_metrics(cfg: TransformerConfig, stats) -> dict:
+    """What a step reports of its routing: assignments that reached an expert
+    held here (of ``moe_top_k x tokens`` a layer, summed over the expert
+    layers), the busiest held expert's load in any layer, and held
+    assignments dropped over capacity."""
+    lo = cfg.moe_expert_offset
+    loads = jnp.stack(_sown(stats, "load"))  # [expert layers, E]
+    held = loads[:, lo:lo + cfg.experts_held]
+    return {"moe_assignments_held": held.sum(),
+            "moe_max_expert_load": held.max(),
+            "moe_dropped": sum(jnp.sum(d) for d in _sown(stats, "dropped"))}
+
+
+def _move_selection_bias(cfg: TransformerConfig, model_state, stats):
+    """``b_e += rate * sign(mean load - load_e)`` in every expert layer, from
+    the step's counts over all routed experts: DeepSeek-V3's auxiliary-loss-
+    free balancing. No gradient, no weight decay, no optimizer."""
+    if "router_state" not in model_state:
+        return model_state
+
+    def move(bias_tree, stats_tree):
+        if "bias" in bias_tree and "load" in stats_tree:
+            load = jnp.sum(jnp.stack(stats_tree["load"]), 0).astype(jnp.float32)
+            return {**bias_tree, "bias": bias_tree["bias"]
+                    + cfg.moe_bias_rate * jnp.sign(load.mean() - load)}
+        return {k: move(v, stats_tree[k]) if k in stats_tree else v
+                for k, v in bias_tree.items()}
+
+    return {**model_state,
+            "router_state": move(model_state["router_state"], stats)}
+
+
 class CheetahTrainer:
     """Builds and owns the sharded init + train step for one config/mesh."""
 
@@ -253,10 +305,13 @@ class CheetahTrainer:
             param_shardings(mesh, boxed_abstract["params"]),
             is_leaf=lambda x: isinstance(x, NamedSharding),
         )
+        # shapes of the state the optimizer does not own (all zeros at init)
+        self._model_state_abstract = _model_state(boxed_abstract)
 
         self._init_jit = jax.jit(
             self._init_raw,
-            out_shardings={"params": self.param_shardings},
+            out_shardings={"params": self.param_shardings,
+                           "model_state": self._repl},
         )
         self._step_jit = jax.jit(self._train_step_raw, donate_argnums=(0,))
 
@@ -264,23 +319,30 @@ class CheetahTrainer:
     def _init_raw(self, rng):
         dummy = jnp.zeros((1, 8), jnp.int32)
         variables = self.model.init(rng, dummy)
-        return {"params": unbox(variables["params"])}
+        return {"params": unbox(variables["params"]),
+                "model_state": _model_state(variables)}
 
     def _commit_replicated(self, opt_state):
         """jit(opt.init) leaves scalar state (e.g. adam's count) on a single
         device; commit such leaves to the full mesh (replicated) so the
-        train step sees one consistent device set (also post-restore)."""
+        train step sees one consistent device set (also post-restore). On a
+        one-device mesh too: a scalar that is on the right device but not
+        under the mesh's sharding has another abstract type than the step
+        returns for it, and the second step would trace and compile again."""
+        def stray(x):
+            return (len(x.sharding.device_set) < self.mesh.size
+                    or not isinstance(x.sharding, NamedSharding))
+
         return jax.tree.map(
             lambda x: jax.device_put(x, self._repl)
-            if isinstance(x, jax.Array)
-            and len(x.sharding.device_set) < self.mesh.size
-            else x,
+            if isinstance(x, jax.Array) and stray(x) else x,
             opt_state,
         )
 
     def init_state(self, rng: jax.Array) -> TrainState:
         with self.mesh:
-            params = self._init_jit(rng)["params"]
+            init = self._init_jit(rng)
+            params, model_state = init["params"], init["model_state"]
             opt_state = jax.jit(self.opt.init)(params)
         opt_state = self._commit_replicated(opt_state)
         n_params = sum(int(p.size) for p in jax.tree.leaves(params))
@@ -293,11 +355,15 @@ class CheetahTrainer:
         mlops.log_cheetah_init(
             {k: int(v) for k, v in self.mesh.shape.items()},
             self.loss_head_gathers_per_step,
+            layers=list(self.cfg.layer_kinds),
+            n_routed_experts=int(self.cfg.moe_experts),
+            experts_held=int(self.cfg.experts_held),
         )
         # step must be committed to the mesh (replicated) — a default-device
         # scalar breaks jit after checkpoint restore (mixed device sets)
         step = jax.device_put(jnp.zeros((), jnp.int32), self._repl)
-        return TrainState(step=step, params=params, opt_state=opt_state)
+        return TrainState(step=step, params=params, opt_state=opt_state,
+                          model_state=model_state)
 
     def state_from_params(self, params: PyTree) -> TrainState:
         """Fresh TrainState around externally-provided params.
@@ -326,69 +392,105 @@ class CheetahTrainer:
         with self.mesh:
             params = jax.tree.map(fresh, params, self.param_shardings)
             opt_state = jax.jit(self.opt.init)(params)
+            # the model's own state starts afresh with the optimizer's
+            model_state = jax.tree.map(
+                lambda s: jax.device_put(jnp.zeros(s.shape, s.dtype),
+                                         self._repl),
+                self._model_state_abstract)
         opt_state = self._commit_replicated(opt_state)
         step = jax.device_put(jnp.zeros((), jnp.int32), self._repl)
-        return TrainState(step=step, params=params, opt_state=opt_state)
+        return TrainState(step=step, params=params, opt_state=opt_state,
+                          model_state=model_state)
 
     # -- train step ---------------------------------------------------------
-    def _loss_fn(self, params, tokens, mask):
-        moe = self.cfg.moe_experts > 1
-        mutable = ["losses"] if moe else False
+    def _loss_fn(self, params, model_state, tokens, mask):
+        """(loss, what the expert layers sowed into ``moe_stats``)."""
+        cfg = self.cfg
+        moe = cfg.moe_experts > 1
+        mtp = cfg.mtp_layers > 0
+        mutable = ["losses", "moe_stats"] if moe else False
+        variables = {"params": params, **model_state}
+        kwargs = dict(mask=None, mutable=mutable)
+        if mtp:
+            kwargs["return_mtp"] = True
         if self.loss_chunk > 0:
-            out = self.model.apply(
-                {"params": params}, tokens, mask=None, return_hidden=True,
-                mutable=mutable,
-            )
-            hidden, var_col = out if moe else (out, {})
+            out = self.model.apply(variables, tokens, return_hidden=True,
+                                   **kwargs)
+            out, var_col = out if moe else (out, {})
+            hidden, mtp_hidden = out if mtp else (out, None)
             with _scope("loss"):
                 loss = lm_loss_chunked(
                     hidden, params["w_lm_head"], tokens, mask,
                     self.loss_chunk
                 )
         else:
-            out = self.model.apply(
-                {"params": params}, tokens, mask=None, mutable=mutable
-            )
-            logits, var_col = out if moe else (out, {})
+            out = self.model.apply(variables, tokens, **kwargs)
+            out, var_col = out if moe else (out, {})
+            logits, mtp_hidden = out if mtp else (out, None)
             with _scope("loss"):
                 loss = lm_loss(logits, tokens, mask)
-        if moe:
+        if mtp:
+            # position i of the module predicts token i + 2: the same loss
+            # through the same head on the tokens moved up by one, the
+            # wrapped last position masked out
+            with _scope("mtp"), _scope("loss"):
+                nxt = jnp.roll(tokens, -1, axis=1)
+                nxt_mask = jnp.roll(mask, -1, axis=1).at[:, -1].set(0)
+                if self.loss_chunk > 0:
+                    mtp_loss = lm_loss_chunked(
+                        mtp_hidden, params["w_lm_head"], nxt, nxt_mask,
+                        self.loss_chunk)
+                else:
+                    mtp_loss = lm_loss(
+                        jnp.einsum("bld,dv->blv", mtp_hidden,
+                                   params["w_lm_head"].astype(mtp_hidden.dtype)
+                                   ).astype(jnp.float32), nxt, nxt_mask)
+                loss = loss + cfg.mtp_weight * mtp_loss
+        if moe and cfg.moe_router == "softmax":
             with _scope("loss"):
                 aux = sum(
                     jnp.sum(jnp.asarray(v))
                     for v in jax.tree.leaves(var_col.get("losses", {}))
                 )
-                loss = loss + self.cfg.moe_aux_weight * aux
-        return loss
+                loss = loss + cfg.moe_aux_weight * aux
+        return loss, var_col.get("moe_stats", {})
 
-    def _loss_and_grads(self, params, tokens, mask):
-        """Loss and gradients of one step's batch: the mean over the
-        microbatches where ``accum_steps > 1``."""
+    def _loss_and_grads(self, params, model_state, tokens, mask):
+        """Loss, gradients and summed ``moe_stats`` of one step's batch: the
+        mean over the microbatches where ``accum_steps > 1``."""
+        grad_fn = jax.value_and_grad(self._loss_fn, has_aux=True)
         if self.accum_steps == 1:
-            return jax.value_and_grad(self._loss_fn)(params, tokens, mask)
+            (loss, stats), grads = grad_fn(params, model_state, tokens, mask)
+            return loss, grads, stats
 
         def micro(carry, xs):
             tok, msk = xs
-            loss, grads = jax.value_and_grad(self._loss_fn)(params, tok, msk)
-            acc_loss, acc_grads = carry
+            (loss, stats), grads = grad_fn(params, model_state, tok, msk)
+            acc_loss, acc_grads, acc_stats = carry
             return (
                 acc_loss + loss,
                 jax.tree.map(jnp.add, acc_grads, grads),
+                jax.tree.map(jnp.add, acc_stats, stats),
             ), None
 
         with _scope("grad_accum"):
             zero = jax.tree.map(jnp.zeros_like, params)
-            (loss_sum, grads), _ = jax.lax.scan(
-                micro, (jnp.zeros(()), zero), (tokens, mask)
+            zero_stats = jax.tree.map(
+                jnp.zeros_like,
+                jax.eval_shape(lambda: self._loss_fn(
+                    params, model_state, tokens[0], mask[0])[1]))
+            (loss_sum, grads, stats), _ = jax.lax.scan(
+                micro, (jnp.zeros(()), zero, zero_stats), (tokens, mask)
             )
             loss = loss_sum / self.accum_steps
             grads = jax.tree.map(lambda g: g / self.accum_steps, grads)
-        return loss, grads
+        return loss, grads, stats
 
     def _train_step_raw(self, state: TrainState, tokens, mask):
         """tokens/mask: [accum, micro_batch, L] when accum_steps > 1,
         else [B, L]."""
-        loss, grads = self._loss_and_grads(state.params, tokens, mask)
+        loss, grads, stats = self._loss_and_grads(
+            state.params, state.model_state, tokens, mask)
         with _scope("optimizer"):
             updates, opt_state = self.opt.update(
                 grads, state.opt_state, state.params
@@ -396,8 +498,14 @@ class CheetahTrainer:
             params = optax.apply_updates(state.params, updates)
         with _scope("metrics"):
             metrics = {"loss": loss, "grad_norm": optax.global_norm(grads)}
+            model_state = state.model_state
+            if stats:
+                metrics.update(_routing_metrics(self.cfg, stats))
+                model_state = _move_selection_bias(
+                    self.cfg, state.model_state, stats)
         return (
-            TrainState(step=state.step + 1, params=params, opt_state=opt_state),
+            TrainState(step=state.step + 1, params=params,
+                       opt_state=opt_state, model_state=model_state),
             metrics,
         )
 

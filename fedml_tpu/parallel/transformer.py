@@ -14,16 +14,25 @@ TPU-first choices:
   names; ``sharding.py`` maps logical → mesh axes (dp/fsdp/tensor/sequence),
   so the same module runs 1-chip or pod-scale unchanged
 - no data-dependent Python control flow — the whole stack jits once
+
+The same model class and layer loop also build what is not Llama's block, from
+the configuration alone (``TransformerConfig``): multi-head latent attention
+(``LatentAttention``), a layer list of leading dense layers then expert
+layers (``parallel/moe.py``), hyper-connected residual streams around every
+sublayer (``HyperConnection``) and a multi-token-prediction module. A
+configuration that names none of them builds the block it always built.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import logging
+import math
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from flax import linen as nn
 
 # names for what no flax module wraps; the modules name the rest
@@ -39,6 +48,7 @@ KV = "kv"
 MLP = "mlp"
 BATCH = "batch"
 LENGTH = "length"
+LORA = "lora"  # the low-rank side of a latent projection: never sharded
 
 
 @dataclasses.dataclass(frozen=True)
@@ -65,6 +75,9 @@ class TransformerConfig:
     # 1 = Switch top-1 routing; 2 = GShard/Mixtral top-2 (renormalised gates,
     # second choice fills capacity left by first choices)
     moe_top_k: int = 1
+    # > 0: slots per expert as a factor of the balanced load, over-capacity
+    # tokens dropped. 0: no capacity and no drop; the experts' grouped
+    # products run over the tokens that arrived
     moe_capacity_factor: float = 1.25
     moe_aux_weight: float = 0.01
     # "auto": Pallas splash attention on TPU, XLA elsewhere. "splash" /
@@ -84,10 +97,110 @@ class TransformerConfig:
     # rotary models can converge to per-client-rotated solutions whose
     # average destroys the task — measured on the prefix-LM seq2seq head.
     pos_emb: str = "rope"
+    # -- attention kind ------------------------------------------------------
+    # "gqa": fused q, k, v at one head size. "mla": multi-head latent
+    # attention (LatentAttention): low-rank q and kv with their norms, a
+    # query/key head of qk_nope_head_dim + qk_rope_head_dim of which only the
+    # rope part is rotated (its key shared by all heads), a value head of
+    # v_head_dim
+    attn_kind: str = "gqa"
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # YaRN (rope_factor > 1): inverse frequencies ramp from interpolated
+    # (divided by the factor) to extrapolated between the dimensions that
+    # turn rope_beta_slow and rope_beta_fast times in rope_original_max_pos
+    # positions; independent of the sequence length. rope_mscale_all_dim > 0
+    # scales the attention scores by (0.1 * mscale_all_dim * ln factor + 1)^2
+    rope_factor: float = 1.0
+    rope_original_max_pos: int = 0
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 1.0
+    rope_mscale_all_dim: float = 0.0
+    # -- layer list ----------------------------------------------------------
+    # with moe_experts > 1: this many leading dense layers (d_ff wide), then
+    # expert layers. 0 = every layer an expert layer (the Switch stacks)
+    first_k_dense: int = 0
+    # -- expert layer (parallel/moe.py): a routing rule and a capacity rule --
+    # "softmax": Switch / GShard scores and auxiliary loss. "sigmoid": scores
+    # sigmoid(x W_r), selection by score + bias (``noaux_tc``; the bias is
+    # state the optimizer does not own, moved after each step from the
+    # expert loads by moe_bias_rate), weights renormalised over the chosen
+    # and scaled by moe_routed_scale
+    moe_router: str = "softmax"
+    moe_routed_scale: float = 1.0
+    moe_bias_rate: float = 1e-3
+    # width of one expert (0 = d_ff) and shared experts of that width that
+    # every token passes
+    moe_d_ff: int = 0
+    moe_shared_experts: int = 0
+    # the share of the routed experts this program holds (expert
+    # parallelism, one chip's part): routes over all moe_experts, computes
+    # experts [offset, offset + held); 0 = all of them
+    moe_experts_held: int = 0
+    moe_expert_offset: int = 0
+    # -- residual path -------------------------------------------------------
+    # hc_mult > 1: manifold-constrained hyper-connections (HyperConnection):
+    # that many residual streams through every sublayer
+    hc_mult: int = 1
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_clamp: float = 30.0
+    # -- multi-token prediction ---------------------------------------------
+    # that many extra blocks (0 or 1), each predicting one token further
+    # through the shared embedding and head; its loss weighs mtp_weight
+    mtp_layers: int = 0
+    mtp_weight: float = 0.3
+
+    def __post_init__(self):
+        if self.attn_kind not in ("gqa", "mla"):
+            raise ValueError(f"attn_kind must be gqa|mla, got {self.attn_kind!r}")
+        if self.attn_kind == "mla" and not (
+                self.q_lora_rank > 0 and self.kv_lora_rank > 0
+                and self.qk_nope_head_dim > 0 and self.qk_rope_head_dim > 0
+                and self.v_head_dim > 0):
+            raise ValueError(
+                "attn_kind mla needs q_lora_rank, kv_lora_rank, "
+                "qk_nope_head_dim, qk_rope_head_dim and v_head_dim")
+        if self.moe_router not in ("softmax", "sigmoid"):
+            raise ValueError(
+                f"moe_router must be softmax|sigmoid, got {self.moe_router!r}")
+        if self.mtp_layers not in (0, 1):
+            raise ValueError(f"mtp_layers must be 0 or 1, got {self.mtp_layers}")
+        held, E = self.moe_experts_held, self.moe_experts
+        if held and not 0 <= self.moe_expert_offset <= E - held:
+            raise ValueError(
+                f"experts [{self.moe_expert_offset}, "
+                f"{self.moe_expert_offset + held}) are not among {E}")
 
     @property
     def head_dim(self) -> int:
         return self.d_model // self.n_heads
+
+    @property
+    def rope_dim(self) -> int:
+        """How many dimensions of a query / key head are rotated."""
+        return (self.qk_rope_head_dim if self.attn_kind == "mla"
+                else self.head_dim)
+
+    @property
+    def layer_kinds(self) -> Tuple[str, ...]:
+        """"dense" or "moe" for each of the n_layers blocks, in order."""
+        if self.moe_experts <= 1:
+            return ("dense",) * self.n_layers
+        k = min(self.first_k_dense, self.n_layers)
+        return ("dense",) * k + ("moe",) * (self.n_layers - k)
+
+    @property
+    def experts_held(self) -> int:
+        return self.moe_experts_held or self.moe_experts
+
+    @property
+    def expert_d_ff(self) -> int:
+        return self.moe_d_ff or self.d_ff
 
     @staticmethod
     def llama2_7b() -> "TransformerConfig":
@@ -99,6 +212,46 @@ class TransformerConfig:
             vocab_size=vocab_size, d_model=128, n_layers=2, n_heads=4,
             n_kv_heads=2, d_ff=384, max_seq_len=128, remat=False,
         )
+
+
+def train_flops_per_token(cfg: TransformerConfig, seq_len: int) -> float:
+    """Model FLOPs per token, forward + backward, of ``cfg`` as it is built:
+    what the live ``cheetah.mfu_estimate`` gauge divides by. Per token
+    forward, one multiply-add = 2 FLOPs: the attention projections (fused
+    q, k, v and o, or MLA's five), causal scores and values (a query sees
+    ``(seq_len + 1) / 2`` keys on average), each layer's SwiGLU (dense, or
+    the router, the shared experts and the expected share of the routed
+    experts held here), the hyper-connection maps and the output head (twice
+    with an MTP module, which also adds a block and its projection). The
+    embedding is a row gather and costs none; backward is twice the forward;
+    recomputation under remat is not counted. ``benchmark/flops`` counts the
+    same from the published keys."""
+    D, H = cfg.d_model, cfg.n_heads
+    if cfg.attn_kind == "mla":
+        dqk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+        proj = 2 * (D * cfg.q_lora_rank + cfg.q_lora_rank * H * dqk
+                    + D * (cfg.kv_lora_rank + cfg.qk_rope_head_dim)
+                    + cfg.kv_lora_rank * H * (cfg.qk_nope_head_dim
+                                              + cfg.v_head_dim)
+                    + H * cfg.v_head_dim * D)
+        attn = 2 * H * (dqk + cfg.v_head_dim) * (seq_len + 1) / 2
+    else:
+        proj = 2 * D * cfg.head_dim * (2 * H + 2 * cfg.n_kv_heads)
+        attn = 2 * 2 * H * cfg.head_dim * (seq_len + 1) / 2
+    n = cfg.hc_mult
+    hyper = 2 * 2 * (n * D) * (2 * n + n * n) if n > 1 else 0
+    dense = 2 * 3 * D * cfg.d_ff
+    held = cfg.moe_top_k * cfg.experts_held / max(cfg.moe_experts, 1)
+    expert = (2 * D * cfg.moe_experts
+              + (cfg.moe_shared_experts + held) * 2 * 3 * D * cfg.expert_d_ff)
+    head = 2 * D * cfg.vocab_size
+    kinds = cfg.layer_kinds
+    forward = (len(kinds) * (proj + attn + hyper) + head
+               + sum(expert if kind == "moe" else dense for kind in kinds))
+    if cfg.mtp_layers:
+        forward += (proj + attn + hyper + 2 * 2 * D * D + head
+                    + (expert if cfg.moe_experts > 1 else dense))
+    return 3.0 * forward
 
 
 def rms_norm(x: jax.Array, weight: jax.Array, eps: float) -> jax.Array:
@@ -121,15 +274,74 @@ class RMSNorm(nn.Module):
         return rms_norm(x, w.astype(x.dtype), self.eps)
 
 
+def yarn_inv_freq(dim: int, theta: float, factor: float, original_max_pos: int,
+                  beta_fast: float, beta_slow: float) -> np.ndarray:
+    """YaRN's inverse frequencies [dim/2], float32: dimension ``i`` turns
+    ``original_max_pos * theta**(-2i/dim) / 2pi`` times over the original
+    context; those that turn more than ``beta_fast`` times keep their
+    frequency (extrapolation), those that turn fewer than ``beta_slow`` times
+    have it divided by ``factor`` (interpolation), and a linear ramp over the
+    dimension index joins the two. A function of the configuration alone."""
+    def turns_at(n_rot):  # the (fractional) dimension that turns n_rot times
+        return (dim * math.log(original_max_pos / (n_rot * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(turns_at(beta_fast)), 0)
+    high = min(math.ceil(turns_at(beta_slow)), dim - 1)
+    extra = 1.0 / theta ** (np.arange(0, dim, 2, dtype=np.float32) / dim)
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float32) - low)
+                   / max(high - low, 1e-3), 0.0, 1.0)
+    return (extra / factor * ramp + extra * (1.0 - ramp)).astype(np.float32)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
 def rotary_embedding(
-    positions: jax.Array, head_dim: int, theta: float
+    positions: jax.Array, head_dim: int, theta: float,
+    inv_freq: Optional[np.ndarray] = None, scale: float = 1.0,
 ) -> Tuple[jax.Array, jax.Array]:
-    """cos/sin tables for the given positions: [*, L, head_dim/2] fp32."""
-    freqs = 1.0 / (
-        theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim)
-    )
+    """cos/sin tables for the given positions: [*, L, head_dim/2] fp32.
+    ``inv_freq`` replaces the plain ``theta**(-2i/head_dim)`` (YaRN), and
+    ``scale`` multiplies both tables (YaRN's mscale ratio)."""
+    if inv_freq is None:
+        freqs = 1.0 / (
+            theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim)
+        )
+    else:
+        freqs = jnp.asarray(inv_freq, jnp.float32)
     angles = positions.astype(jnp.float32)[..., None] * freqs
+    if scale != 1.0:
+        return jnp.cos(angles) * scale, jnp.sin(angles) * scale
     return jnp.cos(angles), jnp.sin(angles)
+
+
+def rope_tables(cfg: "TransformerConfig", positions: jax.Array):
+    """The model's cos/sin tables: plain rotary, or YaRN where
+    ``cfg.rope_factor`` > 1."""
+    if cfg.rope_factor <= 1.0:
+        return rotary_embedding(positions, cfg.rope_dim, cfg.rope_theta)
+    return rotary_embedding(
+        positions, cfg.rope_dim, cfg.rope_theta,
+        inv_freq=yarn_inv_freq(
+            cfg.rope_dim, cfg.rope_theta, cfg.rope_factor,
+            cfg.rope_original_max_pos, cfg.rope_beta_fast, cfg.rope_beta_slow),
+        scale=(yarn_mscale(cfg.rope_factor, cfg.rope_mscale)
+               / yarn_mscale(cfg.rope_factor, cfg.rope_mscale_all_dim)),
+    )
+
+
+def attention_scale(cfg: "TransformerConfig") -> Optional[float]:
+    """What multiplies q.k before the softmax, or None for the plain
+    ``head_dim ** -0.5``: MLA's head is nope + rope wide, and YaRN's
+    ``mscale_all_dim`` multiplies the scale by its mscale squared."""
+    if cfg.attn_kind != "mla":
+        return None
+    scale = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+    if cfg.rope_factor > 1.0 and cfg.rope_mscale_all_dim:
+        scale *= yarn_mscale(cfg.rope_factor, cfg.rope_mscale_all_dim) ** 2
+    return scale
 
 
 def apply_rotary(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
@@ -202,10 +414,13 @@ def _splash_blocks(L: int, block_q: int, block_kv: int, head_dim: int):
 
 def splash_attention_tpu(q: jax.Array, k: jax.Array, v: jax.Array,
                          block_q: int = 0, block_kv: int = 0,
-                         causal: bool = True) -> jax.Array:
+                         causal: bool = True,
+                         scale: Optional[float] = None) -> jax.Array:
     """Splash attention (the current-generation Pallas TPU kernel).
 
-    q: [B, L, H, D]; k/v: [B, L, Hkv, D] → out [B, L, H, D]. GQA/MQA run
+    q: [B, L, H, D]; k: [B, L, Hkv, D]; v: [B, L, Hkv, Dv] → out
+    [B, L, H, Dv] (the kernel takes a value head size apart from the
+    query's). ``scale`` multiplies q.k (None: ``D ** -0.5``). GQA/MQA run
     NATIVELY (``make_splash_mqa`` vmapped over kv groups) — K/V are never
     repeated to H heads, cutting both the repeat's HBM traffic and the
     kernel's K/V block loads by H/Hkv.
@@ -220,7 +435,8 @@ def splash_attention_tpu(q: jax.Array, k: jax.Array, v: jax.Array,
 
     B, L, H, D = q.shape
     Hkv = k.shape[2]
-    scale = float(1.0 / D ** 0.5)
+    if scale is None:
+        scale = float(1.0 / D ** 0.5)
     blocks = _splash_blocks(L, block_q, block_kv, D)
 
     def head_mask(n):
@@ -239,12 +455,13 @@ def splash_attention_tpu(q: jax.Array, k: jax.Array, v: jax.Array,
     kernel = sk.make_splash_mqa(mask=mask, block_sizes=blocks,
                                 head_shards=1, q_seq_shards=1)
     qg = (qt * scale).reshape(B, Hkv, rep, L, D)
-    out = jax.vmap(jax.vmap(kernel))(qg, kt, vt)  # [B, Hkv, rep, L, D]
-    return out.reshape(B, H, L, D).swapaxes(1, 2)
+    out = jax.vmap(jax.vmap(kernel))(qg, kt, vt)  # [B, Hkv, rep, L, Dv]
+    return out.reshape(B, H, L, v.shape[-1]).swapaxes(1, 2)
 
 
 def _constrain_batch_activations(x: jax.Array) -> jax.Array:
-    """Pin [B, L, D] activations to the canonical batch sharding.
+    """Pin [B, L, D] activations (or [B, n, L, D] residual streams) to the
+    canonical batch sharding.
 
     Without this, GSPMD sometimes resolves the fsdp layout by REPLICATING
     activations and partial-summing over contraction-dim-sharded weights —
@@ -269,9 +486,11 @@ def _constrain_batch_activations(x: jax.Array) -> jax.Array:
         return x
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    return jax.lax.with_sharding_constraint(
-        x, NamedSharding(mesh, P(batch if batch else None, lspec, None))
-    )
+    if x.ndim == 4:  # [B, n, L, D] residual streams
+        spec = P(batch if batch else None, None, lspec, None)
+    else:
+        spec = P(batch if batch else None, lspec, None)
+    return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
 
 
 def _constrain_lookup_table(w: jax.Array, shard_rows: bool = True) -> jax.Array:
@@ -352,17 +571,19 @@ def expand_gqa(k, v, n_heads):
 
 def attention_scores(
     q: jax.Array, k: jax.Array, v: jax.Array, mask: Optional[jax.Array],
-    causal: bool = True,
+    causal: bool = True, scale: Optional[float] = None,
 ) -> jax.Array:
     """Plain attention (single-device / tensor-parallel path).
 
-    q: [B, L, H, D], k/v: [B, L, Hkv, D] → out [B, L, H, D]. GQA via repeat.
-    The sequence-parallel path replaces this with ring attention
-    (``ring_attention.py``).
+    q: [B, L, H, D], k: [B, L, Hkv, D], v: [B, L, Hkv, Dv] → out
+    [B, L, H, Dv]. GQA via repeat. ``scale`` multiplies q.k (None:
+    ``D ** -0.5``). The sequence-parallel path replaces this with ring
+    attention (``ring_attention.py``).
     """
     B, L, H, D = q.shape
     k, v = expand_gqa(k, v, H)
-    scale = 1.0 / jnp.sqrt(D).astype(jnp.float32)
+    if scale is None:
+        scale = 1.0 / jnp.sqrt(D).astype(jnp.float32)
     logits = jnp.einsum("blhd,bmhd->bhlm", q, k).astype(jnp.float32) * scale
     if causal:
         tri = jnp.tril(jnp.ones((L, L), jnp.bool_))
@@ -371,6 +592,76 @@ def attention_scores(
         logits = jnp.where(mask[:, None, None, :], logits, -1e30)
     probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
     return jnp.einsum("bhlm,bmhd->blhd", probs, v)
+
+
+def attend(cfg: TransformerConfig, q, k, v, mask=None,
+           scale: Optional[float] = None):
+    """Attention over projected, rotated heads by the path the context and
+    the back-end choose: ring attention under sequence parallelism, the
+    splash kernel on a TPU, XLA elsewhere. q: [B, L, H, D]; k: [B, L, Hkv,
+    D]; v: [B, L, Hkv, Dv] -> [B, L, H, Dv]."""
+    B, L, H, _ = q.shape
+    from .context import get_seq_context
+
+    seq_ctx = get_seq_context()
+    if seq_ctx is not None and (scale is not None
+                                or v.shape[-1] != q.shape[-1]):
+        raise NotImplementedError(
+            "ring attention takes one head size and the plain scale: "
+            "latent attention does not run under sequence parallelism yet")
+    if seq_ctx is not None:
+        # sequence parallelism: exact attention over the ring (L stays
+        # sharded; K/V rotate over ICI — ring_attention.py)
+        from jax.sharding import PartitionSpec as P
+
+        from .. import constants as _c
+        from .ring_attention import make_ring_attention
+        from .sharding import compat_shard_map
+
+        k, v = expand_gqa(k, v, H)  # expand before sharding (GQA)
+        spec = P(
+            (_c.MESH_AXIS_DATA, _c.MESH_AXIS_FSDP),
+            seq_ctx.axis_name,
+            _c.MESH_AXIS_TENSOR,
+            None,
+        )
+        # splash kernel inside the ring when the per-device block is in
+        # the kernel's winning regime (tools/bench_ring_kernel.py). The
+        # r5 backward is the splash dq/dkv kernels too (ring_attention
+        # ._bwd_kernel), so the threshold is no longer bwd-limited; 4096
+        # stands until the TPU block sweep re-measures the crossover
+        Lb = L // seq_ctx.size
+        use_kernel = (
+            _attn_backend(cfg.attn_impl) == "splash"
+            and Lb >= 4096 and Lb % 128 == 0
+        )
+        ring = make_ring_attention(
+            seq_ctx.size, seq_ctx.axis_name, causal=cfg.causal,
+            use_kernel=use_kernel,
+            block_q=cfg.attn_block_q, block_kv=cfg.attn_block_kv,
+        )
+        out = compat_shard_map(
+            ring, mesh=seq_ctx.mesh, in_specs=(spec, spec, spec),
+            out_specs=spec,
+        )(q, k, v)
+    elif (
+        mask is None and L >= 128 and L % 128 == 0
+        and _attn_backend(cfg.attn_impl) == "splash"
+    ):
+        # GQA handled natively by the kernel — no K/V expand
+        from functools import partial
+
+        out = _shard_attn_kernel(
+            partial(
+                splash_attention_tpu,
+                block_q=cfg.attn_block_q, block_kv=cfg.attn_block_kv,
+                causal=cfg.causal, scale=scale,
+            ),
+            q, k, v,
+        )
+    else:
+        out = attention_scores(q, k, v, mask, causal=cfg.causal, scale=scale)
+    return out
 
 
 class Attention(nn.Module):
@@ -404,83 +695,82 @@ class Attention(nn.Module):
             q = apply_rotary(q, cos, sin)
             k = apply_rotary(k, cos, sin)
 
-        from .context import get_seq_context
-
-        seq_ctx = get_seq_context()
-        if seq_ctx is not None:
-            # sequence parallelism: exact attention over the ring (L stays
-            # sharded; K/V rotate over ICI — ring_attention.py)
-            from jax.sharding import PartitionSpec as P
-
-            from .. import constants as _c
-            from .ring_attention import make_ring_attention
-            from .sharding import compat_shard_map
-
-            k, v = expand_gqa(k, v, H)  # expand before sharding (GQA)
-            spec = P(
-                (_c.MESH_AXIS_DATA, _c.MESH_AXIS_FSDP),
-                seq_ctx.axis_name,
-                _c.MESH_AXIS_TENSOR,
-                None,
-            )
-            # splash kernel inside the ring when the per-device block is in
-            # the kernel's winning regime (tools/bench_ring_kernel.py). The
-            # r5 backward is the splash dq/dkv kernels too (ring_attention
-            # ._bwd_kernel), so the threshold is no longer bwd-limited; 4096
-            # stands until the TPU block sweep re-measures the crossover
-            Lb = L // seq_ctx.size
-            use_kernel = (
-                _attn_backend(cfg.attn_impl) == "splash"
-                and Lb >= 4096 and Lb % 128 == 0
-            )
-            ring = make_ring_attention(
-                seq_ctx.size, seq_ctx.axis_name, causal=cfg.causal,
-                use_kernel=use_kernel,
-                block_q=cfg.attn_block_q, block_kv=cfg.attn_block_kv,
-            )
-            out = compat_shard_map(
-                ring, mesh=seq_ctx.mesh, in_specs=(spec, spec, spec),
-                out_specs=spec,
-            )(q, k, v)
-        elif (
-            mask is None and L >= 128 and L % 128 == 0
-            and _attn_backend(cfg.attn_impl) == "splash"
-        ):
-            # GQA handled natively by the kernel — no K/V expand
-            from functools import partial
-
-            out = _shard_attn_kernel(
-                partial(
-                    splash_attention_tpu,
-                    block_q=cfg.attn_block_q, block_kv=cfg.attn_block_kv,
-                    causal=cfg.causal,
-                ),
-                q, k, v,
-            )
-        else:
-            out = attention_scores(q, k, v, mask, causal=cfg.causal)
+        out = attend(cfg, q, k, v, mask)
         out = out.reshape(B, L, H * hd)
         return jnp.einsum("ble,ed->bld", out, wo.astype(cfg.dtype))
 
 
+class LatentAttention(nn.Module):
+    """Multi-head latent attention (DeepSeek-V2's MLA), training form: keys
+    and values are expanded per head and nothing is absorbed.
+
+    ``c_q = norm(x W_qa)``, ``q = c_q W_qb`` split per head into ``q_nope``
+    and ``q_rope``; ``(c_kv, k_rope) = split(x W_kva)`` with ``k_rope``
+    shared by all heads; ``(k_nope, v) = split(norm(c_kv) W_kvb)`` per head;
+    rotary on ``q_rope`` and ``k_rope`` only; scores scaled by
+    :func:`attention_scale`; ``o = concat(heads) W_o``."""
+
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, x, cos, sin, mask=None):
+        cfg = self.cfg
+        D, H = cfg.d_model, cfg.n_heads
+        dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+        rq, rkv = cfg.q_lora_rank, cfg.kv_lora_rank
+        init = nn.initializers.normal(0.02)
+
+        def weight(name, axes, shape):
+            return self.param(name, nn.with_partitioning(init, axes), shape,
+                              cfg.param_dtype).astype(cfg.dtype)
+
+        wq_a = weight("wq_a", (EMBED, LORA), (D, rq))
+        wq_b = weight("wq_b", (LORA, HEADS), (rq, H * (dn + dr)))
+        wkv_a = weight("wkv_a", (EMBED, LORA), (D, rkv + dr))
+        wkv_b = weight("wkv_b", (LORA, HEADS), (rkv, H * (dn + dv)))
+        wo = weight("wo", (HEADS, EMBED), (H * dv, D))
+        B, L, _ = x.shape
+        with _scope("mla"):
+            c_q = RMSNorm(cfg.norm_eps, name="q_norm")(
+                jnp.einsum("bld,dr->blr", x, wq_a))
+            q = jnp.einsum("blr,re->ble", c_q, wq_b).reshape(B, L, H, dn + dr)
+            c_kv, k_rope = jnp.split(
+                jnp.einsum("bld,dr->blr", x, wkv_a), [rkv], axis=-1)
+            kv = jnp.einsum(
+                "blr,re->ble", RMSNorm(cfg.norm_eps, name="kv_norm")(c_kv),
+                wkv_b).reshape(B, L, H, dn + dv)
+            k_nope, v = jnp.split(kv, [dn], axis=-1)
+            q_nope, q_rope = jnp.split(q, [dn], axis=-1)
+            with _scope("rope"):
+                q_rope = apply_rotary(q_rope, cos, sin)
+                k_rope = apply_rotary(k_rope[:, :, None, :], cos, sin)
+            q = jnp.concatenate([q_nope, q_rope], axis=-1)
+            k = jnp.concatenate(
+                [k_nope, jnp.broadcast_to(k_rope, (B, L, H, dr))], axis=-1)
+            out = attend(cfg, q, k, v, mask, scale=attention_scale(cfg))
+            return jnp.einsum("ble,ed->bld", out.reshape(B, L, H * dv), wo)
+
+
 class FeedForward(nn.Module):
     cfg: TransformerConfig
+    d_ff: int = 0  # 0: cfg.d_ff (a shared expert gives its own width)
 
     @nn.compact
     def __call__(self, x):
         cfg = self.cfg
+        d_ff = self.d_ff or cfg.d_ff
         init = nn.initializers.normal(0.02)
         # fused gate+up: one [D, 2*F] matmul
         w_gate_up = self.param(
             "w_gate_up",
             nn.with_partitioning(init, (EMBED, MLP)),
-            (cfg.d_model, 2 * cfg.d_ff),
+            (cfg.d_model, 2 * d_ff),
             cfg.param_dtype,
         )
         w_down = self.param(
             "w_down",
             nn.with_partitioning(init, (MLP, EMBED)),
-            (cfg.d_ff, cfg.d_model),
+            (d_ff, cfg.d_model),
             cfg.param_dtype,
         )
         gu = jnp.einsum("bld,df->blf", x, w_gate_up.astype(cfg.dtype))
@@ -489,31 +779,208 @@ class FeedForward(nn.Module):
         return jnp.einsum("blf,fd->bld", h, w_down.astype(cfg.dtype))
 
 
-class Block(nn.Module):
+# diagonal of the residual map's bias at initialisation: exp(2) against 1 off
+# the diagonal (H_res 0.71 on the diagonal, 0.10 off it). Sinkhorn-Knopp
+# contracts slowly near a permutation: from 5 I twenty sweeps leave the rows
+# 7e-4 off at the initial gains and 2% off at gains of 1; from 2 I they are
+# doubly stochastic to 1e-6 and 1e-3
+HC_RES_INIT = 2.0
+HC_GAIN_INIT = 0.01
+
+
+def sinkhorn(logits: jax.Array, iters: int, eps: float, clamp: float):
+    """[..., n, n] float32 -> doubly stochastic [..., n, n]: ``iters``
+    Sinkhorn-Knopp sweeps (rows, then columns; ``eps`` added to each
+    denominator) of ``exp(clip(logits, -clamp, clamp))``. The n x n entries
+    are moved to the leading axes so that every sum is elementwise over
+    whole token vectors (the token axis stays in the lanes)."""
+    with _scope("sinkhorn"):
+        m = jnp.exp(jnp.clip(logits, -clamp, clamp))
+        m = jnp.moveaxis(m, (-2, -1), (0, 1))
+        for _ in range(iters):
+            m = m / (m.sum(1, keepdims=True) + eps)
+            m = m / (m.sum(0, keepdims=True) + eps)
+        return jnp.moveaxis(m, (0, 1), (-2, -1))
+
+
+class HyperConnection(nn.Module):
+    """The maps of one sublayer's manifold-constrained hyper-connection (mHC)
+    from the ``n = cfg.hc_mult`` residual streams ``X`` [B, n, L, C]:
+    ``x^ = RMSNorm(vec(X))``; ``H~ = a * (x^ P) + b`` for the three maps
+    (one [nC, 2n + n^2] product); ``H_pre = sigmoid(H~_pre)`` [B, L, n],
+    ``H_post = 2 sigmoid(H~_post)`` [B, L, n], ``H_res = sinkhorn(H~_res)``
+    [B, L, n, n], all float32. The gains ``a`` start at 0.01; the biases so
+    that the sublayer reads the streams' mean, writes to every stream alike
+    and each stream keeps most of itself (``HC_RES_INIT``).
+
+    The streams lie [B, n, L, C], the stream axis outside the (L, C) tiles:
+    as the second-minor axis its 4 entries would be padded to a tile of 16.
+    ``vec(X)`` is never built: the norm's weight is folded into the maps
+    (``(x / rms * w) P = (x (w P)) / rms``), so the product reads the
+    streams as they lie and the norm is a per-token scalar after it."""
+
     cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, X):
+        cfg = self.cfg
+        B, n, L, C = X.shape
+        m = 2 * n + n * n
+
+        def bias_init(key, shape, dtype):
+            del key
+            return jnp.concatenate([
+                jnp.full((n,), -math.log(n - 1.0)), jnp.zeros((n,)),
+                (HC_RES_INIT * jnp.eye(n)).reshape(-1)]).astype(dtype)
+
+        w = self.param(
+            "w", nn.with_partitioning(nn.initializers.normal(0.02),
+                                      (EMBED, None)),
+            (n * C, m), cfg.param_dtype)
+        norm = self.param("norm", nn.with_partitioning(
+            nn.initializers.ones, (None,)), (n * C,), jnp.float32)
+        b = self.param("b", nn.with_partitioning(bias_init, (None,)),
+                       (m,), jnp.float32)
+        a = self.param(
+            "a", nn.with_partitioning(
+                nn.initializers.constant(HC_GAIN_INIT), (None,)),
+            (3,), jnp.float32)
+        with _scope("mhc"):
+            wp = (norm[:, None] * w).astype(cfg.dtype).reshape(n, C, m)
+            mean_sq = jnp.mean(jnp.square(X.astype(jnp.float32)), axis=(1, 3))
+            h = jnp.einsum("bnlc,ncj->blj", X, wp,
+                           preferred_element_type=jnp.float32)
+            h = h * jax.lax.rsqrt(mean_sq + cfg.norm_eps)[..., None]
+            h = h * jnp.repeat(a, np.array([n, n, n * n]),
+                               total_repeat_length=m) + b
+            pre = jax.nn.sigmoid(h[..., :n])
+            post = 2.0 * jax.nn.sigmoid(h[..., n:2 * n])
+            res = sinkhorn(h[..., 2 * n:].reshape(B, L, n, n),
+                           cfg.hc_sinkhorn_iters, cfg.hc_eps, cfg.hc_clamp)
+        return pre, post, res
+
+
+def _streams_read(X, pre):
+    """``H_pre X``: [B, n, L, C], [B, L, n] -> the sublayer's input
+    [B, L, C]. The n-term sums are written out so that they fuse into one
+    elementwise pass over the streams (an einsum would be a K = n matmul)."""
+    with _scope("mhc"):
+        return sum(pre[..., i, None] * X[:, i].astype(jnp.float32)
+                   for i in range(X.shape[1])).astype(X.dtype)
+
+
+def _streams_write(X, y, post, res):
+    """``H_res X + H_post^T (x) y``: the streams after the sublayer."""
+    with _scope("mhc"):
+        n = X.shape[1]
+        y32 = y.astype(jnp.float32)
+        rows = [sum(res[..., i, j, None] * X[:, j].astype(jnp.float32)
+                    for j in range(n))
+                + post[..., i, None] * y32 for i in range(n)]
+        return jnp.stack(rows, axis=1).astype(X.dtype)
+
+
+class Block(nn.Module):
+    """One decoder block: attention, then a feed-forward or expert layer,
+    each a pre-norm residual sublayer (``x + F(norm(x))``), or, where
+    ``cfg.hc_mult`` > 1, a hyper-connected one over the residual streams
+    (``x``: [B, n, L, C])."""
+
+    cfg: TransformerConfig
+    # None: an expert layer wherever the configuration has experts (the
+    # pipeline's and the Switch stacks' uniform blocks); the Transformer's
+    # layer list says it per layer
+    moe: Optional[bool] = None
 
     @nn.compact
     def __call__(self, x, cos, sin, mask=None):
-        x = x + Attention(self.cfg)(RMSNorm(self.cfg.norm_eps)(x), cos, sin, mask)
-        if self.cfg.moe_experts > 1:
+        cfg = self.cfg
+        moe = cfg.moe_experts > 1 if self.moe is None else self.moe
+        attn_cls = LatentAttention if cfg.attn_kind == "mla" else Attention
+
+        def attention(h):
+            return attn_cls(cfg)(h, cos, sin, mask)
+
+        def feed_forward(h):
+            if not moe:
+                return FeedForward(cfg)(h)
             from .moe import MoEFeedForward
 
-            y, aux = MoEFeedForward(self.cfg)(RMSNorm(self.cfg.norm_eps)(x))
+            y, aux = MoEFeedForward(cfg)(h)
             # surfaced through the "losses" collection; the trainer adds
             # moe_aux_weight * sum to the task loss
             self.sow("losses", "moe_aux", aux)
-            return x + y
-        x = x + FeedForward(self.cfg)(RMSNorm(self.cfg.norm_eps)(x))
+            return y
+
+        for sublayer in (attention, feed_forward):
+            if cfg.hc_mult > 1:
+                pre, post, res = HyperConnection(cfg)(x)
+                y = sublayer(RMSNorm(cfg.norm_eps)(_streams_read(x, pre)))
+                x = _streams_write(x, y, post, res)
+            else:
+                x = x + sublayer(RMSNorm(cfg.norm_eps)(x))
         return x
 
 
-class Transformer(nn.Module):
-    """Decoder-only LM. tokens [B, L] int32 → logits [B, L, vocab] fp32."""
+def _block_class(cfg: TransformerConfig):
+    if not cfg.remat:
+        return Block
+    policy = (
+        jax.checkpoint_policies.dots_with_no_batch_dims_saveable
+        if cfg.remat_policy == "dots"
+        else None
+    )
+    return nn.remat(Block, policy=policy)
+
+
+def _to_streams(x, n: int):
+    """[B, L, C] copied into n residual streams [B, n, L, C] (n > 1)."""
+    if n <= 1:
+        return x
+    return jnp.broadcast_to(x[:, None], (x.shape[0], n) + x.shape[1:])
+
+
+class MultiTokenPrediction(nn.Module):
+    """One multi-token-prediction module (DeepSeek-V3's MTP): ``h' =
+    [norm(emb(t_{i+1})); norm(h_i)] W_eh``, one more block (an expert layer
+    where the model has experts) with its own hyper-connections, its own
+    final norm. The caller shares the embedding and the head and takes the
+    loss on ``t_{i+2}``. ``h``: the main stack's hidden states before its
+    final norm (streams summed); ``emb_next``: the embedding of the next
+    token; both [B, L, D]. Returns normed hidden states [B, L, D]; the last
+    position has no next token and is the caller's to mask."""
 
     cfg: TransformerConfig
 
     @nn.compact
-    def __call__(self, tokens, mask=None, positions=None, return_hidden=False):
+    def __call__(self, h, emb_next, cos, sin, mask=None):
+        cfg = self.cfg
+        w_eh = self.param(
+            "w_eh", nn.with_partitioning(nn.initializers.normal(0.02),
+                                         (MLP, EMBED)),
+            (2 * cfg.d_model, cfg.d_model), cfg.param_dtype)
+        z = jnp.concatenate([RMSNorm(cfg.norm_eps, name="emb_norm")(emb_next),
+                             RMSNorm(cfg.norm_eps, name="h_norm")(h)], axis=-1)
+        z = jnp.einsum("ble,ed->bld", z, w_eh.astype(cfg.dtype))
+        z = _constrain_batch_activations(_to_streams(z, cfg.hc_mult))
+        z = _block_class(cfg)(cfg, moe=cfg.moe_experts > 1)(z, cos, sin, mask)
+        if cfg.hc_mult > 1:
+            z = z.sum(axis=1)
+        return RMSNorm(cfg.norm_eps, name="final_norm")(z)
+
+
+class Transformer(nn.Module):
+    """Decoder-only LM. tokens [B, L] int32 → logits [B, L, vocab] fp32.
+
+    ``return_hidden`` returns the final-norm hidden states instead (the head
+    is then the caller's); ``return_mtp`` returns a pair, the second member
+    the multi-token-prediction module's hidden states (``cfg.mtp_layers``)."""
+
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, tokens, mask=None, positions=None, return_hidden=False,
+                 return_mtp=False):
         cfg = self.cfg
         embed = self.param(
             "embed",
@@ -521,17 +988,21 @@ class Transformer(nn.Module):
             (cfg.vocab_size, cfg.d_model),
             cfg.param_dtype,
         )
+
         # constrain AT the take: the table is (vocab→tensor, embed→fsdp)
         # sharded, and without an output annotation on the gather itself the
         # partitioner first shards the result like the table (d_model over
         # fsdp) and then hits an "[SPMD] Involuntary full rematerialization"
         # transition to the batch-sharded activation layout (r4 VERDICT
         # weak #5, reproduced on the fsdp×tensor×sequence fedllm mesh)
-        with _scope("embed"):
-            x = _constrain_batch_activations(
-                jnp.take(_constrain_lookup_table(embed), tokens, axis=0)
-                .astype(cfg.dtype)
-            )
+        def lookup(ids):
+            with _scope("embed"):
+                return _constrain_batch_activations(
+                    jnp.take(_constrain_lookup_table(embed), ids, axis=0)
+                    .astype(cfg.dtype)
+                )
+
+        x = lookup(tokens)
         if positions is None:
             positions = jnp.arange(tokens.shape[1])[None, :]
         if cfg.pos_emb == "learned":
@@ -550,29 +1021,32 @@ class Transformer(nn.Module):
                     positions, axis=0,
                 ).astype(cfg.dtype)
             # identity rotation: attention runs position-free
-            ang = jnp.zeros(positions.shape + (cfg.head_dim // 2,),
+            ang = jnp.zeros(positions.shape + (cfg.rope_dim // 2,),
                             jnp.float32)
             with _scope("rope"):
                 cos, sin = jnp.cos(ang), jnp.sin(ang)
         else:
             with _scope("rope"):
-                cos, sin = rotary_embedding(positions, cfg.head_dim,
-                                            cfg.rope_theta)
-        x = _constrain_batch_activations(x)
+                cos, sin = rope_tables(cfg, positions)
+        # hyper-connections: the embedding is copied into the streams
+        x = _constrain_batch_activations(_to_streams(x, cfg.hc_mult))
 
-        if cfg.remat:
-            policy = (
-                jax.checkpoint_policies.dots_with_no_batch_dims_saveable
-                if cfg.remat_policy == "dots"
-                else None
-            )
-            block_cls = nn.remat(Block, policy=policy)
-        else:
-            block_cls = Block
-        for _ in range(cfg.n_layers):
+        block_cls = _block_class(cfg)
+        for kind in cfg.layer_kinds:
             x = _constrain_batch_activations(
-                block_cls(cfg)(x, cos, sin, mask)
+                block_cls(cfg, moe=kind == "moe")(x, cos, sin, mask)
             )
+        if cfg.hc_mult > 1:
+            x = x.sum(axis=1)  # the streams are summed before the final norm
+
+        mtp_hidden = None
+        if cfg.mtp_layers:
+            with _scope("mtp"):
+                # position i joins its hidden state with token i + 1's
+                # embedding (the roll wraps at the last position, which has
+                # no target)
+                mtp_hidden = MultiTokenPrediction(cfg, name="mtp")(
+                    x, lookup(jnp.roll(tokens, -1, axis=1)), cos, sin, mask)
 
         x = RMSNorm(cfg.norm_eps)(x)
         if return_hidden:
@@ -582,7 +1056,7 @@ class Transformer(nn.Module):
             # HBM, and task-head backbones (models/transformer_heads.py)
             # never CREATE the [d_model, vocab] LM head — at 7B scale a
             # ~131M-param dead weight every FL round would otherwise ship
-            return x
+            return (x, mtp_hidden) if return_mtp else x
         # tied-untied choice: separate output head (Llama unties)
         w_out = self.param(
             "w_lm_head",
@@ -590,6 +1064,7 @@ class Transformer(nn.Module):
             (cfg.d_model, cfg.vocab_size),
             cfg.param_dtype,
         )
-        return jnp.einsum("bld,dv->blv", x, w_out.astype(cfg.dtype)).astype(
+        logits = jnp.einsum("bld,dv->blv", x, w_out.astype(cfg.dtype)).astype(
             jnp.float32
         )
+        return (logits, mtp_hidden) if return_mtp else logits

@@ -63,10 +63,25 @@ def lowered_step(accum_steps: int, **cfg):
 @pytest.mark.parametrize("accum_steps, absent", [(2, set()), (1, {"grad_accum"})])
 def test_train_step_carries_its_vocabulary(accum_steps, absent):
     names = scope_names(lowered_step(accum_steps))
-    assert set(scopes.TRAIN_STEP) - names == absent
+    # the tiny preset has none of the mechanisms TRAIN_STEP_BLOCKS names
+    assert set(scopes.TRAIN_STEP) - names == absent | set(scopes.TRAIN_STEP_BLOCKS)
     # flax's modules and JAX's transforms name the model; they are not doubled
     assert {"Attention_0", "FeedForward_0", "RMSNorm_0"} <= names
     assert not {"attention", "feed_forward", "forward", "backward"} & names
+
+
+def test_train_step_names_what_a_configuration_adds_to_the_blocks():
+    """Latent attention, hyper-connections, a sigmoid-routed expert layer with
+    a shared expert and the MTP module, each under its own name."""
+    names = scope_names(lowered_step(
+        1, attn_kind="mla", q_lora_rank=24, kv_lora_rank=16,
+        qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16, hc_mult=2,
+        hc_sinkhorn_iters=2, moe_experts=4, moe_top_k=2, moe_router="sigmoid",
+        moe_capacity_factor=0.0, moe_d_ff=32, moe_shared_experts=1,
+        first_k_dense=1, mtp_layers=1))
+    assert set(scopes.TRAIN_STEP) - names == {"grad_accum"}
+    assert {"LatentAttention_0", "HyperConnection_0", "MoEFeedForward_0",
+            "FeedForward_0", "mtp"} <= names
 
 
 def test_train_step_learned_positions_are_embed_and_rope():

@@ -231,7 +231,7 @@ def _loss_and_grads(cfg, mesh, accum, ragged):
     toks, mask = tr.shard_batch(
         jnp.asarray(toks), jnp.asarray(mask if ragged else np.ones_like(mask)))
     with tr._trace_context():
-        loss, grads = jax.jit(tr._loss_and_grads)(params, toks, mask)
+        loss, grads, _ = jax.jit(tr._loss_and_grads)(params, {}, toks, mask)
     return float(loss), jax.device_get(grads)
 
 

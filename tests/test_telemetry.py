@@ -490,23 +490,25 @@ class TestCompileEvents:
 
 class TestFlopsPerToken:
     def test_agrees_with_the_benchmarks_shape_function(self):
-        """telemetry.flops_per_token counts what benchmark/flops/
-        transformer.py counts (no embedding gather, causal attention):
-        3.605 GFLOP a token at the mistral_7b_v0.1_l2 shapes."""
+        """What the runner's MFU gauge divides by counts what
+        benchmark/flops/transformer.py counts (no embedding gather, causal
+        attention): 3.605 GFLOP a token at the mistral_7b_v0.1_l2 shapes."""
         from benchmark.flops import transformer
+        from fedml_tpu.parallel.transformer import (TransformerConfig,
+                                                    train_flops_per_token)
 
         root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
         with open(os.path.join(root, "benchmark", "configs",
                                "mistral_7b_v0.1_l2.json")) as f:
             config = json.load(f)
         want = transformer.train_flops_per_token(config, 4096)
-        got = telemetry.flops_per_token(
+        got = train_flops_per_token(TransformerConfig(
             d_model=config["hidden_size"],
             n_layers=config["num_hidden_layers"],
             n_heads=config["num_attention_heads"],
             n_kv_heads=config["num_key_value_heads"],
             d_ff=config["intermediate_size"],
-            vocab_size=config["vocab_size"], seq_len=4096)
+            vocab_size=config["vocab_size"]), 4096)
         assert got == pytest.approx(want, rel=0.01)
         assert got == pytest.approx(3.605e9, rel=1e-3)
 
