@@ -26,6 +26,7 @@ from ..core import mlops
 # names for the step's device work outside the flax modules
 from ..core.mlops.scopes import train_step_scope as _scope
 from .context import get_mesh_context, mesh_context, sequence_parallelism
+from .mhc_streams import backward_path
 from .sharding import (
     FSDP,
     TENSOR,
@@ -295,6 +296,9 @@ class CheetahTrainer:
             if self.loss_chunk > 0 and FSDP in batch_mesh_axes(mesh)
             else 0
         )
+        # which backward the hyper-connected blocks' stream reads and writes
+        # take, as mhc_streams decides it when the step is traced
+        self.mhc_backward = backward_path(cfg.hc_mult, cfg.d_model, mesh)
 
         dummy = jnp.zeros((1, 8), jnp.int32)
         boxed_abstract = jax.eval_shape(
@@ -348,9 +352,9 @@ class CheetahTrainer:
         n_params = sum(int(p.size) for p in jax.tree.leaves(params))
         logger.info(
             "cheetah init: %.1fM params over mesh %s, "
-            "loss_head_gathers_per_step %d",
+            "loss_head_gathers_per_step %d, mhc_backward %s",
             n_params / 1e6, dict(self.mesh.shape),
-            self.loss_head_gathers_per_step,
+            self.loss_head_gathers_per_step, self.mhc_backward,
         )
         mlops.log_cheetah_init(
             {k: int(v) for k, v in self.mesh.shape.items()},
@@ -358,6 +362,7 @@ class CheetahTrainer:
             layers=list(self.cfg.layer_kinds),
             n_routed_experts=int(self.cfg.moe_experts),
             experts_held=int(self.cfg.experts_held),
+            mhc_backward=self.mhc_backward,
         )
         # step must be committed to the mesh (replicated) — a default-device
         # scalar breaks jit after checkpoint restore (mixed device sets)

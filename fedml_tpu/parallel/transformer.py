@@ -37,6 +37,7 @@ from flax import linen as nn
 
 # names for what no flax module wraps; the modules name the rest
 from ..core.mlops.scopes import train_step_scope as _scope
+from .mhc_streams import streams_read, streams_write
 
 logger = logging.getLogger(__name__)
 
@@ -860,26 +861,6 @@ class HyperConnection(nn.Module):
         return pre, post, res
 
 
-def _streams_read(X, pre):
-    """``H_pre X``: [B, n, L, C], [B, L, n] -> the sublayer's input
-    [B, L, C]. The n-term sums are written out so that they fuse into one
-    elementwise pass over the streams (an einsum would be a K = n matmul)."""
-    with _scope("mhc"):
-        return sum(pre[..., i, None] * X[:, i].astype(jnp.float32)
-                   for i in range(X.shape[1])).astype(X.dtype)
-
-
-def _streams_write(X, y, post, res):
-    """``H_res X + H_post^T (x) y``: the streams after the sublayer."""
-    with _scope("mhc"):
-        n = X.shape[1]
-        y32 = y.astype(jnp.float32)
-        rows = [sum(res[..., i, j, None] * X[:, j].astype(jnp.float32)
-                    for j in range(n))
-                + post[..., i, None] * y32 for i in range(n)]
-        return jnp.stack(rows, axis=1).astype(X.dtype)
-
-
 class Block(nn.Module):
     """One decoder block: attention, then a feed-forward or expert layer,
     each a pre-norm residual sublayer (``x + F(norm(x))``), or, where
@@ -915,8 +896,8 @@ class Block(nn.Module):
         for sublayer in (attention, feed_forward):
             if cfg.hc_mult > 1:
                 pre, post, res = HyperConnection(cfg)(x)
-                y = sublayer(RMSNorm(cfg.norm_eps)(_streams_read(x, pre)))
-                x = _streams_write(x, y, post, res)
+                y = sublayer(RMSNorm(cfg.norm_eps)(streams_read(x, pre)))
+                x = streams_write(x, y, post, res)
             else:
                 x = x + sublayer(RMSNorm(cfg.norm_eps)(x))
         return x

@@ -287,6 +287,7 @@ class TestSpans:
                  if e.get("kind") == "cheetah_init"]
         assert len(inits) == 1
         assert inits[0]["loss_head_gathers_per_step"] == gathers
+        assert inits[0]["mhc_backward"] == "xla"  # no streams, and no TPU
         want = dict(p.split(":") for p in mesh_shape.split(","))
         assert {k: v for k, v in inits[0]["mesh"].items() if v > 1} == {
             k: int(v) for k, v in want.items()}

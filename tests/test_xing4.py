@@ -132,10 +132,17 @@ def test_logits_and_mtp_hidden_agree_with_the_reference(mtp):
 # The step's own loss (chunked cross entropy, MTP term through the same head,
 # weight 0.3) and its gradient, every leaf, against jax.grad of the
 # reference's loss in the reference's layout: 1e-4 relative to the largest
-# leaf-wise norm, float32 both sides.
+# leaf-wise norm, float32 both sides. "fused": the streams' one-pass backward
+# that a TPU takes (ISSUE 29), its kernels' bodies under Pallas' interpreter,
+# at width 128 because the kernels work on whole lanes.
+@pytest.mark.parametrize("mhc_backward", ["xla", "fused"])
 @pytest.mark.parametrize("mtp", [0, 1], ids=["no_mtp", "mtp"])
-def test_loss_and_gradients_agree_with_the_reference(mtp):
-    cfg = xing_tiny(mtp_layers=mtp)
+def test_loss_and_gradients_agree_with_the_reference(mtp, mhc_backward,
+                                                     request):
+    cfg, kernels = xing_tiny(mtp_layers=mtp), []
+    if mhc_backward == "fused":
+        cfg = dataclasses.replace(cfg, d_model=128)
+        kernels = request.getfixturevalue("fused_mhc_backward")
     config = reference_config(cfg)
     params, state = seeded(cfg, TOKENS)
     trainer = CheetahTrainer(cfg, make_mesh(None, devices=jax.devices()[:1]),
@@ -143,6 +150,9 @@ def test_loss_and_gradients_agree_with_the_reference(mtp):
     mask = jnp.ones_like(TOKENS)
     (got, _), got_grads = jax.value_and_grad(trainer._loss_fn, has_aux=True)(
         params, state, TOKENS, mask)
+    # a read and a write in each sublayer of every block, or none
+    assert len(kernels) == (4 * (cfg.n_layers + mtp)
+                            if mhc_backward == "fused" else 0)
 
     def reference_loss(plain):
         main = mtp_sum = 0.0
