@@ -52,7 +52,7 @@ FLAGSHIP = dict(
     client_num_in_total=100, client_num_per_round=100,
 )
 # ResNet-56 on CIFAR-10-shaped data: 100 clients, 10 a round, batch 32, one
-# local epoch, default round_fusion. Round 0 is the warm-up.
+# local epoch, the jitted, donated round. Round 0 is the warm-up.
 FEDAVG = dict(
     training_type="simulation", backend="sp", dataset="cifar10",
     model="resnet56", client_num_in_total=100, client_num_per_round=10,

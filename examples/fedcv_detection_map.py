@@ -33,7 +33,7 @@ api = FedAvgAPI(args, fedml.get_device(args), ds, bundle)
 
 for r in range(int(args.comm_round)):
     args.round_idx = r
-    api._train_round(r)
+    api.run_round(r)
 
 # ONE forward over the test set; score the same logits at both IoUs
 import numpy as np

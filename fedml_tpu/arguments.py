@@ -143,17 +143,11 @@ _SCHEMA: Dict[str, tuple] = {
     "mqtt_subscribe_retries": (int, 5),
     "mqtt_subscribe_timeout_s": (float, 6.0),
     # round engine (simulation/round_engine.py)
-    # round_fusion: auto fuses the FedAvg-family round into ONE donated XLA
-    # program whenever no host-side hook blocks it; on demands it; off keeps
-    # the legacy multi-dispatch path (the parity reference).
-    "round_fusion": (str, "auto"),  # auto | on | off
     # superround_k > 1 runs K rounds per device-program launch under
     # lax.scan with ON-DEVICE client sampling (needs the HBM-resident
     # single-device path; cohort trajectory differs from host sampling
     # except under full participation). 0/1 = off.
     "superround_k": (int, 0),
-    # sp cohort execution: vmap | map | auto (see FedAvgAPI.cohort_impl)
-    "sp_cohort_impl": (str, ""),
     # million-client cohort substrate (fedml_tpu/scale/ — docs/scale.md).
     # client_registry: a client count ("1000000" registers N virtual
     # clients over the dataset's shards) or a path to a registry saved

@@ -128,7 +128,7 @@ def cmd_top(args) -> int:
         return 1
 
     phases = {}
-    # dispatch→loss latency (the loops that wait: sp unfused, Cheetah)
+    # dispatch→loss latency (the loop that waits: Cheetah)
     # overlaps their train/step + loss_sync spans, so it stays OUT of the
     # phase table (whose % wall must not double-count) and is summarised
     # separately below
@@ -613,9 +613,8 @@ def cmd_lint(args) -> int:
     (S003), host-transfer (S004) and static HBM budgets (S005, via
     ``--model``/``--mesh``). ``--rep``: graftrep (tools/graftrep) —
     determinism discipline (D001 key reuse, D002 seed provenance, D003
-    unordered accumulation, D004 dtype drift, D005 run-identity leaks) and
-    fused/unfused round structural equivalence (``--equiv``). ``--iso``:
-    graftiso (tools/graftiso) — serving-plane state ownership (I001
+    unordered accumulation, D004 dtype drift, D005 run-identity leaks).
+    ``--iso``: graftiso (tools/graftiso) — serving-plane state ownership (I001
     module-global state in handlers, I002 unscoped singleton access, I003
     class-level defaults & cross-instance aliasing, I004 ambient config,
     I005 untethered thread lifecycle). ``--mem``: graftmem (tools/graftmem)
@@ -660,7 +659,7 @@ def cmd_lint(args) -> int:
             return 2
         if suite == "graftrep":
             print("fedml_tpu lint: --runtime is a graftlint/graftshard "
-                  "pass; graftrep's jax-backed pass is --equiv")
+                  "pass; graftrep is pure AST")
             return 2
         if suite == "graftiso":
             print("fedml_tpu lint: --runtime is a graftlint/graftshard "
@@ -673,12 +672,6 @@ def cmd_lint(args) -> int:
                   "(fedml_tpu swarm --leak_check)")
             return 2
         cmd.append("--runtime")
-    if getattr(args, "equiv", False):
-        if suite != "graftrep":
-            print("fedml_tpu lint: --equiv is the graftrep round-"
-                  "equivalence pass — add --rep")
-            return 2
-        cmd.append("--equiv")
     if getattr(args, "model", ""):
         if suite != "graftshard":
             print("fedml_tpu lint: --model is the graftshard HBM "
@@ -853,8 +846,7 @@ def main(argv=None) -> int:
         "lint",
         help="run static analysis over the tree (graftlint; --proto for "
         "the comm-plane protocol suite, --shard for the TPU execution "
-        "plane's sharding/HBM suite, --rep for the determinism & "
-        "round-equivalence suite)",
+        "plane's sharding/HBM suite, --rep for the determinism suite)",
     )
     p_lint.add_argument("paths", nargs="*", default=[],
                         help="files/dirs to lint (default: fedml_tpu)")
@@ -882,11 +874,6 @@ def main(argv=None) -> int:
                         help="run graftrep (PRNG-key discipline, seed "
                         "provenance, unordered accumulation, dtype drift, "
                         "run-identity leaks) instead of graftlint")
-    p_lint.add_argument("--equiv", action="store_true",
-                        help="(--rep) also prove fused/unfused round "
-                        "structural equivalence: _train_round vs "
-                        "build_round_core under jax.make_jaxpr for "
-                        "FedAvg/FedOpt/SCAFFOLD")
     p_lint.add_argument("--runtime", action="store_true",
                         help="also run the suite's runtime pass: graftlint "
                         "traces the round engine under jax.make_jaxpr, "

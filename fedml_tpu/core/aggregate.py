@@ -75,9 +75,7 @@ def fednova_normalized_direction(
 ) -> PyTree:
     """Per-client normalized direction (w_g - w_i)/tau_i, leaf-wise.
 
-    The single definition shared by the unfused round loop and the fused
-    round engine — FedNova's fused-vs-unfused parity depends on both paths
-    computing this identically.
+    Used by the round (``simulation/round_engine.py``) for FedNova.
     """
     return jax.tree.map(
         lambda g, s: (g[None] - s) / tau.reshape((-1,) + (1,) * (s.ndim - 1)),

@@ -610,8 +610,8 @@ class RoundRecord:
     spans: List[Dict[str, Any]] = dataclasses.field(default_factory=list)
     wall_s: float = 0.0
     time: float = 0.0  # epoch seconds at end_round: time - wall_s is the start
-    # where the loop itself waits for the device (unfused path, Cheetah's
-    # loss_sync): dispatch → loss on the host. Null on the fused path
+    # where the loop itself waits for the device (Cheetah's loss_sync):
+    # dispatch → loss on the host. Null for a FedAvg round, which waits nowhere
     dispatch_latency_s: Optional[float] = None
     # earlier rounds dispatched and not yet ready when this one opened
     in_flight: int = 0

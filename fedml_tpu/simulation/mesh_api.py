@@ -14,19 +14,20 @@ aggregators doing torch.distributed broadcast/reduce; see SURVEY.md §3.2/§3.3)
   round state replicated — and ``--mesh_partition_rules`` /
   ``--mesh_state_rules`` override per-leaf placement without code changes
   (pinned bitwise-equal in ``tests/test_scale.py``)
-- the round runs the SAME engine as the sp backend (`FedAvgAPI._train_round`):
-  vmap(local_train) over the sharded cohort → attack → defend → weighted
-  average → DP. XLA propagates the input shardings through the jit'd cohort
-  program and lowers the cross-shard reduction to collectives over ICI — the
-  explicit `dist.reduce(SUM)` + 2-rank gather groups of the reference
-  (``params.py:98-127``) become compiler-inserted collectives
+- the round runs the SAME engine as the sp backend (`FedAvgAPI._train_round`
+  around `round_engine.build_round_core`): vmap(local_train) over the sharded
+  cohort → attack → defend → weighted average → DP. XLA propagates the input
+  shardings through the jit'd round program and lowers the cross-shard
+  reduction to collectives over ICI — the explicit `dist.reduce(SUM)` +
+  2-rank gather groups of the reference (``params.py:98-127``) become
+  compiler-inserted collectives
 - cohort padding (to a multiple of the axis size, zero weight) replaces the
   reference's padded schedule tensors (``Server.py:124-128``)
 
 There are no messages, no pickling, no per-worker processes: a round is one
 device program launch. Because the whole FedAvg-family engine is inherited,
 every federated optimizer (FedProx/FedOpt/FedNova/FedSGD/SCAFFOLD), the
-full trust pipeline (attack → defend → aggregate → DP, ``sp_api.py``) and
+full trust pipeline (attack → defend → aggregate → DP, ``round_engine.py``) and
 the million-client registry/prefetch substrate (``scale/``) work
 identically on the multi-chip path.
 """
@@ -62,7 +63,8 @@ class MeshFedAvgAPI(FedAvgAPI):
     # single-device HBM-resident fast path must not allocate in __init__
     hbm_resident_default = False
     # the cohort axis is SHARDED over devices: lax.map would serialize the
-    # whole mesh onto one program — vmap is structural here
+    # whole mesh onto one program — vmap is structural here, whatever the
+    # model and the platform
     cohort_impl_default = "vmap"
 
     def __init__(self, args, device, dataset, model, client_trainer=None,

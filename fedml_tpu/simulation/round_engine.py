@@ -1,36 +1,42 @@
-"""Fused, donated round engine: one XLA program per FedAvg-family round.
+"""The FedAvg-family round, written once: ``build_round_core``.
 
-The unfused path (``FedAvgAPI._train_round``) drives every round from Python:
-separate dispatches for the cohort step, aggregation, the server optimizer and
-DP, with fresh HBM allocations for the model / optimizer / control-variate
-state each round. This module collapses all of it into a single ``jax.jit``
-with ``donate_argnums`` on the round state, so
+``core(state, cohort_idx, cx, cy, cn, rngs, wmask, round_rng) -> (state,
+metrics)`` is the whole round after the cohort is gathered: data attack ->
+the cohort's local training -> local DP or clipping -> model attack ->
+defense or aggregation -> server update (FedOpt / FedSGD optimizer, FedNova,
+SCAFFOLD's variates) -> central DP, with the round's ``train_loss`` and
+``examples`` as device scalars. Every engine runs this function
+(``FedAvgAPI._host_rule`` decides how, ``_setup_round`` builds it):
 
-- a steady-state round is ONE device-program launch (the recompilation guard
-  in ``tests/test_round_fusion.py`` pins exactly one compile per config);
-- the model, server-optimizer and SCAFFOLD control-variate buffers are
-  donated — XLA updates them in place instead of holding the 2x HBM copy of
-  the stacked ``[cohort, ...]`` leaves plus old-and-new state;
-- central/local DP noising and the jit-safe attack/defense kernels run inside
-  the same program (FL-WBC keeps host-side per-client history and a custom
-  ``ServerAggregator`` is arbitrary Python — both fall back to the unfused
-  path, see ``FedAvgAPI._fusion_blockers``).
+- **jitted and donated**, when every step is jit-safe: one
+  ``jax.jit(core, donate_argnums=(0,))`` program per round. A steady-state
+  round is ONE launch (``tests/test_round_fusion.py`` pins one compile per
+  config), and the model, server-optimizer and control-variate buffers are
+  updated in place instead of held twice.
+- **eagerly, op by op**, when the configuration has a *host aggregation rule*
+  (``FedAvgAPI._host_rule``): a custom ``ServerAggregator``, a subclass's
+  ``_aggregate`` (TurboAggregate's additive shares) or FL-WBC, whose
+  per-client history lives on the host under concrete client ids. The rule
+  stands where the weighted average or the defense stands; the chain around
+  it is the same code. ``cohort_fn`` is itself a ``jax.jit``, so local
+  training is still one program; nothing is donated.
 
 Superround mode (``make_superround_step``) additionally moves client sampling
 on-device (fold-in PRNG choice over client ids) and runs K rounds under
 ``jax.lax.scan`` — steady-state throughput is then bounded by device compute,
-not Python dispatch. It requires the HBM-resident dataset (the cohort gather
-happens inside the program) and uses device-side sampling, so its cohort
-trajectory differs from the host-side ``np.random.RandomState(round_idx)``
-reference semantics EXCEPT under full participation, where both degenerate to
-``arange`` and the trajectories coincide exactly (the parity tests rely on
-this).
+not Python dispatch. It requires the jitted round and the HBM-resident
+dataset (the cohort gather happens inside the program) and uses device-side
+sampling, so its cohort trajectory differs from the host-side
+``np.random.RandomState(round_idx)`` reference semantics EXCEPT under full
+participation, where both degenerate to ``arange`` and the trajectories
+coincide exactly (the parity tests rely on this).
 
 Round state is a flat dict — ``{"global_params", "server_opt_state"?,
 "c_global"?, "c_locals"?}`` — matching ``FedAvgAPI._round_state``. Callers
-must treat the state they passed in as CONSUMED (donation invalidates the
-buffers) and adopt the returned state; ``checkpoint.CheckpointManager.save``
-copies leaves to host before the next round can be dispatched.
+of a jitted round must treat the state they passed in as CONSUMED (donation
+invalidates the buffers) and adopt the returned state;
+``checkpoint.CheckpointManager.save`` copies leaves to host before the next
+round can be dispatched.
 """
 
 from __future__ import annotations
@@ -58,7 +64,7 @@ RoundState = Dict[str, PyTree]
 
 
 def _masked_mean(values, wmask):
-    """Device-side twin of ``sp_api._masked_mean`` (same math, no host pull)."""
+    """Mean of per-client scalars over the real (mask 1) clients, on device."""
     if values is None:
         return jnp.float32(jnp.nan)
     if wmask is None:
@@ -67,21 +73,21 @@ def _masked_mean(values, wmask):
 
 
 def build_round_core(api, n_cohort: int, n_valid: int):
-    """Build the pure round function for ``api``'s config.
+    """Build the round function for ``api``'s config.
 
     ``n_cohort`` is the (padded) cohort length, ``n_valid`` the number of real
-    clients — both static per config, so the zero-weight-padding slices
-    compile to static slicing exactly like the unfused path.
+    clients — both static per config, so the zero-weight-padding slices are
+    static slices.
 
     Returns ``core(state, cohort_idx, cx, cy, cn, rngs, wmask, round_rng) ->
-    (state, metrics)``. The attack/defense hook order and every PRNG fold-in
-    mirror ``FedAvgAPI._train_round`` / ``_aggregate`` bit for bit — the
-    parity tests compare the two paths to atol 1e-5 over multiple rounds.
+    (state, metrics)``. With a host aggregation rule (``api._host_rule()``) it
+    is meant to be called as it is, without ``jax.jit``: the rule gets concrete
+    arrays.
 
     jit-safety note: the attacker's host-side ``np.random`` mask draws are
     seeded by config only (``random_seed``; ``attack_model``'s round offset
-    defaults to 0 on both paths), so under trace they bake into compile-time
-    constants IDENTICAL to what the unfused path recomputes every round.
+    defaults to 0), so under trace they bake into compile-time constants
+    IDENTICAL to what an eager call computes every round.
     """
     attacker, defender, dp = api.attacker, api.defender, api.dp
     fedsgd, fednova, scaffold = api.fedsgd, api.fednova, api.scaffold
@@ -89,11 +95,10 @@ def build_round_core(api, n_cohort: int, n_valid: int):
     server_opt = api.server_opt
     cohort_fn = api.cohort_fn
     client_num = api.ds.client_num
+    host_rule = api._host_rule()
 
     @_scope("aggregate")
-    def aggregate(gp, stacked, weights, rng):
-        # mirror of FedAvgAPI._aggregate minus the unfusable paths (custom
-        # aggregator, FL-WBC) which are excluded by _fusion_blockers
+    def aggregate(gp, stacked, weights, rng, cohort_idx):
         if dp is not None and dp.dp_type == "ldp":
             with _scope("dp"):
                 keys = jax.random.split(jax.random.fold_in(rng, 3), n_cohort)
@@ -102,31 +107,43 @@ def build_round_core(api, n_cohort: int, n_valid: int):
             with _scope("dp"):
                 stacked = dp.clip_client_updates(stacked, gp)
 
-        needs_flat = attacker.is_model_attack() or defender.is_defense_enabled()
-        if not needs_flat:
+        # a host rule stands where the in-program rule (the defense or the
+        # weighted average) stands; a model attack runs before either
+        needs_flat = attacker.is_model_attack() or (
+            defender.is_defense_enabled() and host_rule is None)
+        if not needs_flat and host_rule is None:
             return weighted_average(stacked, weights)
 
-        if n_valid < n_cohort:  # drop zero-weight padding for rank defenses
+        # drop zero-weight padding: rank-based defenses, the attack kernels
+        # and host rules see the real clients only
+        if n_valid < n_cohort:
             stacked = jax.tree.map(lambda x: x[:n_valid], stacked)
             weights = weights[:n_valid]
-        _, treedef, shapes = tree_flatten_to_vector(gp)
-        flat = jax.vmap(lambda t: tree_flatten_to_vector(t)[0])(stacked)
-        gvec, _, _ = tree_flatten_to_vector(gp)
-        if attacker.is_model_attack():
-            with _scope("attack"):
-                flat = attacker.attack_model(
-                    flat, weights, jax.random.fold_in(rng, 1)
-                )
-        if defender.is_defense_enabled():
-            with _scope("defense"):
-                agg_vec = defender.defend(
-                    flat, weights, gvec, jax.random.fold_in(rng, 2),
-                    client_ids=None,
-                )
-        else:
-            w = weights / jnp.maximum(weights.sum(), 1e-12)
-            agg_vec = (w[:, None] * flat).sum(0)
-        return tree_unflatten_from_vector(agg_vec, treedef, shapes)
+        if needs_flat:
+            _, treedef, shapes = tree_flatten_to_vector(gp)
+            flat = jax.vmap(lambda t: tree_flatten_to_vector(t)[0])(stacked)
+            gvec, _, _ = tree_flatten_to_vector(gp)
+            if attacker.is_model_attack():
+                with _scope("attack"):
+                    flat = attacker.attack_model(
+                        flat, weights, jax.random.fold_in(rng, 1)
+                    )
+            if host_rule is None:
+                if defender.is_defense_enabled():
+                    with _scope("defense"):
+                        agg_vec = defender.defend(
+                            flat, weights, gvec, jax.random.fold_in(rng, 2),
+                            client_ids=None,
+                        )
+                else:
+                    w = weights / jnp.maximum(weights.sum(), 1e-12)
+                    agg_vec = (w[:, None] * flat).sum(0)
+                return tree_unflatten_from_vector(agg_vec, treedef, shapes)
+            # the rule aggregates whatever rows the attack left
+            stacked = jax.vmap(
+                lambda v: tree_unflatten_from_vector(v, treedef, shapes)
+            )(flat)
+        return host_rule(stacked, weights, rng, n_valid, cohort_idx[:n_valid])
 
     def round_metrics(metrics, weights, wmask):
         with _scope("metrics"):
@@ -148,7 +165,7 @@ def build_round_core(api, n_cohort: int, n_valid: int):
             grads, metrics = cohort_fn(gp, cx, cy, cn, rngs)
             weights = (metrics["num_samples"] if wmask is None
                        else metrics["num_samples"] * wmask)
-            agg_grad = aggregate(gp, grads, weights, round_rng)
+            agg_grad = aggregate(gp, grads, weights, round_rng, cohort_idx)
             with _scope("server_update"):
                 updates, opt_state = server_opt.update(
                     agg_grad, state["server_opt_state"], gp
@@ -156,7 +173,7 @@ def build_round_core(api, n_cohort: int, n_valid: int):
                 gp = optax.apply_updates(gp, updates)
             new_state = dict(state, global_params=gp,
                              server_opt_state=opt_state)
-            # (the unfused path applies no central-DP noise on FedSGD either)
+            # (FedSGD averages gradients: central DP's model noise has no place)
             return new_state, round_metrics(metrics, weights, wmask)
 
         if scaffold:
@@ -199,7 +216,7 @@ def build_round_core(api, n_cohort: int, n_valid: int):
                 tau_eff = (p * tau).sum()
                 gp = jax.tree.map(lambda g, dd: g - tau_eff * dd, gp, d)
         elif fedopt:
-            w_agg = aggregate(gp, stacked, weights, round_rng)
+            w_agg = aggregate(gp, stacked, weights, round_rng, cohort_idx)
             with _scope("server_update"):
                 pg = pseudo_gradient(gp, w_agg)
                 updates, opt_state = server_opt.update(
@@ -208,7 +225,7 @@ def build_round_core(api, n_cohort: int, n_valid: int):
                 gp = optax.apply_updates(gp, updates)
             state = dict(state, server_opt_state=opt_state)
         else:
-            gp = aggregate(gp, stacked, weights, round_rng)
+            gp = aggregate(gp, stacked, weights, round_rng, cohort_idx)
 
         if dp is not None and dp.dp_type == "cdp":
             with _scope("dp"):
@@ -219,15 +236,21 @@ def build_round_core(api, n_cohort: int, n_valid: int):
     return core
 
 
-def make_fused_round_step(api, n_cohort: int, n_valid: int):
-    """One jit'd, donated program per round.
+def host_defense_rule(api):
+    """FL-WBC as a host aggregation rule: the defender keeps each client's
+    last pseudo-gradient on the host under the client's id, so it is called
+    outside any trace, with the ids concrete."""
 
-    ``donate_argnums=(0,)`` donates every leaf of the round state — the old
-    global params / optimizer state / control variates are updated in place.
-    The caller must adopt the returned state and never touch the donated one.
-    """
-    core = build_round_core(api, n_cohort, n_valid)
-    return jax.jit(core, donate_argnums=(0,))
+    def rule(stacked, weights, rng, n_valid, client_ids):
+        gvec, treedef, shapes = tree_flatten_to_vector(api.global_params)
+        flat = jax.vmap(lambda t: tree_flatten_to_vector(t)[0])(stacked)
+        agg_vec = api.defender.defend(
+            flat, weights, gvec, jax.random.fold_in(rng, 2),
+            client_ids=client_ids,
+        )
+        return tree_unflatten_from_vector(agg_vec, treedef, shapes)
+
+    return rule
 
 
 def make_superround_step(api, k: int, n_cohort: int):

@@ -6,14 +6,19 @@ clones (``sp/fedopt``, ``sp/fedprox``, ``sp/fednova``, ``sp/fedsgd``) with ONE
 engine:
 
 - the round's cohort trains as ``vmap(local_train)`` over a stacked
-  ``[cohort, cap, ...]`` gather of the packed dataset — one fused XLA program
-- aggregation is the stacked weighted-average kernel (core/aggregate.py)
-- the federated optimizer enters as (a) a flag inside the local loss
-  (FedProx), (b) a server-side optax transform on the pseudo-gradient
+  ``[cohort, cap, ...]`` gather of the packed dataset
+- what happens to the cohort's results is ONE function,
+  ``round_engine.build_round_core``: local DP / clipping → attack → defense or
+  aggregation → server update → central DP (the reference's hook order). The
+  federated optimizer enters as (a) a flag inside the local loss (FedProx),
+  (b) a server-side optax transform on the pseudo-gradient
   (FedOpt/FedAdam/FedYogi/FedAdagrad), (c) normalized averaging (FedNova),
   (d) gradient-level averaging (FedSGD), or (e) control variates (SCAFFOLD)
-- hook order preserved from the reference: attack → on_before_aggregation →
-  defend → aggregate → DP → on_after_aggregation
+- this module owns everything around that function: sampling, gather and
+  placement (the hooks the mesh engine overrides), the round state, how the
+  round is executed (``_host_rule`` / ``_setup_round``: one donated program,
+  or op by op when the aggregation rule is host Python), superrounds,
+  checkpoint / resume and the training loop
 
 Client sampling stays host-side and round-seeded exactly like the reference
 (``fedavg_api.py:125-140``: ``np.random.seed(round_idx)`` + choice).
@@ -23,18 +28,13 @@ from __future__ import annotations
 
 import logging
 import time
-from typing import Any, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from .. import constants
-from ..core.aggregate import (
-    fednova_normalized_direction,
-    pseudo_gradient,
-    weighted_average,
-)
 from ..core.dp import FedPrivacyMechanism
 from ..core.mlops import telemetry
 from ..core.security.attacker import FedMLAttacker
@@ -42,13 +42,7 @@ from ..core.security.defender import FedMLDefender
 from ..ml.evaluate import make_eval_fn
 from ..ml.local_train import make_grad_fn, make_local_train_fn
 from ..ml.optimizer import create_server_optimizer
-from ..utils.tree import (
-    tree_flatten_to_vector,
-    tree_scale,
-    tree_sub,
-    tree_unflatten_from_vector,
-    tree_zeros_like,
-)
+from ..utils.tree import tree_zeros_like
 
 logger = logging.getLogger(__name__)
 
@@ -58,6 +52,26 @@ SERVER_OPT_FAMILY = (
     constants.FEDML_FEDERATED_OPTIMIZER_FEDOPT,
     constants.FEDML_FEDERATED_OPTIMIZER_FEDSGD,
 )
+
+
+def _over_cohort(fn: Callable, in_axes: tuple, impl: str) -> Callable:
+    """``fn`` for every client of a cohort: arguments whose ``in_axes`` entry
+    is 0 carry a leading cohort axis, ``None`` marks one shared by all.
+    ``vmap`` makes one batched program, ``map`` runs the clients one after
+    another under ``lax.map``; identical math, the same stacked outputs."""
+    if impl == "vmap":
+        return jax.vmap(fn, in_axes=in_axes)
+    stacked = [i for i, axis in enumerate(in_axes) if axis is not None]
+
+    def one_client(args, rows):
+        args = list(args)
+        for i, row in zip(stacked, rows):
+            args[i] = row
+        return fn(*args)
+
+    return lambda *args: jax.lax.map(
+        lambda rows: one_client(args, rows), tuple(args[i] for i in stacked)
+    )
 
 
 class FedAvgAPI:
@@ -73,13 +87,15 @@ class FedAvgAPI:
     # so __init__ never parks a dead dataset copy in device-0 HBM
     hbm_resident_default = True
 
-    # cohort execution: "vmap" fuses the round into one batched program (the
-    # TPU design); "map" runs clients sequentially under lax.map — identical
-    # math, same stacked outputs. "auto" picks map ONLY for conv models on
-    # XLA:CPU, where vmapped convs lower to a grouped-conv path ~100x slower
-    # than the plain conv (measured: resnet56 compiles >60 min and the
-    # same-substrate cnn leg ran 0.01x; lax.map keeps each conv un-grouped).
-    # The mesh engine pins vmap — its cohort axis is SHARDED over devices.
+    # cohort execution, decided in __init__ and readable as ``cohort_impl``:
+    # "vmap" batches the cohort into one program (the TPU design); "map" runs
+    # clients one after another under lax.map — identical math, same stacked
+    # outputs. "auto" takes map ONLY for conv models on XLA:CPU, where
+    # vmapped convs lower to a grouped-conv path ~100x slower than the plain
+    # conv (measured: resnet56 compiles >60 min and the same-substrate cnn
+    # leg ran 0.01x; lax.map keeps each conv un-grouped). The mesh engine
+    # pins vmap — its cohort axis is SHARDED over devices, and lax.map would
+    # serialize the whole mesh onto one program.
     cohort_impl_default = "auto"
 
     @staticmethod
@@ -152,59 +168,22 @@ class FedAvgAPI:
         self.fedsgd = self.opt_name == constants.FEDML_FEDERATED_OPTIMIZER_FEDSGD
         self.fednova = self.opt_name == constants.FEDML_FEDERATED_OPTIMIZER_FEDNOVA
 
-        cap = self.ds.cap
-        impl = str(
-            getattr(args, "sp_cohort_impl", "") or self.cohort_impl_default
-        ).lower()
-        if self.cohort_impl_default == "vmap" and impl != "vmap":
-            # mesh engine: the cohort axis is SHARDED over devices — lax.map
-            # would silently serialize the whole pod onto one program.
-            # ("auto" resolves to vmap here anyway; only "map" conflicts.)
-            if impl == "map":
-                logger.warning(
-                    "sp_cohort_impl='map' ignored: this engine requires "
-                    "vmap (cohort axis sharded over devices)"
-                )
-            impl = "vmap"
+        impl = self.cohort_impl_default
         if impl == "auto":
-            conv_model = bool(getattr(model, "conv_model", False))
             on_cpu = jax.devices()[0].platform == "cpu"
+            conv_model = bool(getattr(model, "conv_model", False))
             impl = "map" if (conv_model and on_cpu) else "vmap"
-        if impl not in ("vmap", "map"):
-            raise ValueError(f"sp_cohort_impl must be vmap|map|auto, got {impl!r}")
         if impl == "map":
             logger.info("sp engine: lax.map cohort (conv-on-CPU fallback)")
         self.cohort_impl = impl
         if self.fedsgd:
-            fn = make_grad_fn(model, args, cap)
-            if impl == "map":
-                self.cohort_fn = jax.jit(
-                    lambda gp, cx, cy, cn, rngs:
-                    jax.lax.map(lambda o: fn(gp, *o), (cx, cy, cn, rngs))
-                )
-            else:
-                self.cohort_fn = jax.jit(
-                    jax.vmap(fn, in_axes=(None, 0, 0, 0, 0))
-                )
+            fn = make_grad_fn(model, args, self.ds.cap)
         else:
-            fn = make_local_train_fn(model, args, cap, scaffold=self.scaffold)
-            if impl == "map":
-                if self.scaffold:
-                    self.cohort_fn = jax.jit(
-                        lambda gp, cx, cy, cn, rngs, cg, cls:
-                        jax.lax.map(
-                            lambda o: fn(gp, o[0], o[1], o[2], o[3], cg, o[4]),
-                            (cx, cy, cn, rngs, cls),
-                        )
-                    )
-                else:
-                    self.cohort_fn = jax.jit(
-                        lambda gp, cx, cy, cn, rngs:
-                        jax.lax.map(lambda o: fn(gp, *o), (cx, cy, cn, rngs))
-                    )
-            else:
-                axes = (None, 0, 0, 0, 0) + ((None, 0) if self.scaffold else ())
-                self.cohort_fn = jax.jit(jax.vmap(fn, in_axes=axes))
+            fn = make_local_train_fn(model, args, self.ds.cap,
+                                     scaffold=self.scaffold)
+        # (params, x, y, counts, rngs), and SCAFFOLD's (c_global, c_locals)
+        axes = (None, 0, 0, 0, 0) + ((None, 0) if self.scaffold else ())
+        self.cohort_fn = jax.jit(_over_cohort(fn, axes, impl))
 
         # server optimizer over pseudo-gradients (FedOpt family + FedSGD)
         self.server_opt = None
@@ -228,9 +207,8 @@ class FedAvgAPI:
         # queried from the device (60% of its memory limit, leaving room for
         # params/grads/cohort working set); 4 GB on XLA:CPU, which reports none.
         total_bytes = self.ds.train_x.nbytes + self.ds.train_y.nbytes
-        self.hbm_resident = self.hbm_resident_default and bool(
-            getattr(args, "hbm_resident", total_bytes < self._hbm_budget())
-        )
+        self.hbm_resident = (self.hbm_resident_default
+                             and total_bytes < self._hbm_budget())
         if (self.cohort_engine is not None
                 and max(int(getattr(args, "superround_k", 0) or 0), 0) <= 1):
             # registry rounds stream through the prefetcher — a resident
@@ -255,7 +233,7 @@ class FedAvgAPI:
             # cannot compose with a user ServerAggregator override. Silently
             # dropping either one would betray whoever configured it, so fail
             # fast. (Model attacks DO compose: they transform client rows
-            # before whatever aggregation runs — see _aggregate.)
+            # before whatever aggregation runs — see round_engine.)
             raise ValueError(
                 "enable_defense and a custom ServerAggregator are mutually "
                 f"exclusive: defense_type={self.defender.defense_type!r} "
@@ -268,28 +246,14 @@ class FedAvgAPI:
         )
         self.history: List[Dict[str, float]] = []
 
-        # -- fused round engine (round_engine.py): one donated XLA program per
-        # round. "auto" fuses whenever the config has no host-side hook that
-        # must run between cohort step and aggregation; "on" demands it (and
-        # errors on a blocked config); "off" keeps the legacy multi-dispatch
-        # path. Built lazily on first run_round so subclass __init__ (mesh's
-        # sharding setup) has completed.
+        # -- the round (round_engine.py), built lazily on the first round so
+        # that a subclass's __init__ (mesh's sharding setup) has completed:
+        # ``_round`` is what a round calls, ``_round_step`` the jitted,
+        # donated program, None where a host rule makes the round run eagerly
+        self._round = None
         self._round_step = None
         self._superround_step = None
         self._superround_k = max(int(getattr(args, "superround_k", 0) or 0), 0)
-        self._fusion_ready = False
-        mode = str(getattr(args, "round_fusion", "auto") or "auto").lower()
-        if mode not in ("auto", "on", "off"):
-            raise ValueError(f"round_fusion must be auto|on|off, got {mode!r}")
-        blockers = self._fusion_blockers()
-        if mode == "on" and blockers:
-            raise ValueError(
-                "round_fusion='on' but this config cannot fuse: "
-                + "; ".join(blockers)
-            )
-        self._fusion_enabled = mode != "off" and not blockers
-        if blockers and mode != "off":
-            logger.info("round fusion off: %s", "; ".join(blockers))
 
     # -- sampling (reference: fedavg_api.py:125-140) ------------------------
     def _cohort_size(self) -> int:
@@ -376,42 +340,60 @@ class FedAvgAPI:
         """Commit the round state's placement (mesh: replicated)."""
         return state
 
-    # -- fused round engine (round_engine.py) -------------------------------
-    def _fusion_blockers(self) -> List[str]:
-        """Host-side hooks that cannot live inside one jit'd program."""
-        blockers = []
-        if self.custom_aggregator is not None:
-            blockers.append("custom ServerAggregator (arbitrary Python)")
+    # -- the round (round_engine.py) ----------------------------------------
+    def _host_rule(self) -> Optional[Callable]:
+        """The aggregation rule that has to run as host Python, or None.
+
+        The one place that decides how a round is executed: with a rule the
+        round function is called eagerly, op by op, so that the rule gets
+        concrete arrays and client ids; without one it is jitted and donated.
+        A rule is called as ``rule(stacked, weights, rng, n_valid,
+        client_ids)`` on the real clients' rows, where the weighted average
+        or the defense would stand, and returns the aggregate.
+        """
+        if (self.custom_aggregator is not None
+                or type(self)._aggregate is not FedAvgAPI._aggregate):
+            return self._aggregate
         if (self.defender.is_defense_enabled()
                 and self.defender.defense_type == "wbc"):
-            blockers.append("FL-WBC defense (host-side per-client history)")
-        if type(self)._train_round is not FedAvgAPI._train_round:
-            blockers.append(
-                f"{type(self).__name__} overrides _train_round"
-            )
-        # round_engine inlines THIS class's aggregation; a subclass override
-        # (e.g. TurboAggregate's additive-share aggregation) would be
-        # silently bypassed by the fused mirror
-        if type(self)._aggregate is not FedAvgAPI._aggregate:
-            blockers.append(
-                f"{type(self).__name__} overrides _aggregate"
-            )
-        return blockers
+            from .round_engine import host_defense_rule
 
-    def _setup_round_fusion(self) -> None:
-        """Build the jit'd round programs once (lazily, post-subclass-init)."""
-        self._fusion_ready = True
-        if not self._fusion_enabled:
-            return
-        from .round_engine import make_fused_round_step, make_superround_step
+            return host_defense_rule(self)
+        return None
+
+    def _aggregate(self, stacked: PyTree, weights: jax.Array, rng,
+                   n_valid: int, client_ids) -> PyTree:
+        """Host aggregation rule (see ``_host_rule``): the user
+        ServerAggregator's hook chain over the real clients' rows. A subclass
+        that aggregates on the host overrides this (TurboAggregateAPI)."""
+        raw = [
+            (float(weights[i]), jax.tree.map(lambda x: x[i], stacked))
+            for i in range(n_valid)
+        ]
+        raw = self.custom_aggregator.on_before_aggregation(raw)
+        agg = self.custom_aggregator.aggregate(raw)
+        return self.custom_aggregator.on_after_aggregation(agg)
+
+    def _setup_round(self) -> None:
+        """Build the round once (lazily, post-subclass-init): one jitted,
+        donated program, or with a host rule the same function as it is."""
+        if type(self)._train_round is not FedAvgAPI._train_round:
+            return  # the subclass's round is the round (HierarchicalFLAPI)
+        from .round_engine import build_round_core, make_superround_step
 
         per = self._cohort_size()
         cohort0, wmask0 = self._pad_cohort(
             np.arange(per) % self.ds.client_num
         )
-        self._round_step = make_fused_round_step(
-            self, n_cohort=len(cohort0), n_valid=per
-        )
+        core = build_round_core(self, n_cohort=len(cohort0), n_valid=per)
+        if self._host_rule() is not None:
+            logger.info("round runs eagerly: its aggregation rule is host "
+                        "Python (custom aggregator, _aggregate override or "
+                        "FL-WBC)")
+            self._round = core  # no superround either: it scans the program
+            return
+        self._round_step = jax.jit(core, donate_argnums=(0,))
+        self._round = self._round_step
         if self._superround_k > 1:
             if self.hbm_resident and wmask0 is None:
                 self._superround_step = make_superround_step(
@@ -436,8 +418,8 @@ class FedAvgAPI:
         return state
 
     def _set_round_state(self, state: Dict) -> None:
-        """Adopt the round state returned by a donated program. The previous
-        buffers are CONSUMED by donation — never read them again."""
+        """Adopt the round state a round returned. A donated program has
+        CONSUMED the previous buffers — never read them again."""
         self.global_params = state["global_params"]
         if "server_opt_state" in state:
             self.server_opt_state = state["server_opt_state"]
@@ -446,25 +428,21 @@ class FedAvgAPI:
             self.c_locals = state["c_locals"]
 
     def run_round(self, round_idx: int) -> Dict[str, float]:
-        """One federated round: the fused single-program path when the config
-        allows it, the legacy multi-dispatch ``_train_round`` otherwise.
+        """One federated round.
 
         With ``--enable_tracking`` each round opens a telemetry RoundRecord
         (phase spans on the profiler's clock, rounds in flight, HBM, compile
         events) and may open or close a ``--profile_rounds`` jax.profiler
         window. Disabled, both are one boolean check. Neither waits for the
         device: the record's loss is read once it is ready."""
-        if not self._fusion_ready:
-            self._setup_round_fusion()
+        if self._round is None:
+            self._setup_round()
         with telemetry.phase("hooks"):
             telemetry.on_round_start(round_idx)
             rec = telemetry.begin_round(
                 round_idx, fused=self._round_step is not None
             )
-        if self._round_step is None:
-            out = self._train_round(round_idx)
-        else:
-            out = self._train_round_fused(round_idx)
+        out = self._train_round(round_idx)
         with telemetry.phase("record"):
             telemetry.end_round(rec, train_loss=out.get("train_loss"))
             telemetry.on_round_end(round_idx)
@@ -475,8 +453,8 @@ class FedAvgAPI:
         when the config compiled one for exactly ``k`` rounds, else a Python
         loop of single rounds. Returns ``{"train_loss": losses}`` with one
         (device-resident) loss per round."""
-        if not self._fusion_ready:
-            self._setup_round_fusion()
+        if self._round is None:
+            self._setup_round()
         if self._superround_step is not None and k == self._superround_k:
             with telemetry.phase("hooks"):
                 telemetry.on_round_start(start_round)
@@ -505,13 +483,9 @@ class FedAvgAPI:
             self.run_round(start_round + j)["train_loss"] for j in range(k)
         ]}
 
-    def _train_round_fused(self, round_idx: int) -> Dict[str, float]:
-        """One round as ONE donated device program (round_engine.py).
-
-        Returns train_loss as a DEVICE scalar — no host sync, tracked or
-        not. train() keeps dispatch asynchronous: while the device executes
-        round r, the host already samples and gathers round r+1's cohort.
-        """
+    def _round_inputs(self, round_idx: int) -> tuple:
+        """Sample, gather and place what the round function takes:
+        ``(state, cohort_idx, cx, cy, cn, rngs, wmask, round_rng)``."""
         with telemetry.phase("sample"):
             self._prepare_round()
             cohort, wmask = self._pad_cohort(self._client_sampling(round_idx))
@@ -523,194 +497,23 @@ class FedAvgAPI:
             wm = None if wmask is None else self._place(jnp.asarray(wmask))
             cohort_idx = jnp.asarray(cohort, jnp.int32)
             st = self._place_state(self._round_state())
+        return st, cohort_idx, cx, cy, cn, rngs, wm, round_rng
+
+    def _train_round(self, round_idx: int) -> Dict[str, float]:
+        """One round: sample, gather, then the round function
+        (round_engine.py), as one donated program or eagerly.
+
+        Returns train_loss as a DEVICE scalar — no host sync, tracked or
+        not. train() keeps dispatch asynchronous: while the device executes
+        round r, the host already samples and gathers round r+1's cohort.
+        A subclass may replace the whole round (HierarchicalFLAPI).
+        """
+        inputs = self._round_inputs(round_idx)
         with telemetry.phase("dispatch"):
-            state, metrics = self._round_step(
-                st, cohort_idx, cx, cy, cn, rngs, wm, round_rng,
-            )
+            state, metrics = self._round(*inputs)
             self._set_round_state(state)
             telemetry.record_lazy("examples", metrics.get("examples"))
         return {"train_loss": metrics["train_loss"]}
-
-    # -- one round (legacy multi-dispatch path; kept as the numerical
-    # -- reference the fusion parity tests compare against) -----------------
-    def _train_round(self, round_idx: int) -> Dict[str, float]:
-        rec = telemetry.current_record()
-        with telemetry.phase("sample"):
-            self._prepare_round()
-            cohort, wmask = self._pad_cohort(self._client_sampling(round_idx))
-            n_valid = len(cohort) if wmask is None else int(wmask.sum())
-        with telemetry.phase("gather"):
-            cx, cy, cn = self._gather_cohort(cohort)
-        with telemetry.phase("prep"):
-            if self.attacker.is_data_attack():
-                cx, cy = self.attacker.attack_data(cx, cy, n_valid)
-            round_rng = jax.random.fold_in(self.root_rng, round_idx)
-            rngs = self._place(jax.random.split(round_rng, len(cohort)))
-            wm = None if wmask is None else self._place(jnp.asarray(wmask))
-        t_dispatch = time.perf_counter()
-
-        if self.fedsgd:
-            with telemetry.phase("train"):
-                grads, metrics = self.cohort_fn(self.global_params, cx, cy, cn, rngs)
-            weights = metrics["num_samples"] if wm is None else metrics["num_samples"] * wm
-            if rec is not None:
-                rec.lazy["examples"] = weights.sum()
-            agg_grad = self._aggregate(grads, weights, round_rng, n_valid, cohort)
-            updates, self.server_opt_state = self.server_opt.update(
-                agg_grad, self.server_opt_state, self.global_params
-            )
-            import optax
-
-            self.global_params = optax.apply_updates(self.global_params, updates)
-            return {"train_loss": self._loss_sync(
-                metrics["train_loss"], wm, rec, t_dispatch)}
-
-        if self.scaffold:
-            c_cohort = jax.tree.map(lambda x: x[cohort], self.c_locals)
-            with telemetry.phase("train"):
-                stacked, metrics, new_c = self.cohort_fn(
-                    self.global_params, cx, cy, cn, rngs, self.c_global, c_cohort
-                )
-            # scatter back new control variates; update c_global by the mean
-            # delta scaled by cohort/total (SCAFFOLD option II). Only the
-            # n_valid real clients participate — padded rows are dropped.
-            real = cohort[:n_valid]
-            new_c_r = jax.tree.map(lambda x: x[:n_valid], new_c)
-            c_cohort_r = jax.tree.map(lambda x: x[:n_valid], c_cohort)
-            delta_c = jax.tree.map(
-                lambda n, o: (n - o).mean(0), new_c_r, c_cohort_r
-            )
-            scale = n_valid / self.ds.client_num
-            self.c_global = jax.tree.map(
-                lambda cg, d: cg + scale * d, self.c_global, delta_c
-            )
-            self.c_locals = jax.tree.map(
-                lambda all_c, nc: all_c.at[real].set(nc), self.c_locals, new_c_r
-            )
-        else:
-            with telemetry.phase("train"):
-                stacked, metrics = self.cohort_fn(self.global_params, cx, cy, cn, rngs)
-
-        weights = metrics["num_samples"] if wm is None else metrics["num_samples"] * wm
-        if rec is not None:
-            rec.lazy["examples"] = weights.sum()
-
-        if self.fednova:
-            # w_new = w_g - tau_eff * Σ p_i (w_g - w_i)/tau_i
-            tau = metrics["tau"]
-            p = weights / jnp.maximum(weights.sum(), 1e-12)
-            tau_eff = (p * tau).sum()
-            norm_dir = fednova_normalized_direction(self.global_params, stacked, tau)
-            d = weighted_average(norm_dir, weights)
-            self.global_params = jax.tree.map(
-                lambda g, dd: g - tau_eff * dd, self.global_params, d
-            )
-        else:
-            w_agg = self._aggregate(stacked, weights, round_rng, n_valid, cohort)
-            if self.opt_name == constants.FEDML_FEDERATED_OPTIMIZER_FEDOPT:
-                import optax
-
-                pg = pseudo_gradient(self.global_params, w_agg)
-                updates, self.server_opt_state = self.server_opt.update(
-                    pg, self.server_opt_state, self.global_params
-                )
-                self.global_params = optax.apply_updates(self.global_params, updates)
-            else:
-                self.global_params = w_agg
-
-        if self.dp is not None and self.dp.dp_type == "cdp":
-            self.global_params = self.dp.randomize_global(
-                self.global_params, jax.random.fold_in(round_rng, 7)
-            )
-        return {"train_loss": self._loss_sync(
-            metrics.get("train_loss"), wm, rec, t_dispatch)}
-
-    @staticmethod
-    def _loss_sync(values, wm, rec, t_dispatch: float) -> float:
-        """The unfused round's one wait: ``_masked_mean`` pulls a host float,
-        so this span absorbs the device time of everything dispatched since
-        ``t_dispatch``, and the record notes it as its dispatch latency."""
-        with telemetry.phase("loss_sync"):
-            loss = _masked_mean(values, wm)
-        if rec is not None:
-            rec.dispatch_latency_s = time.perf_counter() - t_dispatch
-        return loss
-
-    # -- aggregation with trust hooks ---------------------------------------
-    def _aggregate(
-        self, stacked: PyTree, weights: jax.Array, rng, n_valid: int = None,
-        client_ids=None,
-    ) -> PyTree:
-        """attack → defend → weighted-average → (local/central DP applied by
-        caller), all on the stacked [cohort, ...] arrays.
-
-        ``n_valid``: number of real (non-padding) leading rows. Zero-weight
-        padding is harmless to the weighted average, but rank-based defenses
-        (Krum, median, ...) and the attack kernels see every row — so the
-        trust paths slice to the real cohort first.
-        """
-        with telemetry.phase("aggregate"):
-            return self._aggregate_impl(stacked, weights, rng, n_valid,
-                                        client_ids)
-
-    def _aggregate_impl(
-        self, stacked: PyTree, weights: jax.Array, rng, n_valid: int = None,
-        client_ids=None,
-    ) -> PyTree:
-        if self.dp is not None and self.dp.dp_type == "ldp":
-            keys = jax.random.split(jax.random.fold_in(rng, 3), weights.shape[0])
-            stacked = jax.vmap(self.dp.randomize)(stacked, keys)
-        elif self.dp is not None and self.dp.dp_type == "cdp":
-            # bound per-client sensitivity before averaging; the noise is
-            # added to the aggregate by the caller (randomize_global)
-            stacked = self.dp.clip_client_updates(stacked, self.global_params)
-
-        n = int(weights.shape[0]) if n_valid is None else int(n_valid)
-
-        needs_flat = self.attacker.is_model_attack() or self.defender.is_defense_enabled()
-        if not needs_flat:
-            if self.custom_aggregator is not None:
-                return self._custom_aggregate(stacked, weights, n)
-            return weighted_average(stacked, weights)
-
-        # flatten to [n, dim] once for the attack/defense kernels; drop
-        # zero-weight padding rows so rank-based defenses see real clients
-        if n < weights.shape[0]:
-            stacked = jax.tree.map(lambda x: x[:n], stacked)
-            weights = weights[:n]
-        _, treedef, shapes = tree_flatten_to_vector(self.global_params)
-        flat = jax.vmap(lambda t: tree_flatten_to_vector(t)[0])(stacked)
-        gvec, _, _ = tree_flatten_to_vector(self.global_params)
-        if self.attacker.is_model_attack():
-            flat = self.attacker.attack_model(
-                flat, weights, jax.random.fold_in(rng, 1)
-            )
-        if self.defender.is_defense_enabled():
-            ids = None if client_ids is None else list(client_ids)[:n]
-            agg_vec = self.defender.defend(
-                flat, weights, gvec, jax.random.fold_in(rng, 2), client_ids=ids
-            )
-        elif self.custom_aggregator is not None:
-            # model attack + custom aggregator compose: the attack transformed
-            # the client rows, the user's rule aggregates whatever arrived
-            attacked = jax.vmap(
-                lambda v: tree_unflatten_from_vector(v, treedef, shapes)
-            )(flat)
-            return self._custom_aggregate(attacked, weights, int(weights.shape[0]))
-        else:
-            w = weights / jnp.maximum(weights.sum(), 1e-12)
-            agg_vec = (w[:, None] * flat).sum(0)
-        return tree_unflatten_from_vector(agg_vec, treedef, shapes)
-
-    def _custom_aggregate(self, stacked: PyTree, weights: jax.Array, n: int) -> PyTree:
-        """Run the user ServerAggregator's hook chain on the first n rows."""
-        raw = [
-            (float(weights[i]), jax.tree.map(lambda x: x[i], stacked))
-            for i in range(n)
-        ]
-        raw = self.custom_aggregator.on_before_aggregation(raw)
-        agg = self.custom_aggregator.aggregate(raw)
-        return self.custom_aggregator.on_after_aggregation(agg)
 
     # -- the training loop (reference: fedavg_api.py:65-123) ----------------
     # -- round checkpoint / resume ------------------------------------------
@@ -722,7 +525,7 @@ class FedAvgAPI:
     def _ckpt_state(self) -> Dict:
         # same structure as the donated round state; CheckpointManager.save
         # copies every leaf to host BEFORE the next round's donation can
-        # invalidate these buffers (tested in test_round_fusion.py)
+        # invalidate these buffers (tests/test_round_fusion.py)
         return self._round_state()
 
     def _maybe_resume(self, ckpt) -> int:
@@ -948,8 +751,8 @@ class FedAvgAPI:
                 # latency to ONE round, and the train loop's guard check
                 # commits + exits right after it
                 return 1
-        if not self._fusion_ready:
-            self._setup_round_fusion()
+        if self._round is None:
+            self._setup_round()
         if self._superround_step is None:
             return 1
         if telemetry.profiler_blocks_chunk(r, r + k):
@@ -964,19 +767,9 @@ class FedAvgAPI:
         return k
 
     def _finalize_history(self) -> None:
-        """Realize any still-on-device train_loss scalars (the fused path
-        keeps dispatch async — metrics are only pulled here or at evals)."""
+        """Realize any still-on-device train_loss scalars (dispatch stays
+        async — metrics are only pulled here or at evals)."""
         for e in self.history:
             tl = e.get("train_loss")
             if tl is not None and not isinstance(tl, float):
                 e["train_loss"] = float(np.asarray(tl))
-
-
-def _masked_mean(values, wmask) -> float:
-    """Mean of per-client scalars, ignoring zero-mask (padding) entries."""
-    if values is None:
-        return float("nan")
-    if wmask is None:
-        return float(jnp.mean(values))
-    return float((values * wmask).sum() / jnp.maximum(wmask.sum(), 1.0))
-

@@ -34,8 +34,9 @@ class TurboAggregateAPI(FedAvgAPI):
         self.q_bits = int(getattr(args, "ta_quantize_bits", 8))
         self.group_size = int(getattr(args, "ta_group_size", 2))
 
-    def _aggregate(self, stacked, weights, rng, n_valid=None, client_ids=None):
-        """Replace the trusted-server average with additive-share aggregation.
+    def _aggregate(self, stacked, weights, rng, n_valid, client_ids):
+        """The round's host aggregation rule (``FedAvgAPI._host_rule``):
+        replace the trusted-server average with additive-share aggregation.
 
         Each client i quantizes its weighted update and splits it into
         ``group_size`` additive shares mod p; share s goes to ring position
@@ -45,10 +46,7 @@ class TurboAggregateAPI(FedAvgAPI):
         """
         import jax.numpy as jnp
 
-        n = int(weights.shape[0]) if n_valid is None else int(n_valid)
-        if n < weights.shape[0]:
-            stacked = jax.tree.map(lambda x: x[:n], stacked)
-            weights = weights[:n]
+        n = n_valid  # the rows are the real clients: padding is dropped
         w = np.asarray(weights, np.float64)
         w = w / max(w.sum(), 1e-12)
         _, treedef, shapes = tree_flatten_to_vector(self.global_params)

@@ -76,7 +76,7 @@ class TestFedNLP:
         api = FedAvgAPI(args, fedml.get_device(args), ds, bundle)
         for r in range(int(args.comm_round)):
             args.round_idx = r
-            api._train_round(r)
+            api.run_round(r)
         spec = REGISTRY["fednlp_seq2seq"]
         src_len = (spec.seq_len - 1) // 2
         m = evaluate_generation(
@@ -148,7 +148,7 @@ class TestFederatedDetection224:
                                  iou_thresh=0.25)
         for r in range(int(args.comm_round)):
             args.round_idx = r
-            api._train_round(r)
+            api.run_round(r)
         from fedml_tpu.ml.detection_metrics import (
             collect_detection_logits, map_at_50,
         )

@@ -99,8 +99,7 @@ ALWAYS = {"local_train", "loss", "optimizer", "aggregate", "metrics"}
 def make_api(**kw):
     base = dict(dataset="synthetic", model="lr", client_num_in_total=8,
                 client_num_per_round=8, comm_round=2, epochs=1, batch_size=16,
-                learning_rate=0.1, frequency_of_the_test=100,
-                round_fusion="on")
+                learning_rate=0.1, frequency_of_the_test=100)
     base.update(kw)
     args = fedml.init(Arguments(overrides=base), should_init_logs=False)
     ds, od = data_mod.load(args)
@@ -109,15 +108,10 @@ def make_api(**kw):
 
 
 def lowered_round(api):
-    api._setup_round_fusion()
+    api._setup_round()
     if api._superround_step is not None:
         return api._superround_step.lower(api._round_state(), jnp.int32(0))
-    cohort, wmask = api._pad_cohort(api._client_sampling(0))
-    cx, cy, cn = api._gather_cohort(cohort)
-    rng = jax.random.fold_in(api.root_rng, 0)
-    return api._round_step.lower(
-        api._round_state(), jnp.asarray(cohort, jnp.int32), cx, cy, cn,
-        jax.random.split(rng, len(cohort)), wmask, rng)
+    return api._round_step.lower(*api._round_inputs(0))
 
 
 @pytest.mark.parametrize("config, built", [

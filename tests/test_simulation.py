@@ -146,7 +146,7 @@ class TestTrustHooks:
         ds, out_dim = data_mod.load(args)
         api = FedAvgAPI(args, fedml.get_device(args), ds,
                         model_mod.create(args, out_dim))
-        m = api._train_round(0)
+        m = api.run_round(0)
         assert np.isfinite(m["train_loss"])
 
 

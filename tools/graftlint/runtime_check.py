@@ -16,7 +16,7 @@ artifact. A round function is accepted when
 ``check_round_engine`` builds tiny FedAvg/FedOpt/SCAFFOLD configs the same
 way the parity tests do and verifies ``round_engine.build_round_core``'s
 program for each, so ``python -m tools.graftlint --runtime`` certifies the
-actual fused round path, not a model of it.
+actual round function, not a model of it.
 """
 
 from __future__ import annotations
@@ -123,7 +123,7 @@ def _tiny_api(overrides: dict):
     base = dict(
         dataset="synthetic", model="lr", client_num_in_total=8,
         client_num_per_round=4, comm_round=1, epochs=1, batch_size=8,
-        learning_rate=0.1, round_fusion="off",
+        learning_rate=0.1,
     )
     base.update(overrides)
     args = fedml.init(Arguments(overrides=base), should_init_logs=False)
@@ -136,10 +136,6 @@ def check_round_engine(repo_root: str) -> List[Finding]:
     """Trace ``build_round_core`` for the tiny reference configs."""
     sys.path.insert(0, repo_root)
     try:
-        import jax
-        import jax.numpy as jnp
-        import numpy as np
-
         from fedml_tpu.simulation.round_engine import build_round_core
     except Exception as e:  # pragma: no cover - env without the package
         # environment problem, not a lint finding — the CLI maps this to
@@ -154,18 +150,10 @@ def check_round_engine(repo_root: str) -> List[Finding]:
     for overrides in _CONFIGS:
         opt = overrides["federated_optimizer"]
         api = _tiny_api(overrides)
-        per = min(int(api.args.client_num_per_round), api.ds.client_num)
-        cohort = np.arange(per)
-        cx, cy, cn = api._gather_cohort(cohort)
-        rng = jax.random.fold_in(api.root_rng, 0)
-        rngs = jax.random.split(rng, per)
+        per = api._cohort_size()
         core = build_round_core(api, n_cohort=per, n_valid=per)
-        state = api._round_state()
         issues = trace_purity_issues(
-            core,
-            (state, jnp.asarray(cohort, jnp.int32), cx, cy, cn, rngs, None,
-             rng),
-            name=f"build_round_core[{opt}]",
+            core, api._round_inputs(0), name=f"build_round_core[{opt}]",
         )
         findings += [
             # line_text carries the issue so each distinct runtime failure

@@ -1,4 +1,4 @@
-"""graftrep: static determinism & round-equivalence verification.
+"""graftrep: static determinism verification.
 
 The fourth static-analysis suite (after graftlint/graftproto/graftshard),
 on the same shared driver (:mod:`tools.graftlint.clikit`):
@@ -8,11 +8,6 @@ on the same shared driver (:mod:`tools.graftlint.clikit`):
   dtype-promotion drift (D004), run-identity leaks into ledger state
   (D005) — the static enforcement of every bitwise guarantee the parity
   tests pin at runtime.
-- **--equiv** (imports jax): traces the unfused ``FedAvgAPI._train_round``
-  trust chain and ``round_engine.build_round_core``'s fused mirror under
-  ``jax.make_jaxpr``, canonicalizes both jaxprs, and diffs them — a
-  drifted mirror is a lint finding naming the first diverging equation,
-  not a silent wait for a parity test to notice.
 
 Entry points: ``python -m tools.graftrep`` / ``fedml_tpu lint --rep``.
 """

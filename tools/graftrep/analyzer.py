@@ -2,9 +2,8 @@
 
 Mirrors :func:`tools.graftshard.analyzer.analyze_paths_with_model`, with
 graftrep's own pragma marker (``# graftrep: disable=D001``) and baseline
-file (``tools/graftrep/baseline.json``). The default pass is pure AST —
-no jax import — so the tree gate stays sub-second; ``--equiv``
-(:mod:`equiv`) opts into jax.
+file (``tools/graftrep/baseline.json``). The pass is pure AST — no jax
+import — so the tree gate stays sub-second.
 """
 
 from __future__ import annotations

@@ -2,18 +2,9 @@
 
 Thin suite definition over the shared driver
 (:mod:`tools.graftlint.clikit` — flags, baseline handling, rendering, and
-the exit-code contract live there, shared with the three sibling suites).
+the exit-code contract live there, shared with the sibling suites).
 Exit codes: 0 clean (after baseline + pragmas), 1 findings, 2 usage error
-OR analyzer crash — that includes crashes inside the ``--equiv`` tracer.
-
-Extra over the siblings:
-
-- ``--equiv`` — trace the unfused ``FedAvgAPI._train_round`` trust chain
-  (attack → defend → aggregate → DP) and ``round_engine.build_round_core``'s
-  fused mirror under ``jax.make_jaxpr`` for FedAvg / FedOpt / SCAFFOLD,
-  canonicalize both jaxprs, and diff. A divergence is a finding naming the
-  first differing equation (imports jax; the default pass stays pure AST).
-  The per-config verdicts ride the JSON payload under ``"equiv"``.
+OR analyzer crash. The one pass is pure AST (no jax import).
 """
 
 from __future__ import annotations
@@ -27,51 +18,22 @@ from .analyzer import DEFAULT_BASELINE_RELPATH, analyze_paths
 from .findings import REP_RULES
 
 
-def _add_arguments(p: argparse.ArgumentParser) -> None:
-    p.add_argument("--equiv", action="store_true",
-                   help="also prove fused/unfused round structural "
-                        "equivalence: trace _train_round vs "
-                        "build_round_core under jax.make_jaxpr for "
-                        "FedAvg/FedOpt/SCAFFOLD, canonicalize, diff "
-                        "(imports jax)")
-
-
 def _analyze(args: argparse.Namespace,
              repo_root: str) -> Tuple[List[Finding], Dict]:
-    findings = analyze_paths(args.paths, repo_root=repo_root)
-    extra: Dict = {}
-    if args.equiv:
-        from .equiv import check_round_equivalence
-
-        try:
-            equiv_findings, report = check_round_equivalence(repo_root)
-        except RuntimeError as e:
-            raise clikit.SuiteUsageError(str(e)) from e
-        findings = findings + equiv_findings
-        extra["equiv"] = report
-        if args.format != "json":
-            for row in report:
-                status = ("MATCH" if row["equal"]
-                          else f"DIVERGED at eqn {row['diverges_at']}")
-                print(f"equiv[{row['optimizer']}]: {status} "
-                      f"({row['eqn_count_unfused']} unfused / "
-                      f"{row['eqn_count_fused']} fused eqns)")
-    return findings, extra
+    return analyze_paths(args.paths, repo_root=repo_root), {}
 
 
 def main(argv: Optional[List[str]] = None) -> int:
     return clikit.run_suite(
         argv,
         tool="graftrep",
-        description="static determinism & round-equivalence verification "
-                    "of the trust pipeline: PRNG-key discipline, seed "
-                    "provenance, unordered accumulation, dtype drift, "
-                    "run-identity leaks; --equiv proves the fused round "
-                    "mirror structurally equal to _train_round",
+        description="static determinism verification of the trust "
+                    "pipeline: PRNG-key discipline, seed provenance, "
+                    "unordered accumulation, dtype drift, run-identity "
+                    "leaks",
         rules=REP_RULES,
         analyze=_analyze,
         baseline_relpath=DEFAULT_BASELINE_RELPATH,
-        add_arguments=_add_arguments,
     )
 
 

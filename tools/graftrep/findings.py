@@ -4,8 +4,7 @@ Finding infrastructure so all four suites render/baseline/JSON identically.
 The D-rules statically enforce the repo's determinism discipline — the
 precondition for every bitwise guarantee the runtime parity tests pin
 (kill/restart parity, sync≡async at alpha=0, delta-shipped ≡ full
-broadcast). ``--equiv`` (see :mod:`equiv`) closes the other half: the fused
-round mirror must stay structurally identical to the unfused reference.
+broadcast).
 """
 
 from __future__ import annotations
@@ -52,13 +51,6 @@ REP_RULES: Dict[str, Tuple[str, str]] = {
         "(seed, config, round): route wall-clock/hostname/pid to logs or "
         "telemetry, never into commit_round/ensure_meta payloads or the "
         "round-state dicts a resume replays",
-    ),
-    "D006": (
-        "fused-unfused-round-divergence",
-        "the fused round mirror (round_engine.build_round_core) drifted "
-        "from the unfused reference (_train_round): re-align the mirror at "
-        "the named equation — or better, extract the shared chain into one "
-        "function both paths consume (the ROADMAP trust-pipeline refactor)",
     ),
 }
 

@@ -4,8 +4,7 @@
 #  - graftproto (P001–P009, comm-plane protocol + lock-order verification)
 #  - graftshard (S001–S005, sharding/HBM verification of the TPU
 #                execution plane)
-#  - graftrep   (D001–D006, determinism discipline + fused/unfused round
-#                equivalence of the trust pipeline)
+#  - graftrep   (D001–D005, determinism discipline of the trust pipeline)
 #  - graftiso   (I001–I005, serving-plane state ownership, tenant
 #                isolation & thread lifecycle)
 #  - graftmem   (M001–M005, serving-plane retention: bounded containers,

@@ -98,11 +98,11 @@ def measure_ours(model: str, rounds: int) -> float:
     bundle = model_mod.create(args, classes)
     api = FedAvgAPI(args, fedml.get_device(args), ds, bundle)
 
-    api._train_round(0)  # warmup round (compile)
+    api.run_round(0)  # warmup round (compile)
     jax.tree.leaves(api.global_params)[0].block_until_ready()
     t0 = time.perf_counter()
     for r in range(1, rounds + 1):
-        api._train_round(r)
+        api.run_round(r)
     jax.tree.leaves(api.global_params)[0].block_until_ready()
     return rounds / (time.perf_counter() - t0)
 

@@ -163,7 +163,7 @@ def run_ours_lr(fed, rounds, lr, epochs, per_round, optimizer="FedAvg",
 
     traj = []
     for r in range(rounds):
-        api._train_round(r)
+        api.run_round(r)
         W, b = _get_lr_params(api.global_params)
         traj.append(np.concatenate([W.ravel(), b.ravel()]))
     return np.stack(traj)
