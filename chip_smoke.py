@@ -314,9 +314,8 @@ def leg_fedavg(overrides: dict, run_dir: str) -> dict:
         raise AssertionError(f"fedavg: eval not finite: {result}")
     if api._round_step is None or not all(r["fused"] for r in records):
         raise AssertionError("fedavg: the round did not run fused")
-    if jax.devices()[0].platform == "tpu" and api.cohort_impl != "vmap":
-        raise AssertionError(
-            f"fedavg: cohort ran as {api.cohort_impl!r}, expected vmap")
+    if any(r["cohort_chunk"] != api.cohort_chunk for r in records):
+        raise AssertionError("fedavg: records lack the engine's cohort_chunk")
     out = {
         "ran": f"{type(api).__name__} {overrides['model']} on "
                f"{overrides['dataset']}, {overrides['client_num_in_total']} "
@@ -325,7 +324,7 @@ def leg_fedavg(overrides: dict, run_dir: str) -> dict:
         "data": "real files" if ds.meta.get("real_files") else
                 "synthetic fallback",
         "fused": True,
-        "cohort_impl": api.cohort_impl,
+        "cohort_chunk": api.cohort_chunk,
         "losses": [round(x, 4) for x in losses],
         "test_acc": round(float(result["test_acc"]), 4),
         "run_s": round(run_s, 2),

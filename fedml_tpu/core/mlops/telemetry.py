@@ -604,6 +604,9 @@ class RoundRecord:
     round_idx: int
     fused: bool = False
     superround: bool = False
+    # FedAvg-family rounds: clients of the cohort that train in one batched
+    # program (the cohort size where the cohort is one vmap)
+    cohort_chunk: Optional[int] = None
     phases: Dict[str, float] = dataclasses.field(default_factory=dict)
     # every span that closed under this record or after it, before the next
     # record opened: {name, span, parent, ts_ns (epoch), dur_ns}
@@ -659,8 +662,8 @@ def record_lazy(name: str, value: Any) -> None:
 
 
 def begin_round(round_idx: int, fused: bool = False,
-                superround: bool = False,
-                unit: str = "round") -> Optional[RoundRecord]:
+                superround: bool = False, unit: str = "round",
+                cohort_chunk: Optional[int] = None) -> Optional[RoundRecord]:
     """Open a RoundRecord; ``None`` (after one bool check) when disabled.
 
     Emits the earlier records whose device scalars are ready, in order, and
@@ -675,7 +678,7 @@ def begin_round(round_idx: int, fused: bool = False,
     _TLS.last = None  # the previous record takes no more spans
     drain_records(block=False)
     rec = RoundRecord(round_idx=int(round_idx), fused=fused,
-                      superround=superround)
+                      superround=superround, cohort_chunk=cohort_chunk)
     stack = _span_stack()
     rec.t0_ns = stack[-1].t0_ns if stack else time.perf_counter_ns()
     rec.in_flight = len(_PENDING)

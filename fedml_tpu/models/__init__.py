@@ -213,9 +213,12 @@ def create(args, output_dim: int) -> ModelBundle:
         task=task,
         meta={"dataset": dataset, "output_dim": output_dim},
     )
-    # convolutional families: consumed by the sp engine's cohort-impl
-    # heuristic (XLA:CPU lowers VMAPPED convs pathologically; lr/mlp on
-    # image datasets must NOT be demoted to lax.map by shape alone)
+    # convolutional families: read by the sp engine's cohort rule
+    # (sp_api.cohort_chunk_rule). jax.vmap over per-client kernels makes
+    # every convolution a grouped one, which the TPU compiler lowers with
+    # the cohort as one more spatial dimension, so such a model's cohort
+    # trains a few clients at a time; lr/mlp on an image dataset are
+    # batched matmuls and must not be chunked by input shape alone
     bundle.conv_model = name in CONV_MODEL_FAMILIES
     logger.info("model: %s for %s (output_dim=%d)", name, dataset, output_dim)
     return bundle

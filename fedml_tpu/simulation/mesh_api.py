@@ -62,10 +62,9 @@ class MeshFedAvgAPI(FedAvgAPI):
     # cohorts are host-gathered and placed sharded over the mesh — the
     # single-device HBM-resident fast path must not allocate in __init__
     hbm_resident_default = False
-    # the cohort axis is SHARDED over devices: lax.map would serialize the
-    # whole mesh onto one program — vmap is structural here, whatever the
-    # model and the platform
-    cohort_impl_default = "vmap"
+    # the cohort axis is SHARDED over devices: the cohort stays one vmap
+    # whatever the model (sp_api.cohort_chunk_rule)
+    cohort_sharded = True
 
     def __init__(self, args, device, dataset, model, client_trainer=None,
                  server_aggregator=None):

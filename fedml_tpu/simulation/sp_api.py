@@ -5,8 +5,9 @@ loop: per-client deepcopy → torch train → dict-average) and its per-optimize
 clones (``sp/fedopt``, ``sp/fedprox``, ``sp/fednova``, ``sp/fedsgd``) with ONE
 engine:
 
-- the round's cohort trains as ``vmap(local_train)`` over a stacked
-  ``[cohort, cap, ...]`` gather of the packed dataset
+- the round's cohort trains as ``local_train`` over a stacked
+  ``[cohort, cap, ...]`` gather of the packed dataset: one ``vmap``, or for a
+  convolutional model a few clients at a time (``cohort_chunk_rule``)
 - what happens to the cohort's results is ONE function,
   ``round_engine.build_round_core``: local DP / clipping → attack → defense or
   aggregation → server update → central DP (the reference's hook order). The
@@ -54,12 +55,42 @@ SERVER_OPT_FAMILY = (
 )
 
 
-def _over_cohort(fn: Callable, in_axes: tuple, impl: str) -> Callable:
+# Clients of a convolutional model's cohort that train in one batched program.
+# ``jax.vmap`` over per-client kernels makes every convolution a grouped one
+# (``feature_group_count`` = clients), and the TPU compiler realises the groups
+# as one more spatial dimension: a window of as many taps over an input dilated
+# as many times, of which one tap in each window meets a real value (v5e,
+# ResNet-56, cohort 10: ``window={size=3x3x10 stride=1x1x9 pad=1_1x1_1x0_0
+# lhs_dilate=1x1x10}, dim_labels=b012f_201io->b012f``, forward and backward; the
+# filter gradients ``size=32x32x10``). Its cost grows with the clients that
+# share it, faster than their number. Measured on the chip (PERF.md section 6,
+# PR 31: v5e, ResNet-56, cohort 10, rounds/s in `fedavg_resnet56_iid` / `_lda`):
+# 10 clients a convolution (one vmap) 1.425 / 0.845, 5: 1.613, 2: 2.643 / 1.568,
+# 1: 3.638 / 2.163, with `peak_hbm_gb` 5.37 -> 1.72 (a tenth of the temporaries).
+M_CONV = 1
+
+
+def cohort_chunk_rule(conv_model: bool, sharded: bool, cohort: int) -> int:
+    """How many of a cohort's clients train in one batched program; the
+    cohort size means one ``vmap`` over all of them. A convolutional model's
+    cohort runs ``M_CONV`` at a time (above); batched matmuls (lr, mlp, the
+    RNNs, the transformer heads) pay nothing for a cohort axis and stay one
+    ``vmap``, and so does a cohort axis that is sharded over a mesh, which
+    ``lax.map`` would serialize onto one program."""
+    if conv_model and not sharded:
+        return min(M_CONV, cohort)
+    return cohort
+
+
+def _over_cohort(fn: Callable, in_axes: tuple, chunk: int,
+                 cohort: int) -> Callable:
     """``fn`` for every client of a cohort: arguments whose ``in_axes`` entry
     is 0 carry a leading cohort axis, ``None`` marks one shared by all.
-    ``vmap`` makes one batched program, ``map`` runs the clients one after
-    another under ``lax.map``; identical math, the same stacked outputs."""
-    if impl == "vmap":
+    ``chunk`` clients at a time are batched by ``vmap`` and the chunks run one
+    after another under ``lax.map`` (a remainder as its own, smaller chunk);
+    a chunk of the whole cohort is a bare ``vmap``. Identical math, the same
+    stacked outputs."""
+    if chunk >= cohort:
         return jax.vmap(fn, in_axes=in_axes)
     stacked = [i for i, axis in enumerate(in_axes) if axis is not None]
 
@@ -70,7 +101,8 @@ def _over_cohort(fn: Callable, in_axes: tuple, impl: str) -> Callable:
         return fn(*args)
 
     return lambda *args: jax.lax.map(
-        lambda rows: one_client(args, rows), tuple(args[i] for i in stacked)
+        lambda rows: one_client(args, rows), tuple(args[i] for i in stacked),
+        batch_size=None if chunk == 1 else chunk,
     )
 
 
@@ -87,16 +119,9 @@ class FedAvgAPI:
     # so __init__ never parks a dead dataset copy in device-0 HBM
     hbm_resident_default = True
 
-    # cohort execution, decided in __init__ and readable as ``cohort_impl``:
-    # "vmap" batches the cohort into one program (the TPU design); "map" runs
-    # clients one after another under lax.map — identical math, same stacked
-    # outputs. "auto" takes map ONLY for conv models on XLA:CPU, where
-    # vmapped convs lower to a grouped-conv path ~100x slower than the plain
-    # conv (measured: resnet56 compiles >60 min and the same-substrate cnn
-    # leg ran 0.01x; lax.map keeps each conv un-grouped). The mesh engine
-    # pins vmap — its cohort axis is SHARDED over devices, and lax.map would
-    # serialize the whole mesh onto one program.
-    cohort_impl_default = "auto"
+    # is the cohort axis sharded over a mesh's devices (MeshFedAvgAPI)? Read
+    # by ``cohort_chunk_rule``; the engine's answer is ``self.cohort_chunk``
+    cohort_sharded = False
 
     @staticmethod
     def _hbm_budget() -> int:
@@ -168,14 +193,12 @@ class FedAvgAPI:
         self.fedsgd = self.opt_name == constants.FEDML_FEDERATED_OPTIMIZER_FEDSGD
         self.fednova = self.opt_name == constants.FEDML_FEDERATED_OPTIMIZER_FEDNOVA
 
-        impl = self.cohort_impl_default
-        if impl == "auto":
-            on_cpu = jax.devices()[0].platform == "cpu"
-            conv_model = bool(getattr(model, "conv_model", False))
-            impl = "map" if (conv_model and on_cpu) else "vmap"
-        if impl == "map":
-            logger.info("sp engine: lax.map cohort (conv-on-CPU fallback)")
-        self.cohort_impl = impl
+        per = self._cohort_size()
+        self.cohort_chunk = cohort_chunk_rule(
+            bool(getattr(model, "conv_model", False)), self.cohort_sharded,
+            per)
+        logger.info("sp engine: cohort of %d trains %d clients at a time",
+                    per, self.cohort_chunk)
         if self.fedsgd:
             fn = make_grad_fn(model, args, self.ds.cap)
         else:
@@ -183,7 +206,8 @@ class FedAvgAPI:
                                      scaffold=self.scaffold)
         # (params, x, y, counts, rngs), and SCAFFOLD's (c_global, c_locals)
         axes = (None, 0, 0, 0, 0) + ((None, 0) if self.scaffold else ())
-        self.cohort_fn = jax.jit(_over_cohort(fn, axes, impl))
+        self.cohort_fn = jax.jit(
+            _over_cohort(fn, axes, self.cohort_chunk, per))
 
         # server optimizer over pseudo-gradients (FedOpt family + FedSGD)
         self.server_opt = None
@@ -440,7 +464,8 @@ class FedAvgAPI:
         with telemetry.phase("hooks"):
             telemetry.on_round_start(round_idx)
             rec = telemetry.begin_round(
-                round_idx, fused=self._round_step is not None
+                round_idx, fused=self._round_step is not None,
+                cohort_chunk=self.cohort_chunk,
             )
         out = self._train_round(round_idx)
         with telemetry.phase("record"):
@@ -459,7 +484,8 @@ class FedAvgAPI:
             with telemetry.phase("hooks"):
                 telemetry.on_round_start(start_round)
                 rec = telemetry.begin_round(start_round, fused=True,
-                                            superround=True)
+                                            superround=True,
+                                            cohort_chunk=self.cohort_chunk)
             with telemetry.phase("dispatch"):
                 self._prepare_round()
                 state, scan_metrics = self._superround_step(

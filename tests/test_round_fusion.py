@@ -15,7 +15,7 @@ it is executed is decided from the configuration. Pinned here:
    commit, by hash.
 3. **Host rules**: a custom ``ServerAggregator``, FL-WBC, ``TurboAggregateAPI``
    and ``HierarchicalFLAPI`` run without a jitted round, everything else
-   with one; cohort execution (vmap / lax.map) follows model and platform.
+   with one.
 4. **Donation safety**: the round state really is donated (use-after-donate
    raises), and ``CheckpointManager.save`` copies every leaf to host BEFORE
    the next round's dispatch can invalidate the buffers — so checkpoint /
@@ -23,7 +23,12 @@ it is executed is decided from the configuration. Pinned here:
 5. **Recompilation regression guard**: steady state is ONE compile of the
    round program per (backend, optimizer) config — 5 rounds, cache
    size 1 (lowering-cache inspection via ``jit._cache_size()``).
-6. **Superround**: K rounds per launch under ``lax.scan`` with on-device
+6. **Cohort chunks**: the rule that says how many clients share one batched
+   program, as a pure function and through the three engines; chunked ==
+   ``vmap`` (a chunk that does not divide the cohort, SCAFFOLD's extra axes,
+   whole rounds); a convolutional model's lowered round holds no convolution
+   grouped over more than ``M_CONV`` clients.
+7. **Superround**: K rounds per launch under ``lax.scan`` with on-device
    sampling — under full participation (sampling degenerates to ``arange``
    on both paths) it matches single eager rounds exactly; eval/checkpoint
    schedules are preserved by the chunker; at most two programs compile.
@@ -35,6 +40,7 @@ import hashlib
 import re
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import orbax.checkpoint as ocp
 import pytest
@@ -44,10 +50,13 @@ from fedml_tpu import data as data_mod
 from fedml_tpu import models as model_mod
 from fedml_tpu.arguments import Arguments
 from fedml_tpu.ml.aggregator import DefaultServerAggregator
+from fedml_tpu.ml.local_train import make_local_train_fn
+from fedml_tpu.simulation import sp_api
 from fedml_tpu.simulation.hierarchical_api import HierarchicalFLAPI
 from fedml_tpu.simulation.mesh_api import MeshFedAvgAPI
 from fedml_tpu.simulation.round_engine import build_round_core
-from fedml_tpu.simulation.sp_api import FedAvgAPI
+from fedml_tpu.simulation.sp_api import (M_CONV, FedAvgAPI, _over_cohort,
+                                         cohort_chunk_rule)
 from fedml_tpu.simulation.turboaggregate_api import TurboAggregateAPI
 
 
@@ -227,19 +236,121 @@ class TestHostRules:
         assert not np.allclose(
             before, np.asarray(jax.tree.leaves(api.global_params)[0]))
 
-    @pytest.mark.parametrize("cls, kw, impl", [
-        (FedAvgAPI, dict(dataset="mnist", model="cnn", client_num_in_total=4,
-                         client_num_per_round=2, batch_size=8), "map"),
-        (FedAvgAPI, dict(), "vmap"),
-        (MeshFedAvgAPI, dict(dataset="mnist", model="cnn",
-                             client_num_in_total=8, client_num_per_round=8,
-                             batch_size=8), "vmap"),
-    ])
-    def test_cohort_execution_follows_model_and_platform(self, cls, kw, impl):
-        # tests run on XLA:CPU: a conv model takes lax.map, unless the
-        # cohort axis is sharded over a mesh
-        assert jax.devices()[0].platform == "cpu"
-        assert make_api(cls=cls, **kw).cohort_impl == impl
+
+# ---------------------------------------------------------------------------
+# How many clients of a cohort share one batched program
+# (``sp_api.cohort_chunk_rule`` and ``M_CONV``, which say why;
+# ``sp_api._over_cohort``)
+# ---------------------------------------------------------------------------
+
+CNN = dict(dataset="mnist", model="cnn", client_num_in_total=8, batch_size=8)
+
+
+@pytest.mark.parametrize("cohort", [1, 10, 50])
+@pytest.mark.parametrize("sharded", [False, True])
+@pytest.mark.parametrize("conv_model", [False, True])
+def test_cohort_chunk_rule(conv_model, sharded, cohort):
+    """A pure function of what the engine can observe, and not of the
+    platform: it takes none."""
+    got = cohort_chunk_rule(conv_model, sharded, cohort)
+    if conv_model and not sharded:
+        assert got == min(M_CONV, cohort) >= 1
+    else:
+        assert got == cohort  # one vmap over the whole cohort
+
+
+@pytest.mark.parametrize("cls, kw, chunk", [
+    (FedAvgAPI, dict(CNN, client_num_per_round=4), M_CONV),
+    (FedAvgAPI, dict(client_num_per_round=8), 8),
+    (FedAvgAPI, dict(client_num_per_round=8, federated_optimizer="SCAFFOLD"),
+     8),
+    # unless the cohort axis is sharded over a mesh
+    (MeshFedAvgAPI, dict(CNN, client_num_per_round=8), 8),
+])
+def test_engines_follow_the_rule(cls, kw, chunk):
+    assert make_api(cls=cls, **kw).cohort_chunk == chunk
+
+
+def cohort_outputs(api, chunk, cohort):
+    """``local_train`` over the first ``cohort`` clients, ``chunk`` at a
+    time, from the API's own data, parameters and keys."""
+    scaffold = api.scaffold
+    fn = make_local_train_fn(api.bundle, api.args, api.ds.cap,
+                             scaffold=scaffold)
+    axes = (None, 0, 0, 0, 0) + ((None, 0) if scaffold else ())
+    rows = np.arange(cohort)
+    inputs = [api.global_params, jnp.asarray(api.ds.train_x[rows]),
+              jnp.asarray(api.ds.train_y[rows]),
+              jnp.asarray(api.ds.train_counts[rows].astype(np.int32)),
+              jax.random.split(jax.random.PRNGKey(7), cohort)]
+    if scaffold:
+        # variates that differ by client and by leaf, so a wrong axis shows
+        key = jax.random.PRNGKey(11)
+        inputs.append(jax.tree.map(
+            lambda p: 0.01 * jax.random.normal(key, p.shape), api.c_global))
+        inputs.append(jax.tree.map(
+            lambda p: 0.01 * jax.random.normal(key, (cohort,) + p.shape),
+            api.c_global))
+    return jax.jit(_over_cohort(fn, axes, chunk, cohort))(*inputs)
+
+
+@pytest.mark.parametrize("name, kw, cohort, atol", [
+    ("convex", dict(), 5, 1e-5),
+    ("convex-scaffold", dict(federated_optimizer="SCAFFOLD"), 5, 1e-5),
+    # float additions inside a convolution may be ordered differently; a
+    # smaller cohort because XLA:CPU is slow on the vmapped side
+    ("cnn", dict(CNN), 3, 1e-4),
+    ("cnn-scaffold", dict(CNN, federated_optimizer="SCAFFOLD"), 3, 1e-4),
+])
+def test_chunked_cohort_equals_vmap(name, kw, cohort, atol):
+    """Chunks of 2 that do not divide the cohort (2 + 2 + 1 of 5, 2 + 1 of
+    3) and clients one at a time, against one vmap over the whole cohort."""
+    api = make_api(**kw)
+    want = cohort_outputs(api, cohort, cohort)
+    for chunk in (1, 2):
+        got = cohort_outputs(api, chunk, cohort)
+        assert jax.tree.structure(got) == jax.tree.structure(want)
+        for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            assert g.shape == w.shape and g.dtype == w.dtype
+            np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                       atol=atol, rtol=0)
+
+
+@pytest.mark.parametrize("opt", ["FedAvg", "SCAFFOLD", "FedSGD"])
+def test_chunked_rounds_equal_vmap_rounds(opt, monkeypatch):
+    """Three whole rounds, a cohort of 5 in chunks of 2 against one vmap:
+    every consumer of ``cohort_fn`` in the round gets the same stacked
+    outputs."""
+    kw = dict(federated_optimizer=opt, client_num_per_round=5)
+    whole = make_api(**kw)
+    monkeypatch.setattr(sp_api, "cohort_chunk_rule", lambda *a: 2)
+    chunked = make_api(**kw)
+    assert (whole.cohort_chunk, chunked.cohort_chunk) == (5, 2)
+    for r in range(3):
+        lw, lc = whole.run_round(r), chunked.run_round(r)
+        assert np.isclose(float(lw["train_loss"]), float(lc["train_loss"]),
+                          atol=1e-5)
+    assert chunked._round_step is not None
+    assert max_param_diff(whole, chunked) < 1e-5
+
+
+@pytest.mark.parametrize("sharded, widest", [(False, M_CONV), (True, 6)])
+def test_resnet_round_holds_no_cohort_wide_convolution(sharded, widest,
+                                                       monkeypatch):
+    """The lowered round of ``resnet20`` at a cohort of 6: no convolution is
+    grouped over more than ``M_CONV`` clients. (Told that the cohort axis is
+    sharded the same engine lowers every convolution grouped over all 6: the
+    count below sees what it is meant to see.)"""
+    monkeypatch.setattr(FedAvgAPI, "cohort_sharded", sharded)
+    api = make_api(dataset="cifar10", model="resnet20", client_num_in_total=8,
+                   client_num_per_round=6, batch_size=8)
+    assert api.cohort_chunk == widest
+    api._setup_round()
+    text = api._round_step.lower(*api._round_inputs(0)).as_text()
+    groups = [int(g) for g in
+              re.findall(r"feature_group_count = (\d+)", text)]
+    assert len(groups) >= 3 * 20  # forward, input and filter gradients
+    assert max(groups) == widest
 
 
 class TestDonationSafety:

@@ -88,6 +88,8 @@ class TestRoundRecords:
         assert [r["round_idx"] for r in recs] == [0, 1, 2, 3]
         for r in recs:
             assert r["fused"] is True
+            # a convex model's cohort of 8 is one vmap
+            assert r["cohort_chunk"] == api.cohort_chunk == 8
             # the fused loop waits nowhere, so there is no dispatch latency
             # to note and no device_wait phase; the record says instead how
             # many earlier rounds were still on the device when it opened
