@@ -23,7 +23,8 @@ import jax
 
 # parallel/transformer.py, parallel/moe.py: what a configuration adds to the
 # blocks of ``_train_step_raw``; absent from a step whose configuration has
-# no latent attention, hyper-connections, experts or MTP module
+# no latent attention, hyper-connections, experts, MTP module or
+# linear-attention layers
 TRAIN_STEP_BLOCKS: Tuple[str, ...] = (
     "mla",            # LatentAttention_N: low-rank projections, norms, scores
     "mhc",            # hyper-connection maps, stream reads and writes
@@ -32,6 +33,10 @@ TRAIN_STEP_BLOCKS: Tuple[str, ...] = (
     "moe_experts",    # dispatch gather, grouped products, combine
     "shared_expert",  # the expert every token passes (FeedForward "shared")
     "mtp",            # the multi-token-prediction module and its loss
+    "kda",            # KimiDeltaAttention_N: the linear-attention mixer
+    "kda_conv",       # inside kda: the short causal convolutions of q, k, v
+    "kda_gate",       # inside kda: the decay gate, beta and the output gate
+    "kda_chunk",      # inside kda: the chunked form (kda_chunk_fwd / _bwd)
 )
 
 # parallel/train_step.py: the jitted ``_train_step_raw``

@@ -12,7 +12,11 @@ constant only). One layer, a routing rule and a capacity rule:
   state no gradient reaches (the ``router_state`` collection; the trainer
   moves it after each step from the expert loads), gates from ``s`` alone,
   renormalised over the chosen and scaled by ``cfg.moe_routed_scale``; no
-  auxiliary loss. More than one choice renormalises under either rule.
+  auxiliary loss. With ``cfg.moe_n_group`` > 1 the selection is
+  group-limited: the experts are that many contiguous groups, a group scores
+  the sum of its two largest ``s + b``, and the top k are taken among the
+  ``cfg.moe_topk_group`` best groups. More than one choice renormalises
+  under either rule.
 - **capacity** (``cfg.moe_capacity_factor``). Above 0: GShard slots,
   ``factor * k * T / E`` an expert in an ``[E, capacity, D]`` buffer,
   over-capacity assignments dropped (they pass through the residual
@@ -62,6 +66,9 @@ def route(cfg: TransformerConfig, scores_in: jax.Array, bias=None):
     if cfg.moe_router == "sigmoid":
         scores = jax.nn.sigmoid(scores_in)
         select = scores if bias is None else scores + bias
+        if cfg.moe_n_group > 1:
+            select = _keep_best_groups(select, cfg.moe_n_group,
+                                       cfg.moe_topk_group)
         _, expert = jax.lax.top_k(select, k)
         aux = jnp.zeros((), jnp.float32)
     else:
@@ -79,6 +86,18 @@ def route(cfg: TransformerConfig, scores_in: jax.Array, bias=None):
     if k > 1:  # renormalised over the chosen
         gate = gate / jnp.maximum(gate.sum(-1, keepdims=True), 1e-9)
     return expert.astype(jnp.int32), gate * cfg.moe_routed_scale, aux
+
+
+def _keep_best_groups(select, n_group: int, kept: int):
+    """Selection scores [T, E] with every expert outside the ``kept`` best of
+    ``n_group`` contiguous groups at minus infinity; a group scores the sum
+    of its two largest selection scores (DeepSeek-V3)."""
+    T, E = select.shape
+    grouped = select.reshape(T, n_group, E // n_group)
+    group_score = jax.lax.top_k(grouped, 2)[0].sum(-1)          # [T, n_group]
+    _, best = jax.lax.top_k(group_score, kept)                  # [T, kept]
+    keep = (best[:, :, None] == jnp.arange(n_group)).any(1)     # [T, n_group]
+    return jnp.where(keep[:, :, None], grouped, -jnp.inf).reshape(T, E)
 
 
 class MoEFeedForward(nn.Module):

@@ -95,6 +95,10 @@ class PipelineCheetah:
             raise NotImplementedError(
                 "PipelineCheetah supports pos_emb='rope' only"
             )
+        if cfg.layer_group_size:
+            raise NotImplementedError(
+                "PipelineCheetah stacks one block: a mixer chosen per layer "
+                "(layer_group_size) does not run under it")
         self.schedule = schedule
         self.cfg = cfg
         self.mesh = mesh

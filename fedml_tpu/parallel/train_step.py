@@ -26,6 +26,7 @@ from ..core import mlops
 # names for the step's device work outside the flax modules
 from ..core.mlops.scopes import train_step_scope as _scope
 from .context import get_mesh_context, mesh_context, sequence_parallelism
+from .kda import KDA_CHUNK, scan_path
 from .mhc_streams import backward_path
 from .sharding import (
     FSDP,
@@ -299,6 +300,12 @@ class CheetahTrainer:
         # which backward the hyper-connected blocks' stream reads and writes
         # take, as mhc_streams decides it when the step is traced
         self.mhc_backward = backward_path(cfg.hc_mult, cfg.d_model, mesh)
+        # which form the part of a kda layer that is sequential over chunks
+        # takes, as parallel/kda.py decides it when the step is traced at
+        # sequences of cfg.max_seq_len ("" without such a layer)
+        self.kda_path = scan_path(
+            cfg.n_heads, cfg.kda_head_dim, cfg.kda_head_dim, cfg.max_seq_len,
+            KDA_CHUNK, mesh, seq_sharded) if "kda" in cfg.mixers else ""
 
         dummy = jnp.zeros((1, 8), jnp.int32)
         boxed_abstract = jax.eval_shape(
@@ -356,6 +363,15 @@ class CheetahTrainer:
             n_params / 1e6, dict(self.mesh.shape),
             self.loss_head_gathers_per_step, self.mhc_backward,
         )
+        if (self.kda_path == "xla"
+                and jax.devices()[0].platform == "tpu"):
+            logger.warning(
+                "cheetah init: the kda layers run the XLA form of the chunk "
+                "scan, not the Pallas kernels (mesh %s, sequence sharding "
+                "%s, %d heads of %d, %d tokens in chunks of %d): see "
+                "parallel/kda.scan_path", dict(self.mesh.shape),
+                self.seq_sharded, self.cfg.n_heads, self.cfg.kda_head_dim,
+                self.cfg.max_seq_len, KDA_CHUNK)
         mlops.log_cheetah_init(
             {k: int(v) for k, v in self.mesh.shape.items()},
             self.loss_head_gathers_per_step,
@@ -363,6 +379,8 @@ class CheetahTrainer:
             n_routed_experts=int(self.cfg.moe_experts),
             experts_held=int(self.cfg.experts_held),
             mhc_backward=self.mhc_backward,
+            mixers=",".join(self.cfg.mixers), kda_path=self.kda_path,
+            kda_chunk=KDA_CHUNK if self.kda_path else 0,
         )
         # step must be committed to the mesh (replicated) — a default-device
         # scalar breaks jit after checkpoint restore (mixed device sets)

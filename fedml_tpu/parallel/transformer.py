@@ -19,8 +19,11 @@ The same model class and layer loop also build what is not Llama's block, from
 the configuration alone (``TransformerConfig``): multi-head latent attention
 (``LatentAttention``), a layer list of leading dense layers then expert
 layers (``parallel/moe.py``), hyper-connected residual streams around every
-sublayer (``HyperConnection``) and a multi-token-prediction module. A
-configuration that names none of them builds the block it always built.
+sublayer (``HyperConnection``), a multi-token-prediction module, and a mixer
+chosen per layer (``cfg.mixers``): full attention every ``layer_group_size``
+layers and a gated delta-rule linear-attention mixer (``KimiDeltaAttention``,
+``parallel/kda.py``) in between. A configuration that names none of them
+builds the block it always built.
 """
 
 from __future__ import annotations
@@ -103,7 +106,8 @@ class TransformerConfig:
     # attention (LatentAttention): low-rank q and kv with their norms, a
     # query/key head of qk_nope_head_dim + qk_rope_head_dim of which only the
     # rope part is rotated (its key shared by all heads), a value head of
-    # v_head_dim
+    # v_head_dim. q_lora_rank 0: a full-rank query, ``q = x W_q``, no q norm.
+    # "kda": Kimi delta attention (KimiDeltaAttention), a linear mixer
     attn_kind: str = "gqa"
     q_lora_rank: int = 0
     kv_lora_rank: int = 0
@@ -121,6 +125,16 @@ class TransformerConfig:
     rope_beta_slow: float = 1.0
     rope_mscale: float = 1.0
     rope_mscale_all_dim: float = 0.0
+    # -- a mixer per layer ---------------------------------------------------
+    # G > 0: layer i runs ``attn_kind`` where (i + 1) % G == 0 and the linear
+    # mixer ("kda") elsewhere. 0: ``attn_kind`` in every layer
+    layer_group_size: int = 0
+    # KimiDeltaAttention: n_heads heads of kda_head_dim (keys and values
+    # alike), causal depthwise convolutions of kda_conv_size on q, k and v,
+    # a per-channel log decay in (kda_lower_bound, 0)
+    kda_head_dim: int = 0
+    kda_conv_size: int = 4
+    kda_lower_bound: float = -5.0
     # -- layer list ----------------------------------------------------------
     # with moe_experts > 1: this many leading dense layers (d_ff wide), then
     # expert layers. 0 = every layer an expert layer (the Switch stacks)
@@ -132,6 +146,12 @@ class TransformerConfig:
     # expert loads by moe_bias_rate), weights renormalised over the chosen
     # and scaled by moe_routed_scale
     moe_router: str = "softmax"
+    # sigmoid only: the experts are moe_n_group contiguous groups, a group
+    # scores the sum of its two largest selection scores, and the top k are
+    # taken among the moe_topk_group best groups (DeepSeek-V3's group-limited
+    # routing). 1 group: no group step
+    moe_n_group: int = 1
+    moe_topk_group: int = 1
     moe_routed_scale: float = 1.0
     moe_bias_rate: float = 1e-3
     # width of one expert (0 = d_ff) and shared experts of that width that
@@ -157,18 +177,44 @@ class TransformerConfig:
     mtp_weight: float = 0.3
 
     def __post_init__(self):
-        if self.attn_kind not in ("gqa", "mla"):
-            raise ValueError(f"attn_kind must be gqa|mla, got {self.attn_kind!r}")
+        if self.attn_kind not in ("gqa", "mla", "kda"):
+            raise ValueError(
+                f"attn_kind must be gqa|mla|kda, got {self.attn_kind!r}")
         if self.attn_kind == "mla" and not (
-                self.q_lora_rank > 0 and self.kv_lora_rank > 0
+                self.q_lora_rank >= 0 and self.kv_lora_rank > 0
                 and self.qk_nope_head_dim > 0 and self.qk_rope_head_dim > 0
                 and self.v_head_dim > 0):
             raise ValueError(
-                "attn_kind mla needs q_lora_rank, kv_lora_rank, "
-                "qk_nope_head_dim, qk_rope_head_dim and v_head_dim")
+                "attn_kind mla needs q_lora_rank (0: a full-rank query), "
+                "kv_lora_rank, qk_nope_head_dim, qk_rope_head_dim and "
+                "v_head_dim")
+        if self.layer_group_size < 0:
+            raise ValueError(
+                f"layer_group_size must be >= 0, got {self.layer_group_size}")
+        if self.layer_group_size and self.attn_kind == "kda":
+            raise ValueError(
+                "layer_group_size alternates the linear mixer with "
+                "attn_kind: name the full attention there (gqa|mla)")
+        if "kda" in self.mixers and not (
+                self.kda_head_dim > 0 and self.kda_conv_size >= 1
+                and self.kda_lower_bound < 0):
+            raise ValueError(
+                "a kda layer needs kda_head_dim > 0, kda_conv_size >= 1 and "
+                f"kda_lower_bound < 0, got ({self.kda_head_dim}, "
+                f"{self.kda_conv_size}, {self.kda_lower_bound})")
         if self.moe_router not in ("softmax", "sigmoid"):
             raise ValueError(
                 f"moe_router must be softmax|sigmoid, got {self.moe_router!r}")
+        if self.moe_n_group > 1:
+            E, n, kept = self.moe_experts, self.moe_n_group, self.moe_topk_group
+            if self.moe_router != "sigmoid":
+                raise ValueError("moe_n_group > 1 needs the sigmoid router")
+            if E % n or E // n < 2 or not 1 <= kept <= n \
+                    or self.moe_top_k > kept * (E // n):
+                raise ValueError(
+                    f"group-limited routing needs {n} groups of at least 2 "
+                    f"that divide {E} experts and moe_top_k {self.moe_top_k} "
+                    f"choices inside the {kept} groups kept")
         if self.mtp_layers not in (0, 1):
             raise ValueError(f"mtp_layers must be 0 or 1, got {self.mtp_layers}")
         held, E = self.moe_experts_held, self.moe_experts
@@ -196,6 +242,15 @@ class TransformerConfig:
         return ("dense",) * k + ("moe",) * (self.n_layers - k)
 
     @property
+    def mixers(self) -> Tuple[str, ...]:
+        """"gqa", "mla" or "kda" for each of the n_layers blocks, in order."""
+        G = self.layer_group_size
+        if not G:
+            return (self.attn_kind,) * self.n_layers
+        return tuple(self.attn_kind if (i + 1) % G == 0 else "kda"
+                     for i in range(self.n_layers))
+
+    @property
     def experts_held(self) -> int:
         return self.moe_experts_held or self.moe_experts
 
@@ -218,27 +273,36 @@ class TransformerConfig:
 def train_flops_per_token(cfg: TransformerConfig, seq_len: int) -> float:
     """Model FLOPs per token, forward + backward, of ``cfg`` as it is built:
     what the live ``cheetah.mfu_estimate`` gauge divides by. Per token
-    forward, one multiply-add = 2 FLOPs: the attention projections (fused
-    q, k, v and o, or MLA's five), causal scores and values (a query sees
-    ``(seq_len + 1) / 2`` keys on average), each layer's SwiGLU (dense, or
-    the router, the shared experts and the expected share of the routed
+    forward, one multiply-add = 2 FLOPs: each layer's mixer (fused q, k, v
+    and o with causal scores and values, a query seeing ``(seq_len + 1) / 2``
+    keys on average; MLA's projections, with or without a q-LoRA; or a KDA
+    layer, :func:`kda_forward_flops_per_token`), each layer's SwiGLU (dense,
+    or the router, the shared experts and the expected share of the routed
     experts held here), the hyper-connection maps and the output head (twice
     with an MTP module, which also adds a block and its projection). The
     embedding is a row gather and costs none; backward is twice the forward;
     recomputation under remat is not counted. ``benchmark/flops`` counts the
     same from the published keys."""
     D, H = cfg.d_model, cfg.n_heads
-    if cfg.attn_kind == "mla":
-        dqk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
-        proj = 2 * (D * cfg.q_lora_rank + cfg.q_lora_rank * H * dqk
-                    + D * (cfg.kv_lora_rank + cfg.qk_rope_head_dim)
-                    + cfg.kv_lora_rank * H * (cfg.qk_nope_head_dim
-                                              + cfg.v_head_dim)
-                    + H * cfg.v_head_dim * D)
-        attn = 2 * H * (dqk + cfg.v_head_dim) * (seq_len + 1) / 2
-    else:
+
+    def mixer(kind):
+        if kind == "kda":
+            from .kda import KDA_CHUNK
+
+            return kda_forward_flops_per_token(
+                D, H, cfg.kda_head_dim, cfg.kda_conv_size, KDA_CHUNK)
+        if kind == "mla":
+            dqk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+            q = (D * cfg.q_lora_rank + cfg.q_lora_rank * H * dqk
+                 if cfg.q_lora_rank else D * H * dqk)
+            proj = 2 * (q + D * (cfg.kv_lora_rank + cfg.qk_rope_head_dim)
+                        + cfg.kv_lora_rank * H * (cfg.qk_nope_head_dim
+                                                  + cfg.v_head_dim)
+                        + H * cfg.v_head_dim * D)
+            return proj + 2 * H * (dqk + cfg.v_head_dim) * (seq_len + 1) / 2
         proj = 2 * D * cfg.head_dim * (2 * H + 2 * cfg.n_kv_heads)
-        attn = 2 * 2 * H * cfg.head_dim * (seq_len + 1) / 2
+        return proj + 2 * 2 * H * cfg.head_dim * (seq_len + 1) / 2
+
     n = cfg.hc_mult
     hyper = 2 * 2 * (n * D) * (2 * n + n * n) if n > 1 else 0
     dense = 2 * 3 * D * cfg.d_ff
@@ -247,12 +311,28 @@ def train_flops_per_token(cfg: TransformerConfig, seq_len: int) -> float:
               + (cfg.moe_shared_experts + held) * 2 * 3 * D * cfg.expert_d_ff)
     head = 2 * D * cfg.vocab_size
     kinds = cfg.layer_kinds
-    forward = (len(kinds) * (proj + attn + hyper) + head
+    forward = (sum(mixer(m) for m in cfg.mixers) + len(kinds) * hyper + head
                + sum(expert if kind == "moe" else dense for kind in kinds))
     if cfg.mtp_layers:
-        forward += (proj + attn + hyper + 2 * 2 * D * D + head
+        forward += (mixer(cfg.attn_kind) + hyper + 2 * 2 * D * D + head
                     + (expert if cfg.moe_experts > 1 else dense))
     return 3.0 * forward
+
+
+def kda_forward_flops_per_token(d_model: int, heads: int, head_dim: int,
+                                conv_size: int, chunk: int) -> float:
+    """Forward FLOPs a token of one KDA layer: the projections (q, k, v and
+    the decay gate at ``heads x head_dim`` each, beta and the output gate at
+    ``heads``, the output back), the three depthwise convolutions, and per
+    head the chunked form's products at chunk length ``C``: the two ``C x C``
+    pair matrices, ``W`` and ``U`` through the inverse (``C^3 / 3``
+    multiply-adds by substitution), then against the state ``W S``, ``Qg S``,
+    ``Aqk U~`` and the state's update."""
+    hd, C = head_dim, chunk
+    proj = 2 * d_model * (4 * heads * hd + 2 * heads) + 2 * heads * hd * d_model
+    conv = 2 * conv_size * 3 * heads * hd
+    chunked = heads * (2 * C * 5 * hd + 2 * C * C / 3 + 2 * 3 * hd * hd)
+    return proj + conv + chunked
 
 
 def rms_norm(x: jax.Array, weight: jax.Array, eps: float) -> jax.Array:
@@ -705,8 +785,8 @@ class LatentAttention(nn.Module):
     """Multi-head latent attention (DeepSeek-V2's MLA), training form: keys
     and values are expanded per head and nothing is absorbed.
 
-    ``c_q = norm(x W_qa)``, ``q = c_q W_qb`` split per head into ``q_nope``
-    and ``q_rope``; ``(c_kv, k_rope) = split(x W_kva)`` with ``k_rope``
+    ``c_q = norm(x W_qa)``, ``q = c_q W_qb`` (``q = x W_q`` where
+    ``cfg.q_lora_rank`` is 0) split per head into ``q_nope`` and ``q_rope``; ``(c_kv, k_rope) = split(x W_kva)`` with ``k_rope``
     shared by all heads; ``(k_nope, v) = split(norm(c_kv) W_kvb)`` per head;
     rotary on ``q_rope`` and ``k_rope`` only; scores scaled by
     :func:`attention_scale`; ``o = concat(heads) W_o``."""
@@ -725,16 +805,23 @@ class LatentAttention(nn.Module):
             return self.param(name, nn.with_partitioning(init, axes), shape,
                               cfg.param_dtype).astype(cfg.dtype)
 
-        wq_a = weight("wq_a", (EMBED, LORA), (D, rq))
-        wq_b = weight("wq_b", (LORA, HEADS), (rq, H * (dn + dr)))
+        if rq:
+            wq_a = weight("wq_a", (EMBED, LORA), (D, rq))
+            wq_b = weight("wq_b", (LORA, HEADS), (rq, H * (dn + dr)))
+        else:  # a full-rank query: no low-rank pair, no q norm
+            wq = weight("wq", (EMBED, HEADS), (D, H * (dn + dr)))
         wkv_a = weight("wkv_a", (EMBED, LORA), (D, rkv + dr))
         wkv_b = weight("wkv_b", (LORA, HEADS), (rkv, H * (dn + dv)))
         wo = weight("wo", (HEADS, EMBED), (H * dv, D))
         B, L, _ = x.shape
         with _scope("mla"):
-            c_q = RMSNorm(cfg.norm_eps, name="q_norm")(
-                jnp.einsum("bld,dr->blr", x, wq_a))
-            q = jnp.einsum("blr,re->ble", c_q, wq_b).reshape(B, L, H, dn + dr)
+            if rq:
+                c_q = RMSNorm(cfg.norm_eps, name="q_norm")(
+                    jnp.einsum("bld,dr->blr", x, wq_a))
+                q = jnp.einsum("blr,re->ble", c_q, wq_b)
+            else:
+                q = jnp.einsum("bld,de->ble", x, wq)
+            q = q.reshape(B, L, H, dn + dr)
             c_kv, k_rope = jnp.split(
                 jnp.einsum("bld,dr->blr", x, wkv_a), [rkv], axis=-1)
             kv = jnp.einsum(
@@ -750,6 +837,109 @@ class LatentAttention(nn.Module):
                 [k_nope, jnp.broadcast_to(k_rope, (B, L, H, dr))], axis=-1)
             out = attend(cfg, q, k, v, mask, scale=attention_scale(cfg))
             return jnp.einsum("ble,ed->bld", out.reshape(B, L, H * dv), wo)
+
+
+def _kda_decay_bias_init(heads: int, head_dim: int, lower_bound: float):
+    """``dt_bias`` [heads * head_dim] such that at ``x W_f = 0`` the log
+    decay ``lower_bound * sigmoid(dt_bias)`` is Kimi Linear's at its own
+    initialisation: ``-A dt`` with ``A ~ U(1, 16)`` a head and ``dt`` log
+    uniform in (0.001, 0.1) a channel, so decays ``exp(g)`` from 0.2 to
+    0.999."""
+    def init(key, shape, dtype):
+        ka, kd = jax.random.split(key)
+        A = jax.random.uniform(ka, (heads, 1), minval=1.0, maxval=16.0)
+        dt = jnp.exp(jax.random.uniform(
+            kd, (heads, head_dim), minval=math.log(1e-3), maxval=math.log(0.1)))
+        share = jnp.clip(A * dt / -lower_bound, 1e-6, 1 - 1e-6)
+        return jnp.log(share / (1 - share)).reshape(shape).astype(dtype)
+
+    return init
+
+
+class KimiDeltaAttention(nn.Module):
+    """Kimi delta attention (Kimi Linear, arXiv:2510.26692): a linear mixer
+    whose state per head, ``S`` in ``R^{dk x dv}``, follows the gated delta
+    rule (``parallel/kda.py`` has the recurrence and its chunked form). Per
+    token ``x_t`` and head ``h`` of ``cfg.n_heads``, ``dk = dv =
+    cfg.kda_head_dim``::
+
+        q = l2norm(silu(conv(x W_q))) / sqrt(dk),  k = l2norm(silu(conv(x W_k)))
+        v = silu(conv(x W_v))
+        g = kda_lower_bound * sigmoid(exp(A_log_h) (x W_f + dt_bias))   [dk]
+        beta = sigmoid(x W_b)_h
+        o = the recurrence's output for (q, k, v, g, beta)
+        y = concat_h(RMSNorm(o_h) * sigmoid(x W_g)_h) W_o
+
+    ``conv`` is a causal depthwise convolution over the sequence
+    (``cfg.kda_conv_size`` taps, one filter a channel); there are as many
+    key / value heads as query heads; the layer takes no positions. The
+    decay, beta, the norms and the state are float32, the projections and
+    the chunked form's products in ``cfg.dtype``."""
+
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, x, cos=None, sin=None, mask=None):
+        from .kda import kda_chunked
+
+        del cos, sin  # the recurrence orders the tokens; no rotary here
+        if mask is not None:
+            raise NotImplementedError(
+                "a kda layer takes whole sequences: no padding mask")
+        cfg = self.cfg
+        D, H, hd, K = cfg.d_model, cfg.n_heads, cfg.kda_head_dim, cfg.kda_conv_size
+        init = nn.initializers.normal(0.02)
+
+        def weight(name, axes, shape, init=init, dtype=cfg.param_dtype):
+            return self.param(name, nn.with_partitioning(init, axes), shape, dtype)
+
+        wqkv = weight("wqkv", (EMBED, HEADS), (D, 3 * H * hd)).astype(cfg.dtype)
+        wf = weight("wf", (EMBED, HEADS), (D, H * hd)).astype(cfg.dtype)
+        # beta and the output gate, one scalar a head each: [D, 2 H]
+        wbg = weight("wbg", (EMBED, HEADS), (D, 2 * H)).astype(cfg.dtype)
+        wo = weight("wo", (HEADS, EMBED), (H * hd, D)).astype(cfg.dtype)
+        # torch's Conv1d default for a depthwise filter: U(+-1 / sqrt(taps))
+        conv = weight(
+            "conv", (None, HEADS), (K, 3 * H * hd),
+            init=lambda key, shape, dtype: jax.random.uniform(
+                key, shape, dtype, -K ** -0.5, K ** -0.5))
+        A_log = weight("A_log", (None,), (H,), nn.initializers.zeros,
+                       jnp.float32)
+        dt_bias = weight("dt_bias", (None,), (H * hd,), _kda_decay_bias_init(
+            H, hd, cfg.kda_lower_bound), jnp.float32)
+        o_norm = weight("o_norm", (None,), (hd,), nn.initializers.ones,
+                        jnp.float32)
+        B, L, _ = x.shape
+        with _scope("kda"):
+            qkv = jnp.einsum("bld,de->ble", x, wqkv)
+            with _scope("kda_conv"):
+                # tap j of K multiplies the token K - 1 - j places back
+                taps = conv.astype(cfg.dtype)
+                padded = jnp.pad(qkv, ((0, 0), (K - 1, 0), (0, 0)))
+                qkv = nn.silu(sum(taps[j] * padded[:, j:j + L]
+                                  for j in range(K)))
+                q, k, v = (a.reshape(B, L, H, hd).astype(jnp.float32)
+                           for a in jnp.split(qkv, 3, axis=-1))
+                q = q * jax.lax.rsqrt((q * q).sum(-1, keepdims=True)
+                                      + 1e-6) * hd ** -0.5
+                k = k * jax.lax.rsqrt((k * k).sum(-1, keepdims=True) + 1e-6)
+            with _scope("kda_gate"):
+                f = jnp.einsum("bld,de->ble", x, wf,
+                               preferred_element_type=jnp.float32)
+                g = cfg.kda_lower_bound * jax.nn.sigmoid(
+                    jnp.repeat(jnp.exp(A_log), hd) * (f + dt_bias))
+                bg = jax.nn.sigmoid(jnp.einsum(
+                    "bld,de->ble", x, wbg, preferred_element_type=jnp.float32))
+                beta, out_gate = bg[..., :H], bg[..., H:]
+            with _scope("kda_chunk"):
+                o, _ = kda_chunked(q, k, v, g.reshape(B, L, H, hd), beta,
+                                   dtype=cfg.dtype)
+            o = o.astype(jnp.float32)
+            o = (o * jax.lax.rsqrt((o * o).mean(-1, keepdims=True)
+                                   + cfg.norm_eps) * o_norm
+                 * out_gate[..., None])
+            return jnp.einsum("ble,ed->bld",
+                              o.astype(cfg.dtype).reshape(B, L, H * hd), wo)
 
 
 class FeedForward(nn.Module):
@@ -862,7 +1052,8 @@ class HyperConnection(nn.Module):
 
 
 class Block(nn.Module):
-    """One decoder block: attention, then a feed-forward or expert layer,
+    """One decoder block: a mixer (attention of the configuration's kind, or
+    the linear mixer), then a feed-forward or expert layer,
     each a pre-norm residual sublayer (``x + F(norm(x))``), or, where
     ``cfg.hc_mult`` > 1, a hyper-connected one over the residual streams
     (``x``: [B, n, L, C])."""
@@ -872,12 +1063,16 @@ class Block(nn.Module):
     # pipeline's and the Switch stacks' uniform blocks); the Transformer's
     # layer list says it per layer
     moe: Optional[bool] = None
+    # None: ``cfg.attn_kind`` (uniform blocks, the MTP module's block); the
+    # Transformer's ``cfg.mixers`` says it per layer
+    mixer: Optional[str] = None
 
     @nn.compact
     def __call__(self, x, cos, sin, mask=None):
         cfg = self.cfg
         moe = cfg.moe_experts > 1 if self.moe is None else self.moe
-        attn_cls = LatentAttention if cfg.attn_kind == "mla" else Attention
+        attn_cls = {"gqa": Attention, "mla": LatentAttention,
+                    "kda": KimiDeltaAttention}[self.mixer or cfg.attn_kind]
 
         def attention(h):
             return attn_cls(cfg)(h, cos, sin, mask)
@@ -1013,9 +1208,10 @@ class Transformer(nn.Module):
         x = _constrain_batch_activations(_to_streams(x, cfg.hc_mult))
 
         block_cls = _block_class(cfg)
-        for kind in cfg.layer_kinds:
+        for kind, mixer in zip(cfg.layer_kinds, cfg.mixers, strict=True):
             x = _constrain_batch_activations(
-                block_cls(cfg, moe=kind == "moe")(x, cos, sin, mask)
+                block_cls(cfg, moe=kind == "moe", mixer=mixer)(
+                    x, cos, sin, mask)
             )
         if cfg.hc_mult > 1:
             x = x.sum(axis=1)  # the streams are summed before the final norm

@@ -79,9 +79,21 @@ def test_train_step_names_what_a_configuration_adds_to_the_blocks():
         hc_sinkhorn_iters=2, moe_experts=4, moe_top_k=2, moe_router="sigmoid",
         moe_capacity_factor=0.0, moe_d_ff=32, moe_shared_experts=1,
         first_k_dense=1, mtp_layers=1))
-    assert set(scopes.TRAIN_STEP) - names == {"grad_accum"}
+    kda = {"kda", "kda_conv", "kda_gate", "kda_chunk"}  # no such layer here
+    assert set(scopes.TRAIN_STEP) - names == {"grad_accum"} | kda
     assert {"LatentAttention_0", "HyperConnection_0", "MoEFeedForward_0",
             "FeedForward_0", "mtp"} <= names
+
+
+def test_train_step_names_the_linear_mixer_beside_latent_attention():
+    """A stack whose mixer is chosen per layer: the KDA layers' scopes and
+    the latent-attention layer's, each under its module."""
+    names = scope_names(lowered_step(
+        1, n_layers=2, attn_kind="mla", kv_lora_rank=16, qk_nope_head_dim=16,
+        qk_rope_head_dim=8, v_head_dim=16, layer_group_size=2,
+        kda_head_dim=16))
+    assert {"kda", "kda_conv", "kda_gate", "kda_chunk", "mla",
+            "KimiDeltaAttention_0", "LatentAttention_0"} <= names
 
 
 def test_train_step_learned_positions_are_embed_and_rope():
