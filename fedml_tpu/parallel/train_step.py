@@ -300,9 +300,10 @@ class CheetahTrainer:
         # which backward the hyper-connected blocks' stream reads and writes
         # take, as mhc_streams decides it when the step is traced
         self.mhc_backward = backward_path(cfg.hc_mult, cfg.d_model, mesh)
-        # which form the part of a kda layer that is sequential over chunks
-        # takes, as parallel/kda.py decides it when the step is traced at
-        # sequences of cfg.max_seq_len ("" without such a layer)
+        # which form the chunked part of a kda layer takes, the preparation
+        # and the scan over chunks alike, as parallel/kda.py decides it when
+        # the step is traced at sequences of cfg.max_seq_len ("" without
+        # such a layer)
         self.kda_path = scan_path(
             cfg.n_heads, cfg.kda_head_dim, cfg.kda_head_dim, cfg.max_seq_len,
             KDA_CHUNK, mesh, seq_sharded) if "kda" in cfg.mixers else ""
@@ -366,9 +367,10 @@ class CheetahTrainer:
         if (self.kda_path == "xla"
                 and jax.devices()[0].platform == "tpu"):
             logger.warning(
-                "cheetah init: the kda layers run the XLA form of the chunk "
-                "scan, not the Pallas kernels (mesh %s, sequence sharding "
-                "%s, %d heads of %d, %d tokens in chunks of %d): see "
+                "cheetah init: the kda layers run the XLA forms of the chunk "
+                "preparation and scan, not the Pallas kernels (mesh %s, "
+                "sequence sharding %s, %d heads of %d, %d tokens in chunks of "
+                "%d): see "
                 "parallel/kda.scan_path", dict(self.mesh.shape),
                 self.seq_sharded, self.cfg.n_heads, self.cfg.kda_head_dim,
                 self.cfg.max_seq_len, KDA_CHUNK)

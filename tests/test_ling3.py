@@ -7,6 +7,7 @@ layer against the whole, and that a configuration without the new fields
 builds the program it built. CPU, small sizes."""
 
 import dataclasses
+import functools
 import hashlib
 import re
 import types
@@ -21,6 +22,7 @@ from fedml_tpu.core import mlops
 from fedml_tpu.parallel import kda
 from fedml_tpu.parallel import moe as moe_mod
 from fedml_tpu.parallel import transformer as tfm
+from fedml_tpu.parallel.context import mesh_context
 from fedml_tpu.parallel.sharding import make_mesh, unbox
 from fedml_tpu.parallel.train_step import CheetahTrainer
 from fedml_tpu.parallel.transformer import Transformer, TransformerConfig
@@ -204,6 +206,120 @@ def test_kernels_are_the_plain_scan_and_the_recurrence(dtype, interpreted_kernel
             assert _rel(got, want) < 1e-4
 
 
+# --- the intra-chunk preparation as kernels ---------------------------------
+
+FILLS_THE_BLOCKS = (1, 1024, 4, 128, 128)   # B, T, H, dk, dv
+PREPARED = ("Qg", "Kd", "W", "U", "Aqk", "d")
+DTYPES = {"float32": jnp.float32, "bfloat16": jnp.bfloat16}
+
+
+@functools.lru_cache(maxsize=None)
+def prepared(dtype, gate, beta):
+    """The plain preparation and the interpreted kernels on the same seeded
+    inputs, and both backward passes at the same seeded cotangents (the
+    bfloat16 case also carries the float32 gradients)."""
+    dt = DTYPES[dtype]
+    inputs = kda_inputs(1, *FILLS_THE_BLOCKS, gate, beta)
+    want, vjp = jax.vjp(
+        lambda *a: kda.prepare_plain(*a, kda.KDA_CHUNK, dt), *inputs)
+    got = kda.intra_fwd(*inputs, kda.KDA_CHUNK, dt, interpret=True)
+    cotangents = tuple(
+        jax.random.normal(jax.random.PRNGKey(10 + i), w.shape).astype(w.dtype)
+        for i, w in enumerate(want))
+    grads = kda.intra_bwd(*inputs, *cotangents, kda.KDA_CHUNK, dt,
+                          interpret=True)
+    exact = None
+    if dt != jnp.float32:
+        exact = jax.vjp(lambda *a: kda.prepare_plain(
+            *a, kda.KDA_CHUNK, jnp.float32), *inputs)[1](
+                tuple(c.astype(jnp.float32) for c in cotangents))
+    return got, want, grads, vjp(cotangents), exact
+
+
+CASES = [(d, g, b) for d in DTYPES for g in ("random", "bound")
+         for b in ("random", "near_one", "near_zero")]
+
+
+@pytest.mark.parametrize("dtype,gate,beta", CASES)
+def test_intra_fwd_is_the_plain_preparation(dtype, gate, beta):
+    """``kda_intra_fwd`` under the interpreter, at sizes that fill its
+    blocks, output by output."""
+    got, want, _, _, _ = prepared(dtype, gate, beta)
+    tol = 1e-5 if dtype == "float32" else 2e-2
+    for name, a, b in zip(PREPARED, got, want):
+        assert a.shape == b.shape and a.dtype == b.dtype, name
+        assert bool(jnp.isfinite(a.astype(jnp.float32)).all()), name
+        assert _rel(a.astype(jnp.float32), b.astype(jnp.float32)) < tol, name
+
+
+@pytest.mark.parametrize("dtype,gate,beta", CASES)
+def test_intra_bwd_is_the_plain_preparations_vjp(dtype, gate, beta):
+    """``kda_intra_bwd``, written by hand, against autodiff of the plain
+    preparation at seeded cotangents of all six outputs. The log decay's
+    gradient is a sum of terms that nearly cancel: 1e-3 in float32; in
+    bfloat16 the two roundings differ by more than that, so there it is held
+    to the float32 gradient, no further from it than autodiff's own bfloat16."""
+    _, _, got, want, exact = prepared(dtype, gate, beta)
+    tol = 1e-5 if dtype == "float32" else 2e-2
+    for i, (name, a, b) in enumerate(zip("q k v g beta".split(), got, want)):
+        assert a.shape == b.shape and a.dtype == b.dtype, name
+        assert bool(jnp.isfinite(a).all()), name
+        if name != "g":
+            assert _rel(a, b) < tol, name
+        elif dtype == "float32":
+            assert _rel(a, b) < 1e-3
+        else:
+            assert _rel(a, exact[i]) < 1.1 * _rel(b, exact[i]) + 1e-3
+
+
+@pytest.fixture
+def every_kernel_interpreted(monkeypatch):
+    """The path a TPU takes, both pairs of kernels under their own
+    ``custom_vjp`` rules, run by Pallas' interpreter on this CPU."""
+    monkeypatch.setattr(kda, "scan_path", lambda *a, **k: "fused")
+    for name in ("intra_fwd", "intra_bwd", "chunk_fwd", "chunk_bwd"):
+        monkeypatch.setattr(kda, name, functools.partial(
+            getattr(kda, name), interpret=True))
+
+
+@pytest.mark.parametrize("gate", ["random", "bound"])
+def test_every_kernel_is_the_recurrence(gate, every_kernel_interpreted):
+    """``kda_chunked`` as ``scan_path`` = ``fused`` builds it, in float32
+    against the token recurrence: output, final state and every gradient."""
+    B, T, H, dk, dv = FILLS_THE_BLOCKS
+    inputs = kda_inputs(2, B, T, H, dk, dv, gate)
+    weights = (jax.random.normal(jax.random.PRNGKey(9), (B, T, H, dv)),
+               jax.random.normal(jax.random.PRNGKey(8), (B, H, dk, dv)))
+    (o, S), grads = _both(recurrence, weights, *inputs)
+    (o2, S2), grads2 = _both(kda.kda_chunked, weights, *inputs)
+    assert _rel(o2, o) < 1e-5 and _rel(S2, S) < 1e-5
+    for name, got, want in zip("q k v g beta".split(), grads2, grads):
+        assert bool(jnp.isfinite(got).all()), name
+        assert _rel(got, want) < (1e-3 if name == "g" else 1e-4), name
+
+
+@pytest.mark.parametrize("size,scale", [(16, 0.2), (64, 0.2), (64, 0.5),
+                                        (128, 0.1)])
+def test_the_kernels_inverse_is_the_inverse(size, scale):
+    """``_inverse_tiles`` inside an interpreted kernel against
+    ``jnp.linalg.inv``: tiles of one and of several sub-blocks, and entries
+    large enough that the inverse's reach the hundreds."""
+    from jax.experimental import pallas as pl
+
+    A = jnp.tril(scale * jax.random.normal(
+        jax.random.PRNGKey(0), (3, size, size)), -1)
+
+    def kernel(a_ref, t_ref):
+        (t_ref[0],) = kda._inverse_tiles([a_ref[0]])
+
+    spec = pl.BlockSpec((1, size, size), lambda i: (i, 0, 0))
+    T = pl.pallas_call(kernel, grid=(3,), in_specs=[spec], out_specs=spec,
+                       out_shape=jax.ShapeDtypeStruct(A.shape, A.dtype),
+                       interpret=True)(A)
+    assert _rel(T, jnp.linalg.inv(jnp.eye(size) + A)) < 1e-4
+    assert _rel(T, kda.unit_lower_inverse(A)) < 1e-4
+
+
 def test_scan_path_reads_its_inputs(monkeypatch):
     one = make_mesh(None, devices=jax.devices()[:1])
     four = make_mesh({"fsdp": 4}, devices=jax.devices()[:4])
@@ -217,6 +333,52 @@ def test_scan_path_reads_its_inputs(monkeypatch):
     assert kda.scan_path(32, 64, 128, 8192, 64, None) == "xla"   # lanes
     assert kda.scan_path(2, 128, 128, 8192, 64, None) == "xla"   # heads a step
     assert kda.scan_path(32, 128, 128, 256, 64, None) == "xla"   # chunks a step
+
+
+def test_one_decision_takes_both_pairs_of_kernels(monkeypatch):
+    """Where ``scan_path`` answers ``fused`` the preparation and the scan are
+    the kernels, where ``xla`` neither is: the step lowered for a TPU calls
+    all four by name, or none."""
+    four = make_mesh({"fsdp": 4}, devices=jax.devices()[:4])
+    monkeypatch.setattr(
+        jax, "devices", lambda *a: [types.SimpleNamespace(platform="tpu")])
+    called = []
+
+    def recorded(name, plain):
+        def f(*a):
+            called.append(name)
+            return plain(*a)
+        return f
+
+    monkeypatch.setattr(kda, "prepare_fused",
+                        recorded("prepare_fused", kda.prepare_plain))
+    monkeypatch.setattr(kda, "_scan_fused",
+                        recorded("_scan_fused", kda._scan_plain))
+    fills = kda_inputs(0, 1, 512, 4, 128, 128)
+    assert kda.scan_path(4, 128, 128, 512, 64, None) == "fused"
+    kda.kda_chunked(*fills)
+    assert called == ["prepare_fused", "_scan_fused"]
+    for inputs, mesh in ((kda_inputs(0, 1, 512, 2, 128, 128), None),
+                         (kda_inputs(0, 1, 256, 4, 128, 128), None),
+                         (fills, four)):
+        del called[:]
+        with mesh_context(mesh):
+            kda.kda_chunked(*inputs)
+        assert called == []
+    monkeypatch.undo()
+    monkeypatch.setattr(
+        jax, "devices", lambda *a: [types.SimpleNamespace(platform="tpu")])
+
+    def loss(*a):
+        o, S = kda.kda_chunked(*a, dtype=jnp.bfloat16)
+        return jnp.sum(o.astype(jnp.float32)) + jnp.sum(S)
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).trace(
+        *fills).lower(lowering_platforms=("tpu",)).as_text()
+    assert "tpu_custom_call" in text
+    for name in ("kda_intra_fwd", "kda_intra_bwd", "kda_chunk_fwd",
+                 "kda_chunk_bwd"):
+        assert name in text, name
 
 
 # ---------------------------------------------------------------------------
