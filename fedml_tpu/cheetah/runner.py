@@ -58,8 +58,9 @@ def config_from_args(args) -> TransformerConfig:
             max_seq_len=int(getattr(args, "seq_len", 1024)),
         )
     # every other field of TransformerConfig is an argument of the same name
-    # (attention kind and its ranks, YaRN, the layer list, the expert layer's
-    # routing and capacity rules, hyper-connections, MTP, norm_eps,
+    # (attention kind and its ranks, the GQA head's size and q/k norms, YaRN,
+    # the layer list, the expert layer's routing and capacity rules,
+    # hyper-connections, MTP, the objective and its block length, norm_eps,
     # rope_theta, splash blocks, remat ...), applied to EVERY size: the one
     # place the args→config mapping lives, and the dataclass defaults stay
     # the single source of truth. A field's default gives the cast.
@@ -223,10 +224,11 @@ class CheetahRunner:
                 if rec is not None:
                     rec.dispatch_latency_s = time.perf_counter() - t_dispatch
                     rec.lazy["examples"] = tokens.size
-                    # the step's routing counters: device scalars, realized
-                    # with the loss when the record is emitted (no sync here)
+                    # the step's routing and noise-draw counters: device
+                    # scalars, realized with the loss when the record is
+                    # emitted (no sync here)
                     rec.lazy.update((k, v) for k, v in metrics.items()
-                                    if k.startswith("moe_"))
+                                    if k.startswith(("moe_", "bd_")))
                 telemetry.end_round(rec, train_loss=losses[-1])
                 if rec is not None and rec.wall_s > 0:
                     tps = tokens.size / rec.wall_s

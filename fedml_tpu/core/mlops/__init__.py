@@ -197,14 +197,18 @@ def log_cheetah_init(mesh: Dict[str, int],
                      loss_head_gathers_per_step: int,
                      layers: list, n_routed_experts: int,
                      experts_held: int, mhc_backward: str,
-                     mixers: str, kda_path: str, kda_chunk: int) -> None:
+                     mixers: str, kda_path: str, kda_chunk: int,
+                     objective: str, bd_block: int, attn_mask: Dict[str, Any],
+                     head_dim: int) -> None:
     """What a Cheetah trainer decided from its mesh and its configuration at
     trace time, once a run (docs/telemetry.md, ``cheetah_init``)."""
     _emit({"kind": "cheetah_init", "mesh": mesh,
            "loss_head_gathers_per_step": loss_head_gathers_per_step,
            "layers": layers, "n_routed_experts": n_routed_experts,
            "experts_held": experts_held, "mhc_backward": mhc_backward,
-           "mixers": mixers, "kda_path": kda_path, "kda_chunk": kda_chunk})
+           "mixers": mixers, "kda_path": kda_path, "kda_chunk": kda_chunk,
+           "objective": objective, "bd_block": bd_block,
+           "attn_mask": attn_mask, "head_dim": head_dim})
 
 
 def log_training_status(status: str) -> None:

@@ -23,8 +23,8 @@ import jax
 
 # parallel/transformer.py, parallel/moe.py: what a configuration adds to the
 # blocks of ``_train_step_raw``; absent from a step whose configuration has
-# no latent attention, hyper-connections, experts, MTP module or
-# linear-attention layers
+# no latent attention, hyper-connections, experts, MTP module,
+# linear-attention layers, q/k norms or block-diffusion objective
 TRAIN_STEP_BLOCKS: Tuple[str, ...] = (
     "mla",            # LatentAttention_N: low-rank projections, norms, scores
     "mhc",            # hyper-connection maps, stream reads and writes
@@ -37,6 +37,8 @@ TRAIN_STEP_BLOCKS: Tuple[str, ...] = (
     "kda_conv",       # inside kda: the short causal convolutions of q, k, v
     "kda_gate",       # inside kda: the decay gate, beta and the output gate
     "kda_chunk",      # inside kda: the chunked form (kda_chunk_fwd / _bwd)
+    "qk_norm",        # inside Attention_N: the per-head RMS norms of q and k
+    "bd_noise",       # block diffusion: the noise draw and the doubled input
 )
 
 # parallel/train_step.py: the jitted ``_train_step_raw``

@@ -4,9 +4,12 @@ New-capability work (SURVEY.md §2.5 "Expert parallelism / MoE" — the
 reference has no MoE at all; the ``expert`` mesh axis existed here as a
 constant only). One layer, a routing rule and a capacity rule:
 
-- **routing** (``cfg.moe_router``). ``softmax``: Switch top-1 or GShard /
-  Mixtral top-2 over softmax scores with the Switch load-balancing auxiliary
-  loss; a single choice keeps its raw probability as gate. ``sigmoid``
+- **routing** (``cfg.moe_router``). ``softmax``: scores ``p = softmax(x
+  W_r)`` over all experts in float32, the ``cfg.moe_top_k`` largest (Switch
+  top-1, GShard / Mixtral top-2, Qwen3-MoE's top-8 of 128), gates ``p_e /
+  sum of the chosen p``, and the Switch load-balancing auxiliary loss over
+  all the router's outputs; a single choice keeps its raw probability as
+  gate. ``sigmoid``
   (DeepSeek-V3's ``noaux_tc``): scores ``s = sigmoid(x W_r)`` in float32,
   the top ``cfg.moe_top_k`` of ``s + b`` selected, where the bias ``b`` is
   state no gradient reaches (the ``router_state`` collection; the trainer
@@ -72,9 +75,6 @@ def route(cfg: TransformerConfig, scores_in: jax.Array, bias=None):
         _, expert = jax.lax.top_k(select, k)
         aux = jnp.zeros((), jnp.float32)
     else:
-        if k not in (1, 2):
-            raise ValueError(
-                f"the softmax router takes moe_top_k 1 or 2, got {k}")
         scores = jax.nn.softmax(scores_in, axis=-1)
         _, expert = jax.lax.top_k(scores, k)
         # Switch aux loss: E * sum_e frac_e * mean_prob_e. The load fraction

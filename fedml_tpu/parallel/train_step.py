@@ -25,6 +25,7 @@ from ..core import mlops
 
 # names for the step's device work outside the flax modules
 from ..core.mlops.scopes import train_step_scope as _scope
+from . import block_diffusion
 from .context import get_mesh_context, mesh_context, sequence_parallelism
 from .kda import KDA_CHUNK, scan_path
 from .mhc_streams import backward_path
@@ -141,11 +142,22 @@ def lm_loss_chunked(
     With batch extent 1 nothing is wrapped and the program is the plain
     scan.
     """
+    num, den = chunked_ce_sums(hidden, w_head, tokens, mask, chunk)
+    return num / jnp.maximum(den, 1.0)
+
+
+def chunked_ce_sums(hidden, w_head, tokens, mask, chunk: int = 256,
+                    shift: bool = True):
+    """What :func:`lm_loss_chunked` divides: (sum of ``mask`` times the cross
+    entropy, sum of ``mask``) over the batch, by the same scan under the same
+    wrap. ``shift`` True: position ``i`` predicts token ``i + 1``; False:
+    its own token, and ``mask`` may be any float weight a position (the
+    block-diffusion loss)."""
     mesh = get_mesh_context()  # None on one device
     batch_axes = batch_mesh_axes(mesh) if mesh is not None else ()
     if not batch_axes:
-        num, den = _chunked_ce_sums(hidden, w_head, tokens, mask, chunk)
-        return num / jnp.maximum(den, 1.0)
+        return _chunked_ce_sums(hidden, w_head, tokens, mask, chunk,
+                                shift=shift)
 
     tensor = TENSOR if int(mesh.shape[TENSOR]) > 1 else None
 
@@ -158,28 +170,33 @@ def lm_loss_chunked(
             # 2.60 GB before this wrap; compiled for v5e:2x2, PR 27)
             w, h = jax.lax.optimization_barrier((w, h))
             w = jax.lax.all_gather(w, FSDP, axis=0, tiled=True)
-        num, den = _chunked_ce_sums(h, w, tok, msk, chunk, vocab_axis=tensor)
+        num, den = _chunked_ce_sums(h, w, tok, msk, chunk, vocab_axis=tensor,
+                                    shift=shift)
         return jax.lax.psum((num, den), batch_axes)
 
     rows = P(batch_axes)
-    num, den = compat_shard_map(
+    return compat_shard_map(
         per_shard, mesh,
         in_specs=(rows, logical_to_mesh_spec((EMBED, VOCAB)), rows, rows),
         out_specs=(P(), P()),
     )(hidden, w_head.astype(hidden.dtype), tokens, mask)
-    return num / jnp.maximum(den, 1.0)
 
 
-def _chunked_ce_sums(hidden, w, tokens, mask, chunk, vocab_axis=None):
-    """(sum of masked next-token CE, sum of the mask) over ``hidden``'s rows,
-    scanned in ``chunk`` positions at a time. ``w`` is the [D, V] head or,
-    inside a shard_map that names ``vocab_axis``, this shard's
-    [D, V / extent] columns of it."""
+def _chunked_ce_sums(hidden, w, tokens, mask, chunk, vocab_axis=None,
+                     shift=True):
+    """(sum of masked CE, sum of the mask) over ``hidden``'s rows, scanned in
+    ``chunk`` positions at a time; next-token CE, or with ``shift`` False
+    each position's own token. ``w`` is the [D, V] head or, inside a
+    shard_map that names ``vocab_axis``, this shard's [D, V / extent]
+    columns of it."""
     B, L, D = hidden.shape
-    h = hidden[:, :-1]
-    targets = tokens[:, 1:]
-    m = mask[:, 1:].astype(jnp.float32)
-    n = L - 1
+    if shift:
+        h = hidden[:, :-1]
+        targets = tokens[:, 1:]
+        m = mask[:, 1:].astype(jnp.float32)
+        n = L - 1
+    else:
+        h, targets, m, n = hidden, tokens, mask.astype(jnp.float32), L
     chunk = min(chunk, n)
     pad = (-n) % chunk
     if pad:
@@ -308,6 +325,22 @@ class CheetahTrainer:
             cfg.n_heads, cfg.kda_head_dim, cfg.kda_head_dim, cfg.max_seq_len,
             KDA_CHUNK, mesh, seq_sharded) if "kda" in cfg.mixers else ""
 
+        # the attention mask of a step at sequences of cfg.max_seq_len, by
+        # name, with the share of the [rows, rows] pairs it lets through
+        self.bd = cfg.objective == "block_diffusion"
+        if self.bd and self.loss_chunk <= 0:
+            raise NotImplementedError(
+                "objective block_diffusion takes its loss through the chunk "
+                "scan over whole sequences: it does not run under sequence "
+                "parallelism yet")
+        L = cfg.max_seq_len
+        kind = cfg.attn_mask(2 * L if self.bd else L)[0]
+        self.attn_mask = {
+            "kind": kind,
+            "pair_share": {"block_diffusion": block_diffusion.pair_share(
+                L, cfg.bd_block), "causal": (L + 1) / (2 * L), "full": 1.0}[kind],
+        }
+
         dummy = jnp.zeros((1, 8), jnp.int32)
         boxed_abstract = jax.eval_shape(
             lambda r: self.model.init(r, dummy), jax.random.PRNGKey(0)
@@ -383,6 +416,8 @@ class CheetahTrainer:
             mhc_backward=self.mhc_backward,
             mixers=",".join(self.cfg.mixers), kda_path=self.kda_path,
             kda_chunk=KDA_CHUNK if self.kda_path else 0,
+            objective=self.cfg.objective, bd_block=int(self.cfg.bd_block),
+            attn_mask=self.attn_mask, head_dim=int(self.cfg.head_dim),
         )
         # step must be committed to the mesh (replicated) — a default-device
         # scalar breaks jit after checkpoint restore (mixed device sets)
@@ -428,13 +463,16 @@ class CheetahTrainer:
                           model_state=model_state)
 
     # -- train step ---------------------------------------------------------
-    def _loss_fn(self, params, model_state, tokens, mask):
-        """(loss, what the expert layers sowed into ``moe_stats``)."""
+    def _loss_fn(self, params, model_state, tokens, mask, noise_key=None):
+        """(loss, what the expert layers sowed into ``moe_stats``; under the
+        block-diffusion objective also ``bd``: the draw's counters)."""
         cfg = self.cfg
         moe = cfg.moe_experts > 1
         mtp = cfg.mtp_layers > 0
         mutable = ["losses", "moe_stats"] if moe else False
         variables = {"params": params, **model_state}
+        if self.bd:
+            return self._bd_loss(variables, tokens, mask, noise_key, mutable)
         kwargs = dict(mask=None, mutable=mutable)
         if mtp:
             kwargs["return_mtp"] = True
@@ -471,26 +509,65 @@ class CheetahTrainer:
                                    params["w_lm_head"].astype(mtp_hidden.dtype)
                                    ).astype(jnp.float32), nxt, nxt_mask)
                 loss = loss + cfg.mtp_weight * mtp_loss
-        if moe and cfg.moe_router == "softmax":
+        return self._with_aux(loss, var_col), var_col.get("moe_stats", {})
+
+    def _with_aux(self, loss, var_col):
+        """``loss`` plus the softmax router's weighted auxiliary loss."""
+        cfg = self.cfg
+        if cfg.moe_experts > 1 and cfg.moe_router == "softmax":
             with _scope("loss"):
                 aux = sum(
                     jnp.sum(jnp.asarray(v))
                     for v in jax.tree.leaves(var_col.get("losses", {}))
                 )
                 loss = loss + cfg.moe_aux_weight * aux
-        return loss, var_col.get("moe_stats", {})
+        return loss
 
-    def _loss_and_grads(self, params, model_state, tokens, mask):
+    def _bd_loss(self, variables, tokens, mask, noise_key, mutable):
+        """The block-diffusion objective on one batch [B, L]: the noise from
+        ``noise_key``, the model once over the ``2L`` rows ``[x_t ; x_0]``,
+        the weighted unshifted loss on the noised half."""
+        cfg = self.cfg
+        with _scope("bd_noise"):
+            x_t, masked, weight = block_diffusion.noise(
+                noise_key, tokens, cfg.bd_block, cfg.bd_mask_token)
+            rows, positions = block_diffusion.model_rows(x_t, tokens)
+            coefficient = masked * weight * mask.astype(jnp.float32)
+        out = self.model.apply(variables, rows, positions=positions,
+                               return_hidden=True, mutable=mutable)
+        hidden, var_col = out if mutable else (out, {})
+        with _scope("loss"):
+            loss = block_diffusion.loss(
+                hidden, variables["params"]["w_lm_head"], tokens, coefficient,
+                self.loss_chunk)
+        stats = dict(var_col.get("moe_stats", {}))
+        stats["bd"] = {"masked_tokens": masked.sum().astype(jnp.int32),
+                       "weight_sum": (masked * weight).sum()}
+        return self._with_aux(loss, var_col), stats
+
+    def _loss_and_grads(self, params, model_state, tokens, mask, step=None):
         """Loss, gradients and summed ``moe_stats`` of one step's batch: the
-        mean over the microbatches where ``accum_steps > 1``."""
+        mean over the microbatches where ``accum_steps > 1``. ``step`` numbers
+        the step: what the block-diffusion objective draws its noise from
+        (no other objective reads it)."""
         grad_fn = jax.value_and_grad(self._loss_fn, has_aux=True)
+
+        def noise_key(micro=0):
+            # None for every other objective: no op in their programs
+            return block_diffusion.step_key(step, micro) if self.bd else None
+
         if self.accum_steps == 1:
-            (loss, stats), grads = grad_fn(params, model_state, tokens, mask)
+            (loss, stats), grads = grad_fn(params, model_state, tokens, mask,
+                                           noise_key())
             return loss, grads, stats
+        xs = (tokens, mask)
+        if self.bd:
+            xs += (jnp.arange(self.accum_steps),)
 
         def micro(carry, xs):
-            tok, msk = xs
-            (loss, stats), grads = grad_fn(params, model_state, tok, msk)
+            tok, msk, *index = xs
+            (loss, stats), grads = grad_fn(params, model_state, tok, msk,
+                                           noise_key(*index))
             acc_loss, acc_grads, acc_stats = carry
             return (
                 acc_loss + loss,
@@ -503,9 +580,9 @@ class CheetahTrainer:
             zero_stats = jax.tree.map(
                 jnp.zeros_like,
                 jax.eval_shape(lambda: self._loss_fn(
-                    params, model_state, tokens[0], mask[0])[1]))
+                    params, model_state, tokens[0], mask[0], noise_key())[1]))
             (loss_sum, grads, stats), _ = jax.lax.scan(
-                micro, (jnp.zeros(()), zero, zero_stats), (tokens, mask)
+                micro, (jnp.zeros(()), zero, zero_stats), xs
             )
             loss = loss_sum / self.accum_steps
             grads = jax.tree.map(lambda g: g / self.accum_steps, grads)
@@ -515,7 +592,7 @@ class CheetahTrainer:
         """tokens/mask: [accum, micro_batch, L] when accum_steps > 1,
         else [B, L]."""
         loss, grads, stats = self._loss_and_grads(
-            state.params, state.model_state, tokens, mask)
+            state.params, state.model_state, tokens, mask, state.step)
         with _scope("optimizer"):
             updates, opt_state = self.opt.update(
                 grads, state.opt_state, state.params
@@ -524,6 +601,9 @@ class CheetahTrainer:
         with _scope("metrics"):
             metrics = {"loss": loss, "grad_norm": optax.global_norm(grads)}
             model_state = state.model_state
+            stats = dict(stats)
+            metrics.update({f"bd_{k}": v
+                            for k, v in stats.pop("bd", {}).items()})
             if stats:
                 metrics.update(_routing_metrics(self.cfg, stats))
                 model_state = _move_selection_bias(

@@ -22,8 +22,11 @@ layers (``parallel/moe.py``), hyper-connected residual streams around every
 sublayer (``HyperConnection``), a multi-token-prediction module, and a mixer
 chosen per layer (``cfg.mixers``): full attention every ``layer_group_size``
 layers and a gated delta-rule linear-attention mixer (``KimiDeltaAttention``,
-``parallel/kda.py``) in between. A configuration that names none of them
-builds the block it always built.
+``parallel/kda.py``) in between; a GQA head size apart from ``d_model /
+n_heads`` with per-head RMS norms of q and k; and the block-diffusion
+objective's doubled sequence under its structured attention mask
+(``cfg.objective``, ``parallel/block_diffusion.py``). A configuration that
+names none of them builds the block it always built.
 """
 
 from __future__ import annotations
@@ -76,8 +79,10 @@ class TransformerConfig:
     # Mixture-of-Experts FFN (parallel/moe.py): 0/1 = dense; >1 = that many
     # experts, stacked expert weights shardable over the `expert` mesh axis
     moe_experts: int = 0
-    # 1 = Switch top-1 routing; 2 = GShard/Mixtral top-2 (renormalised gates,
-    # second choice fills capacity left by first choices)
+    # choices a token (any k under either router): 1 = Switch top-1, the raw
+    # score its gate; more renormalise the gates over the chosen (GShard /
+    # Mixtral top-2, Qwen3-MoE top-8; under a capacity factor later choices
+    # fill what earlier ones left)
     moe_top_k: int = 1
     # > 0: slots per expert as a factor of the balanced load, over-capacity
     # tokens dropped. 0: no capacity and no drop; the experts' grouped
@@ -140,7 +145,9 @@ class TransformerConfig:
     # expert layers. 0 = every layer an expert layer (the Switch stacks)
     first_k_dense: int = 0
     # -- expert layer (parallel/moe.py): a routing rule and a capacity rule --
-    # "softmax": Switch / GShard scores and auxiliary loss. "sigmoid": scores
+    # "softmax": scores softmax(x W_r) over all experts, the moe_top_k
+    # largest, the Switch load-balancing auxiliary loss (Switch, GShard,
+    # Mixtral, Qwen3-MoE). "sigmoid": scores
     # sigmoid(x W_r), selection by score + bias (``noaux_tc``; the bias is
     # state the optimizer does not own, moved after each step from the
     # expert loads by moe_bias_rate), weights renormalised over the chosen
@@ -175,6 +182,23 @@ class TransformerConfig:
     # through the shared embedding and head; its loss weighs mtp_weight
     mtp_layers: int = 0
     mtp_weight: float = 0.3
+    # -- the GQA head ("gqa" only) -------------------------------------------
+    # a head size of its own (0: d_model // n_heads), and RMS norms of every
+    # q and k head before the rotary, one learned weight of head_dim each
+    # (Qwen3's q_norm / k_norm)
+    attn_head_dim: int = 0
+    qk_norm: bool = False
+    # -- objective -----------------------------------------------------------
+    # "next_token": causal cross entropy. "block_diffusion"
+    # (parallel/block_diffusion.py): the model reads the 2L rows
+    # [noised ; clean] of a sequence of L under the block-diffusion mask at
+    # blocks of bd_block and returns the noised half; the step draws the
+    # noise (one t a block, uniform on [block_diffusion.T_MIN, 1];
+    # bd_mask_token where a token is masked) and takes the 1/t-weighted,
+    # unshifted loss
+    objective: str = "next_token"
+    bd_block: int = 0
+    bd_mask_token: int = 0
 
     def __post_init__(self):
         if self.attn_kind not in ("gqa", "mla", "kda"):
@@ -222,10 +246,43 @@ class TransformerConfig:
             raise ValueError(
                 f"experts [{self.moe_expert_offset}, "
                 f"{self.moe_expert_offset + held}) are not among {E}")
+        if self.attn_head_dim < 0 or self.attn_head_dim % 2:
+            raise ValueError(
+                f"attn_head_dim must be even and >= 0, got {self.attn_head_dim}")
+        if self.objective not in ("next_token", "block_diffusion"):
+            raise ValueError(
+                "objective must be next_token|block_diffusion, got "
+                f"{self.objective!r}")
+        if self.objective == "block_diffusion":
+            if self.bd_block < 1 or not 0 <= self.bd_mask_token < self.vocab_size:
+                raise ValueError(
+                    "objective block_diffusion needs bd_block >= 1 and "
+                    "bd_mask_token inside the vocabulary, got "
+                    f"({self.bd_block}, {self.bd_mask_token})")
+            for name, on in (("an MTP module (mtp_layers)", self.mtp_layers),
+                             ("hyper-connections (hc_mult)", self.hc_mult > 1),
+                             ("a kda mixer", "kda" in self.mixers),
+                             ("learned positions (pos_emb)",
+                              self.pos_emb != "rope")):
+                if on:
+                    raise NotImplementedError(
+                        f"objective block_diffusion does not run with {name}")
 
     @property
     def head_dim(self) -> int:
-        return self.d_model // self.n_heads
+        return self.attn_head_dim or self.d_model // self.n_heads
+
+    def attn_mask(self, rows: int) -> Tuple:
+        """The attention mask by name for a sequence of ``rows`` rows:
+        ``("causal",)``, ``("full",)`` or ``("block_diffusion", L, B)`` over
+        ``rows = 2L``."""
+        if self.objective == "block_diffusion":
+            if rows % (2 * self.bd_block):
+                raise ValueError(
+                    f"the block-diffusion mask is over 2L rows in blocks of "
+                    f"{self.bd_block}, got {rows} rows")
+            return ("block_diffusion", rows // 2, self.bd_block)
+        return ("causal",) if self.causal else ("full",)
 
     @property
     def rope_dim(self) -> int:
@@ -276,14 +333,22 @@ def train_flops_per_token(cfg: TransformerConfig, seq_len: int) -> float:
     forward, one multiply-add = 2 FLOPs: each layer's mixer (fused q, k, v
     and o with causal scores and values, a query seeing ``(seq_len + 1) / 2``
     keys on average; MLA's projections, with or without a q-LoRA; or a KDA
-    layer, :func:`kda_forward_flops_per_token`), each layer's SwiGLU (dense,
+    layer, :func:`kda_forward_flops_per_token`; the GQA head is
+    ``cfg.head_dim`` wide), each layer's SwiGLU (dense,
     or the router, the shared experts and the expected share of the routed
     experts held here), the hyper-connection maps and the output head (twice
     with an MTP module, which also adds a block and its projection). The
     embedding is a row gather and costs none; backward is twice the forward;
-    recomputation under remat is not counted. ``benchmark/flops`` counts the
-    same from the published keys."""
+    recomputation under remat is not counted. Under the block-diffusion
+    objective a token is a *data* token: its two rows pass every projection
+    and expert layer, its attention is over the mask's ``(L^2 + L B) / L``
+    pairs a token, and the head reads the noised row alone.
+    ``benchmark/flops`` counts the same from the published keys."""
     D, H = cfg.d_model, cfg.n_heads
+    bd = cfg.objective == "block_diffusion"
+    # rows a token passes through the layers, and keys a query sees on average
+    rows = 2 if bd else 1
+    pairs = (seq_len + cfg.bd_block) / rows if bd else (seq_len + 1) / 2
 
     def mixer(kind):
         if kind == "kda":
@@ -299,9 +364,9 @@ def train_flops_per_token(cfg: TransformerConfig, seq_len: int) -> float:
                         + cfg.kv_lora_rank * H * (cfg.qk_nope_head_dim
                                                   + cfg.v_head_dim)
                         + H * cfg.v_head_dim * D)
-            return proj + 2 * H * (dqk + cfg.v_head_dim) * (seq_len + 1) / 2
+            return proj + 2 * H * (dqk + cfg.v_head_dim) * pairs
         proj = 2 * D * cfg.head_dim * (2 * H + 2 * cfg.n_kv_heads)
-        return proj + 2 * 2 * H * cfg.head_dim * (seq_len + 1) / 2
+        return proj + 2 * 2 * H * cfg.head_dim * pairs
 
     n = cfg.hc_mult
     hyper = 2 * 2 * (n * D) * (2 * n + n * n) if n > 1 else 0
@@ -311,8 +376,9 @@ def train_flops_per_token(cfg: TransformerConfig, seq_len: int) -> float:
               + (cfg.moe_shared_experts + held) * 2 * 3 * D * cfg.expert_d_ff)
     head = 2 * D * cfg.vocab_size
     kinds = cfg.layer_kinds
-    forward = (sum(mixer(m) for m in cfg.mixers) + len(kinds) * hyper + head
+    forward = (sum(mixer(m) for m in cfg.mixers) + len(kinds) * hyper
                + sum(expert if kind == "moe" else dense for kind in kinds))
+    forward = rows * forward + head
     if cfg.mtp_layers:
         forward += (mixer(cfg.attn_kind) + hyper + 2 * 2 * D * D + head
                     + (expert if cfg.moe_experts > 1 else dense))
@@ -496,7 +562,8 @@ def _splash_blocks(L: int, block_q: int, block_kv: int, head_dim: int):
 def splash_attention_tpu(q: jax.Array, k: jax.Array, v: jax.Array,
                          block_q: int = 0, block_kv: int = 0,
                          causal: bool = True,
-                         scale: Optional[float] = None) -> jax.Array:
+                         scale: Optional[float] = None,
+                         mask_kind: Optional[Tuple] = None) -> jax.Array:
     """Splash attention (the current-generation Pallas TPU kernel).
 
     q: [B, L, H, D]; k: [B, L, Hkv, D]; v: [B, L, Hkv, Dv] → out
@@ -505,6 +572,11 @@ def splash_attention_tpu(q: jax.Array, k: jax.Array, v: jax.Array,
     NATIVELY (``make_splash_mqa`` vmapped over kv groups) — K/V are never
     repeated to H heads, cutting both the repeat's HBM traffic and the
     kernel's K/V block loads by H/Hkv.
+
+    ``mask_kind`` names the mask (``TransformerConfig.attn_mask``; None:
+    ``causal`` decides). Every kind is a mask object the kernel computes tile
+    by tile: its bookkeeping skips the empty tiles, and no ``[L, L]`` array
+    exists anywhere. The same mask serves the query heads of a group.
 
     The kernel is built per trace — make_splash_mha captures trace-local
     mask arrays, so caching it across jit traces leaks tracers.
@@ -520,8 +592,21 @@ def splash_attention_tpu(q: jax.Array, k: jax.Array, v: jax.Array,
         scale = float(1.0 / D ** 0.5)
     blocks = _splash_blocks(L, block_q, block_kv, D)
 
+    if mask_kind is None:
+        mask_kind = ("causal",) if causal else ("full",)
+
     def head_mask(n):
-        m = sm.CausalMask((L, L)) if causal else sm.FullMask((L, L))
+        if mask_kind[0] == "block_diffusion":
+            from .block_diffusion import splash_mask
+
+            if 2 * mask_kind[1] != L:
+                raise ValueError(f"mask {mask_kind} over {L} rows")
+            m = splash_mask(*mask_kind[1:])
+        elif mask_kind[0] in ("causal", "full"):
+            m = (sm.CausalMask((L, L)) if mask_kind[0] == "causal"
+                 else sm.FullMask((L, L)))
+        else:
+            raise ValueError(f"no attention mask named {mask_kind!r}")
         return sm.MultiHeadMask([m] * n)
 
     qt, kt, vt = (x.swapaxes(1, 2) for x in (q, k, v))  # [B, H(kv), L, D]
@@ -653,13 +738,15 @@ def expand_gqa(k, v, n_heads):
 def attention_scores(
     q: jax.Array, k: jax.Array, v: jax.Array, mask: Optional[jax.Array],
     causal: bool = True, scale: Optional[float] = None,
+    pairs: Optional[np.ndarray] = None,
 ) -> jax.Array:
     """Plain attention (single-device / tensor-parallel path).
 
     q: [B, L, H, D], k: [B, L, Hkv, D], v: [B, L, Hkv, Dv] → out
     [B, L, H, Dv]. GQA via repeat. ``scale`` multiplies q.k (None:
-    ``D ** -0.5``). The sequence-parallel path replaces this with ring
-    attention (``ring_attention.py``).
+    ``D ** -0.5``). ``pairs``: a dense boolean [L, L], which query may see
+    which key (a structured mask's plain form). The sequence-parallel path
+    replaces this with ring attention (``ring_attention.py``).
     """
     B, L, H, D = q.shape
     k, v = expand_gqa(k, v, H)
@@ -669,6 +756,8 @@ def attention_scores(
     if causal:
         tri = jnp.tril(jnp.ones((L, L), jnp.bool_))
         logits = jnp.where(tri[None, None], logits, -1e30)
+    if pairs is not None:
+        logits = jnp.where(jnp.asarray(pairs)[None, None], logits, -1e30)
     if mask is not None:
         logits = jnp.where(mask[:, None, None, :], logits, -1e30)
     probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
@@ -680,11 +769,19 @@ def attend(cfg: TransformerConfig, q, k, v, mask=None,
     """Attention over projected, rotated heads by the path the context and
     the back-end choose: ring attention under sequence parallelism, the
     splash kernel on a TPU, XLA elsewhere. q: [B, L, H, D]; k: [B, L, Hkv,
-    D]; v: [B, L, Hkv, Dv] -> [B, L, H, Dv]."""
+    D]; v: [B, L, Hkv, Dv] -> [B, L, H, Dv]. The mask is the
+    configuration's by name (``cfg.attn_mask``), built once per trace: a
+    mask object for the kernel, the dense boolean form for XLA."""
     B, L, H, _ = q.shape
     from .context import get_seq_context
 
     seq_ctx = get_seq_context()
+    mask_kind = cfg.attn_mask(L)
+    structured = mask_kind[0] not in ("causal", "full")
+    if seq_ctx is not None and structured:
+        raise NotImplementedError(
+            f"ring attention is causal or full: the {mask_kind[0]} mask "
+            "does not run under sequence parallelism yet")
     if seq_ctx is not None and (scale is not None
                                 or v.shape[-1] != q.shape[-1]):
         raise NotImplementedError(
@@ -736,10 +833,15 @@ def attend(cfg: TransformerConfig, q, k, v, mask=None,
             partial(
                 splash_attention_tpu,
                 block_q=cfg.attn_block_q, block_kv=cfg.attn_block_kv,
-                causal=cfg.causal, scale=scale,
+                scale=scale, mask_kind=mask_kind,
             ),
             q, k, v,
         )
+    elif structured:
+        from .block_diffusion import dense_mask
+
+        out = attention_scores(q, k, v, mask, causal=False, scale=scale,
+                               pairs=dense_mask(*mask_kind[1:]))
     else:
         out = attention_scores(q, k, v, mask, causal=cfg.causal, scale=scale)
     return out
@@ -772,6 +874,12 @@ class Attention(nn.Module):
         q = q.reshape(B, L, H, hd)
         k = k.reshape(B, L, Hkv, hd)
         v = v.reshape(B, L, Hkv, hd)
+        if cfg.qk_norm:
+            # every head normalised over its hd entries; one weight for all
+            # the q heads, one for the k heads
+            with _scope("qk_norm"):
+                q = RMSNorm(cfg.norm_eps, name="q_norm")(q)
+                k = RMSNorm(cfg.norm_eps, name="k_norm")(k)
         with _scope("rope"):
             q = apply_rotary(q, cos, sin)
             k = apply_rotary(k, cos, sin)
@@ -1150,7 +1258,13 @@ class Transformer(nn.Module):
 
     ``return_hidden`` returns the final-norm hidden states instead (the head
     is then the caller's); ``return_mtp`` returns a pair, the second member
-    the multi-token-prediction module's hidden states (``cfg.mtp_layers``)."""
+    the multi-token-prediction module's hidden states (``cfg.mtp_layers``).
+
+    Under ``cfg.objective`` ``block_diffusion`` ``tokens`` are the ``2L``
+    rows ``[noised ; clean]`` of sequences of ``L``
+    (``block_diffusion.model_rows``), ``positions`` default to ``[0..L-1 ;
+    0..L-1]``, the blocks attend under the block-diffusion mask, and what
+    comes back (logits or hidden states) is the noised half's, [B, L, ..]."""
 
     cfg: TransformerConfig
 
@@ -1179,6 +1293,11 @@ class Transformer(nn.Module):
                 )
 
         x = lookup(tokens)
+        bd = cfg.objective == "block_diffusion"
+        if positions is None and bd:
+            from .block_diffusion import repeated_positions
+
+            positions = repeated_positions(tokens.shape[1] // 2)
         if positions is None:
             positions = jnp.arange(tokens.shape[1])[None, :]
         if cfg.pos_emb == "learned":
@@ -1215,6 +1334,10 @@ class Transformer(nn.Module):
             )
         if cfg.hc_mult > 1:
             x = x.sum(axis=1)  # the streams are summed before the final norm
+        if bd:
+            # the clean copy has served as keys and values; the head and the
+            # loss read the noised half
+            x = x[:, :tokens.shape[1] // 2]
 
         mtp_hidden = None
         if cfg.mtp_layers:

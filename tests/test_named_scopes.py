@@ -80,7 +80,8 @@ def test_train_step_names_what_a_configuration_adds_to_the_blocks():
         moe_capacity_factor=0.0, moe_d_ff=32, moe_shared_experts=1,
         first_k_dense=1, mtp_layers=1))
     kda = {"kda", "kda_conv", "kda_gate", "kda_chunk"}  # no such layer here
-    assert set(scopes.TRAIN_STEP) - names == {"grad_accum"} | kda
+    bd = {"qk_norm", "bd_noise"}   # nor q/k norms or the block-diffusion draw
+    assert set(scopes.TRAIN_STEP) - names == {"grad_accum"} | kda | bd
     assert {"LatentAttention_0", "HyperConnection_0", "MoEFeedForward_0",
             "FeedForward_0", "mtp"} <= names
 
@@ -94,6 +95,17 @@ def test_train_step_names_the_linear_mixer_beside_latent_attention():
         kda_head_dim=16))
     assert {"kda", "kda_conv", "kda_gate", "kda_chunk", "mla",
             "KimiDeltaAttention_0", "LatentAttention_0"} <= names
+
+
+def test_train_step_names_the_block_diffusion_draw_and_the_qk_norms():
+    """The block-diffusion objective over a GQA stack with per-head q/k norms:
+    the draw with the doubled input, and the norms inside their module."""
+    names = scope_names(lowered_step(
+        1, attn_head_dim=16, qk_norm=True, objective="block_diffusion",
+        bd_block=4, bd_mask_token=255, moe_experts=4, moe_top_k=2,
+        moe_capacity_factor=0.0, moe_d_ff=32))
+    assert {"bd_noise", "qk_norm", "moe_route", "moe_experts", "loss",
+            "Attention_0", "q_norm", "k_norm"} <= names
 
 
 def test_train_step_learned_positions_are_embed_and_rope():
