@@ -12,14 +12,17 @@ noised row. The head reads the noised half; the loss of a sequence is
 position itself (no shift), averaged over the batch.
 
 Three pieces, each a pure function: :func:`noise` (of a key),
-:func:`visible` (of ``(i, j, L, B)``; the dense boolean form and the splash
-kernel's mask object are both built from it), :func:`loss` (through the
-trainer's chunk scan). ``train_step._loss_fn`` puts them together.
+:func:`visible` (of ``(i, j, L, B)``: the definition, from which the dense
+boolean form is built; the splash kernel is handed the same mask in a form
+that costs it a compare's worth per pair, :func:`kernel_visible`),
+:func:`loss` (through the trainer's chunk scan). ``train_step._loss_fn`` puts
+them together.
 """
 
 from __future__ import annotations
 
 import functools
+from typing import Dict
 
 import jax
 import jax.numpy as jnp
@@ -107,6 +110,43 @@ def pair_share(L: int, B: int) -> float:
     return (L * L + L * B) / (4.0 * L * L)
 
 
+# marks a clean row's integer in ``kernel_rows``: above every row index, so
+# the own-block test of ``kernel_visible`` never holds for a clean row
+_CLEAN = 1 << 30
+
+
+def kernel_rows(L: int, B: int) -> np.ndarray:
+    """One integer a row of the ``2L``, int32 [2L]: all of the row that
+    :func:`kernel_visible` needs. With ``lo`` the first row of the row's
+    block, a noised row's is ``lo`` (it sees the ``lo`` clean keys before its
+    block, and the ``B`` keys from ``lo``); a clean row's is ``_CLEAN`` plus
+    the ``lo + B - L`` clean keys it sees."""
+    rows = np.arange(2 * L, dtype=np.int32)
+    lo = rows - rows % L % B
+    return np.where(lo < L, lo, (lo + B - L) | _CLEAN).astype(np.int32)
+
+
+def _unsigned(x):
+    # bit for bit: a negative difference becomes a large one, so one compare
+    # tests both ends of a range (numpy wraps; in the kernel it is no
+    # operation)
+    return x.astype(np.uint32)
+
+
+def kernel_visible(r, j, L: int, B: int):
+    """:func:`visible` ``(i, j)`` from ``r = kernel_rows(L, B)[i]``, for ``B``
+    that divides ``L``: the clean keys the row sees are the first
+    ``r mod _CLEAN`` of them, and a noised row also sees the ``B`` keys from
+    ``r``. Two differences, an ``and``, two unsigned compares and an ``or``
+    on tile-shaped operands where :func:`visible` traces to sixty primitives
+    with four divisions: the splash kernels evaluate a mask's function pair
+    by pair in every tile they keep, whole tiles included. Serves numpy's
+    arrays (the tile bookkeeping) and traced ones (the kernel)."""
+    clean_keys = _unsigned(j - L) < _unsigned(r & (_CLEAN - 1))
+    own_block = _unsigned(j - r) < B
+    return clean_keys | own_block
+
+
 @functools.cache
 def _splash_mask_class():
     """The mask's class, built on first use (the splash package is a TPU
@@ -117,10 +157,18 @@ def _splash_mask_class():
 
     class BlockDiffusionMask(sm._ComputableMask):
         def __init__(self, L: int, B: int):
+            if L % B or 2 * L >= _CLEAN:
+                raise ValueError(
+                    f"the block-diffusion mask's kernel form is for blocks "
+                    f"that divide the sequence, under {_CLEAN // 2} tokens: "
+                    f"got {L} tokens in blocks of {B}")
             self.seq_len, self.block = L, B
             super().__init__(
                 shape=(2 * L, 2 * L),
-                mask_function=lambda q_ids, kv_ids: visible(q_ids, kv_ids, L, B))
+                mask_function=lambda r, kv_ids: kernel_visible(r, kv_ids, L, B))
+            # what the kernel is handed a row: not the row's index (which the
+            # base class has just put there) but the row's integer
+            self.q_sequence = kernel_rows(L, B)
 
         def __eq__(self, other):
             return (isinstance(other, type(self))
@@ -134,11 +182,28 @@ def _splash_mask_class():
 
 
 def splash_mask(L: int, B: int):
-    """The mask as an object the splash kernel computes tile by tile from
-    :func:`visible` (no ``[2L, 2L]`` array anywhere): the kernel's own
-    bookkeeping finds the empty tiles and skips them, and evaluates the
-    function inside the tiles the mask crosses."""
+    """The mask as an object the splash kernel computes tile by tile (no
+    ``[2L, 2L]`` array anywhere): the kernel's own bookkeeping finds the empty
+    tiles and skips them, and evaluates :func:`kernel_visible` on
+    :func:`kernel_rows` inside every tile it keeps."""
     return _splash_mask_class()(L, B)
+
+
+def tile_counts(L: int, B: int, block_q: int, block_kv: int) -> Dict[str, int]:
+    """Of the ``block_q x block_kv`` tiles of the ``[2L, 2L]`` pairs, how many
+    the kernel keeps (some pair visible: it evaluates the mask there) and how
+    many of those the mask crosses (some pair not), counted from the object
+    the kernel is handed, as its bookkeeping counts them."""
+    mask, rows = splash_mask(L, B), 2 * L
+    kept = crossed = 0
+    for r in range(0, rows, block_q):
+        for c in range(0, rows, block_kv):
+            tile = mask[r:min(r + block_q, rows), c:min(c + block_kv, rows)]
+            if tile.any():
+                kept += 1
+                crossed += not tile.all()
+    return {"kept": kept, "crossed": crossed,
+            "of": -(-rows // block_q) * -(-rows // block_kv)}
 
 
 def loss(hidden, w_head, x_0, coefficient, chunk: int):

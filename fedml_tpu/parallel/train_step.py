@@ -40,7 +40,8 @@ from .sharding import (
     replicated,
     unbox,
 )
-from .transformer import EMBED, VOCAB, Transformer, TransformerConfig
+from .transformer import (EMBED, VOCAB, Transformer, TransformerConfig,
+                          splash_forward_tiles)
 
 logger = logging.getLogger(__name__)
 
@@ -340,6 +341,11 @@ class CheetahTrainer:
             "pair_share": {"block_diffusion": block_diffusion.pair_share(
                 L, cfg.bd_block), "causal": (L + 1) / (2 * L), "full": 1.0}[kind],
         }
+        if kind == "block_diffusion":
+            # the tiles of the forward kernel that evaluate the mask
+            self.attn_mask["tiles"] = block_diffusion.tile_counts(
+                L, cfg.bd_block, *splash_forward_tiles(
+                    2 * L, cfg.attn_block_q, cfg.attn_block_kv, cfg.head_dim))
 
         dummy = jnp.zeros((1, 8), jnp.int32)
         boxed_abstract = jax.eval_shape(

@@ -559,6 +559,20 @@ def _splash_blocks(L: int, block_q: int, block_kv: int, head_dim: int):
     )
 
 
+def splash_forward_tiles(L: int, block_q: int, block_kv: int,
+                         head_dim: int) -> Tuple[int, int]:
+    """The forward kernel's ``(block_q, block_kv)`` over ``L`` rows: what
+    :func:`_splash_blocks` makes of the configuration's sizes, the kernel's
+    own default where the configuration names none."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as sk,
+    )
+
+    blocks = (_splash_blocks(L, block_q, block_kv, head_dim)
+              or sk.BlockSizes.get_default())
+    return blocks.block_q, blocks.block_kv
+
+
 def splash_attention_tpu(q: jax.Array, k: jax.Array, v: jax.Array,
                          block_q: int = 0, block_kv: int = 0,
                          causal: bool = True,

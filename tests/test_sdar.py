@@ -264,19 +264,85 @@ def test_the_definition_by_hand():
     assert bd.pair_share(4096, 4) == pytest.approx(0.25, abs=3e-4)
 
 
-@pytest.mark.parametrize("block", [4, 32])
-def test_the_splash_mask_object_is_the_dense_form(block):
-    """Entry by entry at L 256, and the tiles the kernel's bookkeeping keeps:
-    of the 4 x 4 tiles of 128 the noised half's rows keep their diagonal tile
-    (crossed: 1) and the clean tiles up to theirs (whole: 2, the last
-    crossed), the clean half's rows the clean tiles up to theirs; the clean
-    half never reads a noised tile. 8 of 16 tiles."""
+@pytest.mark.parametrize("L_, B_", [(256, 4), (256, 32), (96, 3), (120, 8),
+                                    (60, 60), (64, 1), (384, 3)])
+def test_the_kernel_form_is_the_definition(L_, B_):
+    """What the splash kernel is handed (one integer a row and a function of
+    it and the column) against ``visible`` entry by entry: whole, and on two
+    slices that cut blocks and halves; through numpy as the tile bookkeeping
+    calls it and traced on int32 operands as the kernel does."""
+    mask = bd.splash_mask(L_, B_)
+    rows = np.arange(2 * L_, dtype=np.int32)
+    want = np.asarray(bd.visible(rows[:, None], rows[None, :], L_, B_))
+    assert mask.q_sequence.dtype == np.int32
+    assert (mask.q_sequence == bd.kernel_rows(L_, B_)).all()
+    assert (np.asarray(mask[:, :]) == want).all()
+    for a, b in ((slice(L_ // 3, L_ + L_ // 2 + 1), slice(L_ // 5, 2 * L_ - 3)),
+                 (slice(L_ - 1, 2 * L_), slice(1, L_ + 2))):
+        assert (np.asarray(mask[a, b]) == want[a, b]).all()
+    r = jnp.broadcast_to(jnp.asarray(mask.q_sequence)[:, None], want.shape)
+    j = jnp.broadcast_to(jnp.asarray(rows)[None, :], want.shape)
+    traced = jax.jit(mask.mask_function)(r, j)
+    assert traced.dtype == jnp.bool_ and (np.asarray(traced) == want).all()
+
+
+def test_the_kernel_form_divides_nothing():
+    """The function the kernel evaluates pair by pair in every tile it keeps:
+    a handful of cheap integer primitives on tile-shaped operands (the
+    definition traces to sixty, four ``div`` and four ``rem`` among them),
+    and a function of ``(L, B)`` alone."""
+    tile = jax.ShapeDtypeStruct((128, 128), jnp.int32)
+
+    def primitives(f):
+        found = []
+
+        def walk(jaxpr):
+            for eqn in jaxpr.eqns:
+                inner = [v for v in eqn.params.values()
+                         if hasattr(v, "jaxpr") or hasattr(v, "eqns")]
+                for sub in inner:
+                    walk(getattr(sub, "jaxpr", sub))
+                if not inner:
+                    found.append(str(eqn.primitive))
+
+        walk(jax.make_jaxpr(f)(tile, tile).jaxpr)
+        return found
+
+    handed = primitives(bd.splash_mask(4096, 4).mask_function)
+    assert not {"div", "rem", "floor", "pow"} & set(handed), handed
+    assert len(handed) <= 16, handed
+    defined = primitives(lambda i, j: bd.visible(i, j, 4096, 4))
+    assert defined.count("div") == 4 and len(defined) > 3 * len(handed)
+
+
+# the tiles of 128 the kernel's bookkeeping keeps (1: crossed, 2: whole). At
+# L 256 the noised half's rows keep their diagonal tile (crossed) and the
+# clean tiles up to theirs (the last crossed), the clean half's rows the clean
+# tiles up to theirs; the clean half never reads a noised tile: 8 of 16. At
+# L 384 in blocks of 3 the tile borders 128 and 256 cut blocks 42 and 85, so
+# each half's diagonal spills into the tiles beside it: 21 of 36.
+TILES_256 = [[1, 0, 1, 0],
+             [0, 1, 2, 1],
+             [0, 0, 1, 0],
+             [0, 0, 2, 1]]
+TILES_384_3 = [[1, 1, 0, 1, 0, 0],
+               [1, 1, 1, 1, 1, 0],
+               [0, 1, 1, 2, 1, 1],
+               [0, 0, 0, 1, 1, 0],
+               [0, 0, 0, 2, 1, 1],
+               [0, 0, 0, 2, 2, 1]]
+
+
+@pytest.mark.parametrize("L_, block, want", [
+    (256, 4, TILES_256), (256, 32, TILES_256), (384, 3, TILES_384_3)],
+    ids=["4", "32", "3"])
+def test_the_splash_mask_object_is_the_dense_form(L_, block, want):
+    """Entry by entry, and the tiles the kernel's bookkeeping keeps."""
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_mask as sm,
         splash_attention_mask_info as mi,
     )
 
-    L_ = 256
     mask, dense = bd.splash_mask(L_, block), bd.dense_mask(L_, block)
     assert mask.shape == (2 * L_, 2 * L_)
     assert (np.asarray(mask[:, :]) == dense).all()
@@ -284,19 +350,21 @@ def test_the_splash_mask_object_is_the_dense_form(block):
     assert mask == bd.splash_mask(L_, block) != bd.splash_mask(L_, 2 * block)
     info, fn = mi.process_mask(sm.MultiHeadMask([mask] * 2), (128, 128),
                                shrink_grid=False)
-    assert fn is not None and info.partial_mask_blocks is None  # computed
-    tiles = np.asarray(info.block_mask)[0]
-    want = np.array([[1, 0, 1, 0],
-                     [0, 1, 2, 1],
-                     [0, 0, 1, 0],
-                     [0, 0, 2, 1]])
+    # computed in the kernel from one integer a row: no tile is stored
+    assert fn is not None and info.partial_mask_blocks is None
+    assert (info.q_sequence == bd.kernel_rows(L_, block)).all()
+    tiles, n = np.asarray(info.block_mask)[0], 2 * L_ // 128
     by_hand = np.array([[dense[r * 128:(r + 1) * 128, c * 128:(c + 1) * 128]
-                         .any() for c in range(4)] for r in range(4)])
-    assert ((tiles > 0) == by_hand).all() and (tiles > 0).sum() == 8
-    assert (tiles == want).all()
+                         .any() for c in range(n)] for r in range(n)])
+    assert ((tiles > 0) == by_hand).all()
+    assert (tiles == np.array(want)).all()
+    assert bd.tile_counts(L_, block, 128, 128) == {
+        "kept": int((tiles > 0).sum()), "crossed": int((tiles == 1).sum()),
+        "of": n * n}
 
 
-def test_the_kernel_path_agrees_with_the_dense_path(monkeypatch):
+@pytest.mark.parametrize("L_, block", [(128, 4), (192, 3)], ids=["4", "3"])
+def test_the_kernel_path_agrees_with_the_dense_path(monkeypatch, L_, block):
     """``attend`` on the splash path (the kernel under Pallas' interpreter,
     its mask the object above, GQA native) against the dense boolean form
     through ``attention_scores``, forward and backward."""
@@ -307,8 +375,8 @@ def test_the_kernel_path_agrees_with_the_dense_path(monkeypatch):
     for name in ("make_splash_mha", "make_splash_mqa"):
         monkeypatch.setattr(sk, name, lambda *a, _f=getattr(sk, name), **kw:
                             _f(*a, interpret=True, **kw))
-    L_ = 128
-    cfg = sdar_tiny(max_seq_len=L_, attn_block_q=128, attn_block_kv=128)
+    cfg = sdar_tiny(max_seq_len=L_, bd_block=block, attn_block_q=128,
+                    attn_block_kv=128)
     keys = jax.random.split(jax.random.PRNGKey(5), 3)
     q = jax.random.normal(keys[0], (1, 2 * L_, 4, 32))
     k = jax.random.normal(keys[1], (1, 2 * L_, 2, 32))
@@ -466,8 +534,10 @@ def test_the_step_trains_and_reports_its_objective(monkeypatch):
     (event,) = [e for e in events if e["kind"] == "cheetah_init"]
     assert (event["objective"], event["bd_block"], event["head_dim"]) == (
         "block_diffusion", B, 32)
+    # 2L = 128 rows under the kernel's default tiles of 128: one tile
     assert event["attn_mask"] == {"kind": "block_diffusion",
-                                  "pair_share": (L * L + L * B) / (4 * L * L)}
+                                  "pair_share": (L * L + L * B) / (4 * L * L),
+                                  "tiles": {"kept": 1, "crossed": 1, "of": 1}}
     tokens = jnp.tile(jnp.arange(L) % 7, (2, 1)).astype(jnp.int32)
     losses, masked = [], []
     for _ in range(8):
@@ -521,6 +591,8 @@ def test_what_the_objective_refuses_it_refuses_by_name():
     with pytest.raises(ValueError, match="2L rows"):
         cfg.attn_mask(2 * L + 2)
     assert cfg.attn_mask(2 * L) == ("block_diffusion", L, B)
+    with pytest.raises(ValueError, match="blocks that divide the sequence"):
+        bd.splash_mask(96, 5)
     assert TransformerConfig.tiny().attn_mask(32) == ("causal",)
     assert dataclasses.replace(TransformerConfig.tiny(),
                                causal=False).attn_mask(32) == ("full",)
