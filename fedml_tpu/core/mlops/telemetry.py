@@ -39,6 +39,7 @@ import bisect
 import collections
 import dataclasses
 import itertools
+import logging
 import threading
 import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
@@ -497,6 +498,70 @@ def _emit_compile(fun_name: str, seconds: float) -> None:
 
 
 # ---------------------------------------------------------------------------
+# Which scope every instruction of a compiled program belongs to
+# ---------------------------------------------------------------------------
+
+
+def compiles() -> float:
+    """Backend compiles and persistent-cache loads so far (``jax.compiles``):
+    a jitted call around which this moved has made itself a new program."""
+    return _REG.counter("jax.compiles")
+
+
+def abstract_of(args: Any) -> Any:
+    """``args`` with every ``jax.Array`` leaf replaced by its shape, dtype,
+    weak type and, where it is committed to one, its sharding: what
+    ``jitted.lower`` needs to find the program that a call with ``args``
+    compiled; a donated array still says all of these. (An uncommitted
+    array's placement is the call's to choose; a sharding named for it would
+    be another program.)"""
+    import jax
+
+    def leaf(x):
+        if not isinstance(x, jax.Array):
+            return x
+        return jax.ShapeDtypeStruct(
+            x.shape, x.dtype, weak_type=x.weak_type,
+            sharding=x.sharding if x.committed else None)
+
+    return jax.tree.map(leaf, args)
+
+
+def record_program_scopes(lowered, vocabulary: Tuple[str, ...]) -> None:
+    """One ``program_scopes`` event for a compiled program: the scope path
+    and pass of every instruction of its optimized HLO
+    (``scopes.program_map``), under the instruction names a profiler trace
+    prints on its ``XLA Ops`` line. ``lowered`` is ``jitted.lower(..)`` of
+    :func:`abstract_of` the arguments a call has just had: its ``compile()``
+    then hands back the call's own executable, and the map is read from what
+    runs, so it agrees with the trace even where the persistent cache served
+    an executable compiled under other names (docs/telemetry.md). Where the
+    lowering found another program than the call's, nothing is published:
+    compiling a step a second time to name its instructions is not worth it.
+    Callers publish once per compiled program (a call around which
+    :func:`compiles` moved) and only where :func:`enabled`; a no-op after
+    one bool check otherwise."""
+    if not _State.enabled:
+        return
+    from . import _emit
+    from . import scopes
+
+    # private to jax (0.9.0): the lowering's cached executable, set by the
+    # call path's compile; None or absent means compile() would compile
+    if getattr(getattr(lowered, "_lowering", None), "_executable", None) is None:
+        _REG.inc("program_scopes.skipped")
+        logging.getLogger(__name__).warning(
+            "program_scopes: the lowered program is not the one the call "
+            "compiled; not published")
+        return
+    found = scopes.program_map(lowered.compile().as_text(), vocabulary)
+    module = found.pop("module")
+    _emit({"kind": "program_scopes",
+           "program": module[len("jit_"):] if module.startswith("jit_") else module,
+           "module": module, **found})
+
+
+# ---------------------------------------------------------------------------
 # Phase spans
 # ---------------------------------------------------------------------------
 
@@ -667,7 +732,7 @@ def begin_round(round_idx: int, fused: bool = False,
     """Open a RoundRecord; ``None`` (after one bool check) when disabled.
 
     Emits the earlier records whose device scalars are ready, in order, and
-    never waits for one. Called inside a span (the loops' ``hooks``), the
+    never waits for one (the ``emit_record`` span). Called inside a span (the loops' ``hooks``), the
     record starts where that span started, so the span lies within it. The
     round or step runs under a ``StepTraceAnnotation`` named ``unit``."""
     if not _State.enabled:
@@ -676,11 +741,13 @@ def begin_round(round_idx: int, fused: bool = False,
 
     now = time.perf_counter()
     _TLS.last = None  # the previous record takes no more spans
-    drain_records(block=False)
     rec = RoundRecord(round_idx=int(round_idx), fused=fused,
                       superround=superround, cohort_chunk=cohort_chunk)
     stack = _span_stack()
     rec.t0_ns = stack[-1].t0_ns if stack else time.perf_counter_ns()
+    _TLS.record = rec  # the emit_record span below is already this record's
+    with phase("emit_record", record=False):
+        drain_records(block=False)
     rec.in_flight = len(_PENDING)
     rec._compiles0 = _REG.counter("jax.compiles")
     with _STATE_LOCK:  # read-modify-write shared with comm-thread rounds
@@ -693,7 +760,6 @@ def begin_round(round_idx: int, fused: bool = False,
     rec._step_annotation = jax.profiler.StepTraceAnnotation(
         unit, step_num=rec.round_idx)
     rec._step_annotation.__enter__()
-    _TLS.record = rec
     return rec
 
 
@@ -749,11 +815,20 @@ def end_round(rec: Optional[RoundRecord],
 
 
 def _emit_record(rec: RoundRecord) -> None:
+    import jax
+
     from . import _emit
 
-    rec.train_loss = _realize(rec.lazy.pop("train_loss", None))
-    rec.examples = _realize(rec.lazy.pop("examples", None))
-    rec.counters = {k: _realize(v) for k, v in rec.lazy.items()}
+    try:
+        # the record's device scalars in one go: their copies to the host
+        # overlap, where one np.asarray each waits for each in turn (a step's
+        # routing counters cost 2 to 3 ms of begin_round so: PERF.md, PR 36)
+        lazy = jax.device_get(rec.lazy)
+    except Exception:  # a value that cannot be read reads as None below
+        lazy = rec.lazy
+    rec.train_loss = _realize(lazy.pop("train_loss", None))
+    rec.examples = _realize(lazy.pop("examples", None))
+    rec.counters = {k: _realize(v) for k, v in lazy.items()}
     rec.lazy.clear()
     rec.emitted = True
     _REG.inc("rounds.total")

@@ -22,8 +22,10 @@ from flax import struct
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..core import mlops
+from ..core.mlops import telemetry
 
 # names for the step's device work outside the flax modules
+from ..core.mlops.scopes import TRAIN_STEP
 from ..core.mlops.scopes import train_step_scope as _scope
 from . import block_diffusion
 from .context import get_mesh_context, mesh_context, sequence_parallelism
@@ -653,8 +655,28 @@ class CheetahTrainer:
 
     def train_step(self, state: TrainState, tokens, mask) -> Tuple[TrainState, dict]:
         tokens, mask = self.shard_batch(tokens, mask)
+        tracked = telemetry.enabled()
+        if tracked:
+            compiles = telemetry.compiles()
         with self._trace_context():
-            return self._step_jit(state, tokens, mask)
+            out = self._step_jit(state, tokens, mask)
+        if tracked and telemetry.compiles() != compiles:
+            # the donated state's types only (shape, dtype, sharding), which
+            # it still says; its buffers are not read
+            self._publish_scopes((state, tokens, mask))  # graftlint: disable=G002
+        return out
+
+    def _publish_scopes(self, args) -> None:
+        """After a tracked call that compiled (or loaded) its program: which
+        scope every instruction of that program belongs to
+        (``program_scopes``, docs/telemetry.md). Lowering the types the call
+        had finds the call's own executable: nothing compiles here. Under
+        fsdp the first step's program is not the later steps' (the moments
+        arrive replicated once), so each is published as it appears."""
+        with telemetry.phase("program_scopes", record=False):
+            with self._trace_context():
+                lowered = self._step_jit.lower(*telemetry.abstract_of(args))
+            telemetry.record_program_scopes(lowered, TRAIN_STEP)
 
     def lower_step(self, state: TrainState, tokens, mask):
         """The step lowered (not compiled) exactly as ``train_step`` traces

@@ -38,6 +38,7 @@ import numpy as np
 from .. import constants
 from ..core.dp import FedPrivacyMechanism
 from ..core.mlops import telemetry
+from ..core.mlops.scopes import ROUND
 from ..core.security.attacker import FedMLAttacker
 from ..core.security.defender import FedMLDefender
 from ..ml.evaluate import make_eval_fn
@@ -535,10 +536,23 @@ class FedAvgAPI:
         A subclass may replace the whole round (HierarchicalFLAPI).
         """
         inputs = self._round_inputs(round_idx)
+        tracked = telemetry.enabled()
+        if tracked:
+            compiles = telemetry.compiles()
         with telemetry.phase("dispatch"):
             state, metrics = self._round(*inputs)
             self._set_round_state(state)
             telemetry.record_lazy("examples", metrics.get("examples"))
+            if (tracked and self._round_step is not None
+                    and telemetry.compiles() != compiles):
+                # the jitted round made itself a program in this call: which
+                # scope each of its instructions belongs to (program_scopes,
+                # docs/telemetry.md). Lowering the types the call had finds
+                # the call's own executable: nothing compiles here
+                with telemetry.phase("program_scopes", record=False):
+                    telemetry.record_program_scopes(
+                        self._round_step.lower(
+                            *telemetry.abstract_of(inputs)), ROUND)
         return {"train_loss": metrics["train_loss"]}
 
     # -- the training loop (reference: fedavg_api.py:65-123) ----------------
