@@ -38,8 +38,16 @@ constant only). One layer, a routing rule and a capacity rule:
   passes, added to the routed result.
 
 Either way dispatch and combine are row gathers over assignments sorted by
-expert (stable, so GShard's priority order survives inside each group); the
-only scatters are the ones autodiff inserts for the gathers' transposes.
+expert (stable, so GShard's priority order survives inside each group), and
+so are their transposes, written by hand: no row is scattered. Without a
+capacity an assignments-sized buffer (``[kT, D]``) is written once, by the
+gather that makes it, and read once, by the product or the reduction that
+consumes it: a gather that wants zeros for missing rows reads a tokens-sized
+operand with one zero row appended (:func:`_padded_rows`), one that reads an
+assignments-sized operand drops them inside the sum over the choices
+(:func:`_gathered_sum`), and the combine's backward works row by row from
+the tokens' cotangent (:func:`_combine`). Under a capacity the rows' way to
+their slots appends its zero row to the rows themselves, one copy more.
 Expert weights are ONE stacked param tree ``[held, ...]``; the logical
 ``expert`` axis maps to the ``expert`` mesh axis (sharding.LOGICAL_RULES).
 
@@ -157,17 +165,22 @@ class MoEFeedForward(nn.Module):
             # experts this program holds are 0..held-1, the others `held`
             local = flat_expert - offset
             local = jnp.where((local >= 0) & (local < held), local, held)
-            order = jnp.argsort(local, stable=True).astype(jnp.int32)  # [kT]
-            sorted_local = local[order]
+            # one stable sort brings the keys, the assignments' flat indices
+            # and their gates into expert order; the gates ride along for the
+            # combine's backward, which works row by row, and carry no
+            # gradient here (the combine returns the gates')
+            sorted_local, order, gate_row = jax.lax.sort(
+                (local, jnp.arange(k * T, dtype=jnp.int32),
+                 jax.lax.stop_gradient(gate).T.reshape(k * T)),
+                num_keys=1, is_stable=True)                        # [kT] each
             if cfg.moe_capacity_factor > 0:
                 kept_sorted, slot = _capacity_slots(cfg, sorted_local, counts)
             else:
                 kept_sorted, slot = sorted_local < held, None
-            # the three index maps of dispatch and combine; an index past the
-            # end reads as a row of zeros: row -> its token, row -> its flat
-            # assignment, (choice, token) -> its row
+            # the two index maps of dispatch and combine; an index one past
+            # the end stands for a row of zeros: row -> its token, and
+            # (choice, token) -> its row
             token = jnp.where(kept_sorted, order % T, T)
-            back = jnp.where(kept_sorted, order, k * T)
             inv = jnp.argsort(order, stable=True).astype(jnp.int32)
             src = jnp.where(kept_sorted[inv], inv, k * T).reshape(k, T)
         self.sow("moe_stats", "load", load)
@@ -182,10 +195,10 @@ class MoEFeedForward(nn.Module):
                 out = _slotted_experts(cfg, rows, w_gate_up, w_down, slot)
             # each choice's row back at its token, weighed by its gate;
             # dropped and absent assignments add nothing
-            chosen = _combine(out, src, back)                        # [k, T, D]
-            y32 = sum(gate[:, c, None] * chosen[c].astype(jnp.float32)
-                      for c in range(k))
-        y = y32.astype(cfg.dtype).reshape(B, L, D)
+            # in the result's own shape: a reshape after the sum over the
+            # choices would stand between that sum and the slices it reads
+            y = _combine(out, gate.reshape(B, L, k), gate_row,
+                         src.reshape(k, B, L), token, order)         # [B, L, D]
         if cfg.moe_shared_experts:
             with _scope("shared_expert"):
                 y = y + FeedForward(
@@ -193,52 +206,88 @@ class MoEFeedForward(nn.Module):
         return y, aux_loss
 
 
-def _rows(x, index):
-    """``x[index]`` along axis 0, a row of zeros where ``index`` is past the
-    end."""
-    return jnp.take(x, index, axis=0, mode="fill", fill_value=0)
+def _padded_rows(x, index):
+    """``x[index]`` along axis 0 for ``index`` up to ``len(x)``, which reads a
+    row of zeros: one zero row appended to ``x`` and a plain in-bounds gather,
+    so no pass over the result to fill it. The copy is the operand's size,
+    the tokens' where it matters."""
+    padded = jnp.concatenate([x, jnp.zeros((1,) + x.shape[1:], x.dtype)])
+    return jnp.take(padded, index, axis=0, mode="clip")
+
+
+def _gathered_sum(rows, src, weight=None):
+    """``sum_c weight[c, t] * rows[src[c, t]]`` in float32, [N, D] and [k, ...]
+    -> [..., D], leaving out the entries whose ``src`` is ``N`` or more. They
+    are selected away, not multiplied by zero (a row no entry names may hold
+    anything). The gathered ``[k, ..., D]`` rows are read once: select,
+    convert, product and the sum over ``c``, added in the order of ``c``, are
+    one fusion."""
+    chosen = jnp.take(rows, src, axis=0, mode="clip")
+    present = src < rows.shape[0]
+    total = 0
+    for c in range(src.shape[0]):
+        term = jnp.where(present[c, ..., None], chosen[c], 0).astype(jnp.float32)
+        total = total + (term if weight is None else weight[c, ..., None] * term)
+    return total
 
 
 @jax.custom_vjp
 def _dispatch(x, token, src):
-    """Tokens [T, D] -> the assignments' rows [kT, D], sorted by expert:
-    row ``p`` is token ``token[p]`` (zeros where the assignment is dropped or
-    its expert held elsewhere). Its transpose is :func:`_combine` summed over
-    the choices, so the backward pass gathers too: autodiff's own transpose
-    of a gather is a scatter-add, which a TPU serialises."""
+    """Rows [T, D] -> [N, D]: row ``p`` is ``x[token[p]]``, zeros where
+    ``token[p]`` is ``T``; ``src`` [k, T] names the rows that read each ``x[t]``
+    (``N`` where fewer than ``k`` do). Tokens to the assignments' rows sorted
+    by expert, and under a capacity those rows to their slots and back. Its
+    transpose sums ``g[src[c, t]]`` over ``c``, so the backward pass gathers
+    too: autodiff's own transpose of a gather is a scatter-add, which a TPU
+    serialises."""
     del src
-    return _rows(x, token)
+    return _padded_rows(x, token)
 
 
 def _dispatch_fwd(x, token, src):
-    return _rows(x, token), src
+    return _padded_rows(x, token), src
 
 
 def _dispatch_bwd(src, g):
-    return (_rows(g, src).astype(jnp.float32).sum(0).astype(g.dtype),
-            None, None)
+    return _gathered_sum(g, src).astype(g.dtype), None, None
 
 
 _dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 
 @jax.custom_vjp
-def _combine(rows, src, back):
-    """The experts' output rows [kT, D] -> [k, T, D]: choice ``c`` of token
-    ``t`` is row ``src[c, t]`` (zeros where it has none). Rows that belong
+def _combine(out, gate, gate_row, src, token, order):
+    """The experts' output rows [kT, D] and the gates [..., k] (the tokens in
+    any leading shape, ``src`` [k, ...] in the same) -> [..., D] in the rows'
+    type: ``sum_c gate[t, c] * out[src[c, t]]`` in float32. Rows that belong
     to no kept assignment are never read, whatever the kernel left there.
-    Its transpose reads cotangent ``back[p]`` (a flat ``c * T + t``) for row
-    ``p``: a gather again."""
-    del back
-    return _rows(rows, src)
+
+    The backward pass works in row order and never builds a ``[k, T, D]``
+    cotangent: row ``p`` reads ``dy[token[p]]`` (a gather from the tokens'
+    ``[T, D]``, zeros where ``p`` is no kept assignment), times its gate
+    ``gate_row[p]`` it is ``out``'s cotangent, and its product with ``out[p]``
+    summed over ``D`` the gate's, which one sort by ``order`` (row -> flat
+    ``c * T + t``, a permutation) returns to the gates' shape. The residual is
+    ``out`` itself, so a rematerialised block recomputes no combine."""
+    del gate_row, token, order
+    return _gathered_sum(out, src, jnp.moveaxis(gate, -1, 0)).astype(out.dtype)
 
 
-def _combine_fwd(rows, src, back):
-    return _rows(rows, src), back
+def _combine_fwd(out, gate, gate_row, src, token, order):
+    y = _gathered_sum(out, src, jnp.moveaxis(gate, -1, 0)).astype(out.dtype)
+    return y, (out, gate_row, src.shape, token, order)
 
 
-def _combine_bwd(back, g):
-    return _rows(g.reshape(-1, g.shape[-1]), back), None, None
+def _combine_bwd(residuals, dy):
+    out, gate_row, choices, token, order = residuals
+    dy = dy.reshape(-1, dy.shape[-1])                                # [T, D]
+    g = _padded_rows(dy, token).astype(jnp.float32)                 # [kT, D]
+    d_out = (gate_row[:, None] * g).astype(out.dtype)
+    d_gate_row = jnp.where(
+        token < dy.shape[0], (g * out.astype(jnp.float32)).sum(-1), 0)   # [kT]
+    _, d_gate_flat = jax.lax.sort((order, d_gate_row), num_keys=1)
+    d_gate = jnp.moveaxis(d_gate_flat.reshape(choices), 0, -1)
+    return d_out, d_gate, None, None, None, None
 
 
 _combine.defvjp(_combine_fwd, _combine_bwd)
@@ -278,14 +327,14 @@ def _slotted_experts(cfg, rows, w_gate_up, w_down, slot):
     # slot -> the row that fills it (kT where none does)
     filler = jnp.full((n_slots + 1,), kT, jnp.int32).at[slot].set(
         jnp.arange(kT, dtype=jnp.int32), mode="drop")[:n_slots]
-    expert_in = _rows(rows, filler).reshape(held, capacity, D)
+    expert_in = _dispatch(rows, filler, slot[None]).reshape(held, capacity, D)
 
     def ffn(gu_w, down_w, h):
         gu = jnp.einsum("cd,df->cf", h, gu_w.astype(cfg.dtype))
         return jnp.einsum("cf,fd->cd", _swiglu(gu), down_w.astype(cfg.dtype))
 
     expert_out = jax.vmap(ffn)(w_gate_up, w_down, expert_in)
-    return _rows(expert_out.reshape(n_slots, D), slot)
+    return _dispatch(expert_out.reshape(n_slots, D), slot, filler[None])
 
 
 def _grouped_experts(cfg, rows, w_gate_up, w_down, counts):

@@ -190,6 +190,38 @@ def test_compiled_step_maps_every_instruction(config, holds, passes):
     assert "optimizer" in scopes.program_map(text, renamed)["stale"]
 
 
+def test_an_expert_layers_backward_and_recomputation_stay_under_its_scopes():
+    """ISSUE 37 wrote the combine's backward by hand (a gather from the tokens'
+    cotangent, the product with the rows' gates, the gate gradient's reduction
+    and the sort that returns it to ``[T, k]``): every instruction of it is
+    traced under ``moe_experts`` like the forward's, so the seconds
+    ``moe_experts.dispatch_combine_s_per_step`` reads hold all of it. Only the
+    forward has instructions under the module alone (the sown counters)."""
+    cfg = dataclasses.replace(TransformerConfig.tiny(), remat=True, **EXPERTS)
+    # one device: under the tests' eight the module's own reshape of the
+    # tokens becomes a collective of the backward pass too
+    trainer = CheetahTrainer(cfg, make_mesh({"fsdp": 1}, devices=jax.devices()[:1]))
+    state = trainer.init_state(jax.random.PRNGKey(0))
+    tokens = jnp.zeros((8, 32), jnp.int32)
+    text = trainer.lower_step(state, tokens, jnp.ones_like(tokens)).compile().as_text()
+    found = scopes.program_map(text, scopes.TRAIN_STEP)
+    of = {name: tuple(found["scopes"][i]) for name, i in found["ops"].items()}
+    layer = {name: key for name, key in of.items()
+             if "MoEFeedForward" in key[0].split("/")}
+    inner = {"moe_route", "moe_experts", "shared_expert"}
+    bare = {which for path, which in layer.values()
+            if not inner & set(path.split("/"))}
+    assert bare == {"fwd"}
+    passes = {which for path, which in layer.values()
+              if path.endswith("/moe_experts")}
+    assert passes == {"fwd", "bwd", "remat"}
+    # the one sort of the backward pass is the gate gradient's way back
+    sorts = [name for name in re.findall(r"^\s+%?(\S+) = .* sort\(", text, re.M)
+             if layer.get(name, ("", ""))[1] == "bwd"]
+    assert len(sorts) == 1
+    assert layer[sorts[0]][0].endswith("MoEFeedForward/moe_experts")
+
+
 # ---------------------------------------------------------------------------
 # the publication: once per compiled program, tracked runs only
 # ---------------------------------------------------------------------------

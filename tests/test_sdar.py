@@ -648,12 +648,14 @@ def test_the_programs_flops_count_a_data_token():
 # factor (Switch-shaped): the head size, the q/k norms, the named mask, the
 # softmax rule for any k and the objective leave such programs as they were.
 # (The Mistral- and Xing4-shaped hashes are tests/test_xing4.py's and
-# tests/test_ling3.py's.)
+# tests/test_ling3.py's.) Taken again at PR 37, which rewrote the expert
+# layer's dispatch and combine and so every program that holds one (forward
+# bit for bit and gradients against a dense oracle: tests/test_moe.py).
 PARENT_STEPS = {
     "ling_shaped":
-        "5d63b3b34df3c98fa977ef9e8022feeb35786c02d1ca33c869014dc17e3e618e",
+        "46d8d20806451e5a66b28e59e6d8e55b1b159b187b27cbb5317103a59211c4c4",
     "switch_shaped":
-        "4cf8b06710f53ac9a21a9a53cdb6b6b5c574771c3806cc518e1f81149b52207d",
+        "62807bb177a79a906c5894fcbdce26276f9773ada7496bba8fc50f7b852e053d",
 }
 
 
