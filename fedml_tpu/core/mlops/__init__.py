@@ -199,7 +199,8 @@ def log_cheetah_init(mesh: Dict[str, int],
                      experts_held: int, mhc_backward: str,
                      mixers: str, kda_path: str, kda_chunk: int,
                      objective: str, bd_block: int, attn_mask: Dict[str, Any],
-                     head_dim: int) -> None:
+                     head_dim: int, layer_pattern: str, ssd: Dict[str, Any],
+                     ffn_act: str) -> None:
     """What a Cheetah trainer decided from its mesh and its configuration at
     trace time, once a run (docs/telemetry.md, ``cheetah_init``)."""
     _emit({"kind": "cheetah_init", "mesh": mesh,
@@ -208,7 +209,8 @@ def log_cheetah_init(mesh: Dict[str, int],
            "experts_held": experts_held, "mhc_backward": mhc_backward,
            "mixers": mixers, "kda_path": kda_path, "kda_chunk": kda_chunk,
            "objective": objective, "bd_block": bd_block,
-           "attn_mask": attn_mask, "head_dim": head_dim})
+           "attn_mask": attn_mask, "head_dim": head_dim,
+           "layer_pattern": layer_pattern, "ssd": ssd, "ffn_act": ffn_act})
 
 
 def log_training_status(status: str) -> None:

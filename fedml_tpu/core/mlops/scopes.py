@@ -32,7 +32,8 @@ import jax
 # parallel/transformer.py, parallel/moe.py: what a configuration adds to the
 # blocks of ``_train_step_raw``; absent from a step whose configuration has
 # no latent attention, hyper-connections, experts, MTP module,
-# linear-attention layers, q/k norms or block-diffusion objective
+# linear-attention or state-space layers, q/k norms or block-diffusion
+# objective
 TRAIN_STEP_BLOCKS: Tuple[str, ...] = (
     "mla",            # LatentAttention_N: low-rank projections, norms, scores
     "mhc",            # hyper-connection maps, stream reads and writes
@@ -47,6 +48,11 @@ TRAIN_STEP_BLOCKS: Tuple[str, ...] = (
     "kda_chunk",      # inside kda: the chunked form (kda_chunk_fwd / _bwd)
     "qk_norm",        # inside Attention_N: the per-head RMS norms of q and k
     "bd_noise",       # block diffusion: the noise draw and the doubled input
+    "mamba",          # Mamba2Mixer_N: the state-space mixer
+    "ssd_proj",       # inside mamba: the input and the output projection
+    "ssd_conv",       # inside mamba: the short causal convolution of x, B, C
+    "ssd_chunk",      # inside mamba: the chunked recurrence, all of it
+    "ssd_norm",       # inside mamba: the gate and the grouped RMS norm
 )
 
 # parallel/train_step.py: the jitted ``_train_step_raw``

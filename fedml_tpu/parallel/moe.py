@@ -35,7 +35,11 @@ constant only). One layer, a routing rule and a capacity rule:
   What the absent experts would have added is left out, and nothing stands
   in for their chips or the exchange.
 - ``cfg.moe_shared_experts`` experts of the same width that every token
-  passes, added to the routed result.
+  passes, added to the routed result (``cfg.moe_shared_d_ff``: one of a
+  width of its own).
+- **the activation** (``cfg.ffn_act``): an expert is the gated SiLU form,
+  ``w_gate_up`` ``[held, D, 2F]``, or ``relu(x W_up)^2 W_down`` with no gate
+  matrix, ``w_up`` ``[held, D, F]``; the grouped products take either.
 
 Either way dispatch and combine are row gathers over assignments sorted by
 expert (stable, so GShard's priority order survives inside each group), and
@@ -65,7 +69,8 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from ..core.mlops.scopes import train_step_scope as _scope
-from .transformer import EMBED, MLP, FeedForward, TransformerConfig
+from .transformer import (EMBED, MLP, FeedForward, TransformerConfig,
+                          ffn_activation)
 
 EXPERT_AXIS = "expert_dim"  # logical name for the stacked-expert axis
 
@@ -132,10 +137,11 @@ class MoEFeedForward(nn.Module):
             "w_router", nn.with_partitioning(init, (EMBED, None)),
             (D, E), jnp.float32,
         )
-        w_gate_up = self.param(
-            "w_gate_up",
+        gated = cfg.ffn_act == "swiglu"  # gate and up fused, or up alone
+        w_up = self.param(
+            "w_gate_up" if gated else "w_up",
             nn.with_partitioning(init, (EXPERT_AXIS, EMBED, MLP)),
-            (held, D, 2 * F), cfg.param_dtype,
+            (held, D, 2 * F if gated else F), cfg.param_dtype,
         )
         w_down = self.param(
             "w_down",
@@ -190,9 +196,9 @@ class MoEFeedForward(nn.Module):
         with _scope("moe_experts"):
             rows = _dispatch(xt.astype(cfg.dtype), token, src)       # [kT, D]
             if slot is None:
-                out = _grouped_experts(cfg, rows, w_gate_up, w_down, counts)
+                out = _grouped_experts(cfg, rows, w_up, w_down, counts)
             else:
-                out = _slotted_experts(cfg, rows, w_gate_up, w_down, slot)
+                out = _slotted_experts(cfg, rows, w_up, w_down, slot)
             # each choice's row back at its token, weighed by its gate;
             # dropped and absent assignments add nothing
             # in the result's own shape: a reshape after the sum over the
@@ -201,8 +207,7 @@ class MoEFeedForward(nn.Module):
                          src.reshape(k, B, L), token, order)         # [B, L, D]
         if cfg.moe_shared_experts:
             with _scope("shared_expert"):
-                y = y + FeedForward(
-                    cfg, d_ff=F * cfg.moe_shared_experts, name="shared")(x)
+                y = y + FeedForward(cfg, d_ff=cfg.shared_d_ff, name="shared")(x)
         return y, aux_loss
 
 
@@ -293,11 +298,6 @@ def _combine_bwd(residuals, dy):
 _combine.defvjp(_combine_fwd, _combine_bwd)
 
 
-def _swiglu(h):
-    gate, up = jnp.split(h, 2, axis=-1)
-    return nn.silu(gate) * up
-
-
 def _capacity_slots(cfg, sorted_local, counts):
     """GShard's capacity: which sorted assignments are kept (their expert is
     held here and they are among its first ``capacity``), and the slot
@@ -315,12 +315,12 @@ def _capacity_slots(cfg, sorted_local, counts):
     return kept, jnp.where(kept, group * capacity + pos, held * capacity)
 
 
-def _slotted_experts(cfg, rows, w_gate_up, w_down, slot):
+def _slotted_experts(cfg, rows, w_up, w_down, slot):
     """Each held expert computes ``capacity`` rows, whatever arrived: the
     sorted rows go to their slots of an ``[held, capacity, D]`` buffer (a
     gather by the slots' inverse: a kept row's slot is unique), through the
-    vmapped SwiGLU, and back."""
-    held = w_gate_up.shape[0]
+    vmapped feed-forward, and back."""
+    held = w_up.shape[0]
     kT, D = rows.shape
     capacity = max(int(cfg.moe_capacity_factor * kT / cfg.moe_experts), 1)
     n_slots = held * capacity
@@ -329,21 +329,23 @@ def _slotted_experts(cfg, rows, w_gate_up, w_down, slot):
         jnp.arange(kT, dtype=jnp.int32), mode="drop")[:n_slots]
     expert_in = _dispatch(rows, filler, slot[None]).reshape(held, capacity, D)
 
-    def ffn(gu_w, down_w, h):
-        gu = jnp.einsum("cd,df->cf", h, gu_w.astype(cfg.dtype))
-        return jnp.einsum("cf,fd->cd", _swiglu(gu), down_w.astype(cfg.dtype))
+    def ffn(up_w, down_w, h):
+        up = jnp.einsum("cd,df->cf", h, up_w.astype(cfg.dtype))
+        return jnp.einsum("cf,fd->cd", ffn_activation(cfg.ffn_act, up),
+                          down_w.astype(cfg.dtype))
 
-    expert_out = jax.vmap(ffn)(w_gate_up, w_down, expert_in)
+    expert_out = jax.vmap(ffn)(w_up, w_down, expert_in)
     return _dispatch(expert_out.reshape(n_slots, D), slot, filler[None])
 
 
-def _grouped_experts(cfg, rows, w_gate_up, w_down, counts):
+def _grouped_experts(cfg, rows, w_up, w_down, counts):
     """No capacity: the sorted rows, held experts first, go through two
     grouped products whose groups are the experts' arrivals. Rows past the
     last group (assignments to experts held elsewhere) belong to no group:
     the kernel computes nothing for them, and :func:`_combine` never reads
     what it leaves there."""
-    h = jax.lax.ragged_dot(rows, w_gate_up.astype(cfg.dtype), counts,
+    h = jax.lax.ragged_dot(rows, w_up.astype(cfg.dtype), counts,
                            preferred_element_type=cfg.dtype)
-    return jax.lax.ragged_dot(_swiglu(h), w_down.astype(cfg.dtype), counts,
+    return jax.lax.ragged_dot(ffn_activation(cfg.ffn_act, h),
+                              w_down.astype(cfg.dtype), counts,
                               preferred_element_type=cfg.dtype)

@@ -99,6 +99,11 @@ class PipelineCheetah:
             raise NotImplementedError(
                 "PipelineCheetah stacks one block: a mixer chosen per layer "
                 "(layer_group_size) does not run under it")
+        if cfg.layer_pattern:
+            raise NotImplementedError(
+                "PipelineCheetah stacks one block: a layer list given as a "
+                "pattern (layer_pattern), one sublayer a layer, does not run "
+                "under it")
         self.schedule = schedule
         self.cfg = cfg
         self.mesh = mesh

@@ -42,6 +42,7 @@ from .sharding import (
     replicated,
     unbox,
 )
+from .ssd import SSD_PATH
 from .transformer import (EMBED, VOCAB, Transformer, TransformerConfig,
                           splash_forward_tiles)
 
@@ -327,6 +328,17 @@ class CheetahTrainer:
         self.kda_path = scan_path(
             cfg.n_heads, cfg.kda_head_dim, cfg.kda_head_dim, cfg.max_seq_len,
             KDA_CHUNK, mesh, seq_sharded) if "kda" in cfg.mixers else ""
+        # the state-space layers' sizes and the form their recurrence takes
+        # ({} without such a layer)
+        self.ssd = {}
+        if "ssd" in cfg.mixers:
+            if seq_sharded:
+                raise NotImplementedError(
+                    "a state-space mixer carries its state along the whole "
+                    "sequence: it does not run under sequence sharding yet")
+            self.ssd = {"heads": cfg.ssm_heads, "head_dim": cfg.ssm_head_dim,
+                        "groups": cfg.ssm_groups, "state": cfg.ssm_state,
+                        "chunk": cfg.ssm_chunk, "path": SSD_PATH}
 
         # the attention mask of a step at sequences of cfg.max_seq_len, by
         # name, with the share of the [rows, rows] pairs it lets through
@@ -426,6 +438,8 @@ class CheetahTrainer:
             kda_chunk=KDA_CHUNK if self.kda_path else 0,
             objective=self.cfg.objective, bd_block=int(self.cfg.bd_block),
             attn_mask=self.attn_mask, head_dim=int(self.cfg.head_dim),
+            layer_pattern=self.cfg.layer_pattern, ssd=self.ssd,
+            ffn_act=self.cfg.ffn_act,
         )
         # step must be committed to the mesh (replicated) — a default-device
         # scalar breaks jit after checkpoint restore (mixed device sets)

@@ -25,8 +25,12 @@ layers and a gated delta-rule linear-attention mixer (``KimiDeltaAttention``,
 ``parallel/kda.py``) in between; a GQA head size apart from ``d_model /
 n_heads`` with per-head RMS norms of q and k; and the block-diffusion
 objective's doubled sequence under its structured attention mask
-(``cfg.objective``, ``parallel/block_diffusion.py``). A configuration that
-names none of them builds the block it always built.
+(``cfg.objective``, ``parallel/block_diffusion.py``); a layer list given as
+data (``cfg.layer_pattern``) whose layers are one sublayer alone, a
+state-space mixer (``Mamba2Mixer``, ``parallel/ssd.py``), an expert layer, a
+dense feed-forward or attention; a squared-ReLU feed-forward with no gate
+matrix (``cfg.ffn_act``). A configuration that names none of them builds the
+block it always built.
 """
 
 from __future__ import annotations
@@ -56,6 +60,10 @@ MLP = "mlp"
 BATCH = "batch"
 LENGTH = "length"
 LORA = "lora"  # the low-rank side of a latent projection: never sharded
+
+
+# cfg.layer_pattern's letters: one sublayer a layer
+_PATTERN_LETTERS = "M*E-"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -105,6 +113,8 @@ class TransformerConfig:
     # average cleanly under FedAvg (clients share one positional basis);
     # rotary models can converge to per-client-rotated solutions whose
     # average destroys the task — measured on the prefix-LM seq2seq head.
+    # "none": attention takes no positions (a stack whose recurrent mixers
+    # order the tokens); gqa only
     pos_emb: str = "rope"
     # -- attention kind ------------------------------------------------------
     # "gqa": fused q, k, v at one head size. "mla": multi-head latent
@@ -112,7 +122,7 @@ class TransformerConfig:
     # query/key head of qk_nope_head_dim + qk_rope_head_dim of which only the
     # rope part is rotated (its key shared by all heads), a value head of
     # v_head_dim. q_lora_rank 0: a full-rank query, ``q = x W_q``, no q norm.
-    # "kda": Kimi delta attention (KimiDeltaAttention), a linear mixer
+    # "kda": Kimi delta attention (KimiDeltaAttention), a linear mixer.
     attn_kind: str = "gqa"
     q_lora_rank: int = 0
     kv_lora_rank: int = 0
@@ -144,6 +154,29 @@ class TransformerConfig:
     # with moe_experts > 1: this many leading dense layers (d_ff wide), then
     # expert layers. 0 = every layer an expert layer (the Switch stacks)
     first_k_dense: int = 0
+    # the layer list as data, one letter a layer, each layer ONE pre-norm
+    # residual sublayer: "M" a state-space mixer, "*" attention of
+    # ``attn_kind``, "E" an expert layer, "-" a dense feed-forward. "": the
+    # two rules above (first_k_dense, layer_group_size), every layer a mixer
+    # and a feed-forward part
+    layer_pattern: str = ""
+    # Mamba2Mixer: ssm_heads heads of ssm_head_dim, B and C in ssm_groups
+    # groups of ssm_state, a causal depthwise convolution of ssm_conv_size
+    # taps, the recurrence in chunks of ssm_chunk; dt_bias drawn so that the
+    # step sizes start log-uniform in [ssm_dt_min, ssm_dt_max], floored
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_groups: int = 1
+    ssm_state: int = 0
+    ssm_conv_size: int = 4
+    ssm_chunk: int = 128
+    ssm_dt_min: float = 1e-3
+    ssm_dt_max: float = 0.1
+    ssm_dt_floor: float = 1e-4
+    # -- feed-forward activation, dense and expert alike -----------------------
+    # "swiglu": silu(x W_gate) * (x W_up), gate and up fused. "relu2":
+    # relu(x W_up)^2, no gate matrix
+    ffn_act: str = "swiglu"
     # -- expert layer (parallel/moe.py): a routing rule and a capacity rule --
     # "softmax": scores softmax(x W_r) over all experts, the moe_top_k
     # largest, the Switch load-balancing auxiliary loss (Switch, GShard,
@@ -165,6 +198,9 @@ class TransformerConfig:
     # every token passes
     moe_d_ff: int = 0
     moe_shared_experts: int = 0
+    # the shared expert's width where it is not moe_shared_experts times an
+    # expert's (0: it is)
+    moe_shared_d_ff: int = 0
     # the share of the routed experts this program holds (expert
     # parallelism, one chip's part): routes over all moe_experts, computes
     # experts [offset, offset + held); 0 = all of them
@@ -204,6 +240,48 @@ class TransformerConfig:
         if self.attn_kind not in ("gqa", "mla", "kda"):
             raise ValueError(
                 f"attn_kind must be gqa|mla|kda, got {self.attn_kind!r}")
+        if self.pos_emb not in ("rope", "learned", "none"):
+            raise ValueError(
+                f"pos_emb must be rope|learned|none, got {self.pos_emb!r}")
+        if self.pos_emb == "none" and "mla" in self.mixers:
+            raise ValueError("latent attention rotates part of its head: "
+                             "pos_emb none runs with gqa attention only")
+        if self.ffn_act not in ("swiglu", "relu2"):
+            raise ValueError(
+                f"ffn_act must be swiglu|relu2, got {self.ffn_act!r}")
+        if self.layer_pattern:
+            if set(self.layer_pattern) - set(_PATTERN_LETTERS):
+                raise ValueError(
+                    f"layer_pattern is made of {sorted(_PATTERN_LETTERS)}, "
+                    f"got {self.layer_pattern!r}")
+            if len(self.layer_pattern) != self.n_layers:
+                raise ValueError(
+                    f"layer_pattern {self.layer_pattern!r} names "
+                    f"{len(self.layer_pattern)} layers, n_layers is "
+                    f"{self.n_layers}")
+            if self.first_k_dense or self.layer_group_size:
+                raise ValueError(
+                    "layer_pattern is the whole layer list: first_k_dense "
+                    "and layer_group_size are the other spelling")
+            if "E" in self.layer_pattern and self.moe_experts <= 1:
+                raise ValueError("an E layer needs moe_experts > 1")
+        if "ssd" in self.mixers:
+            H, G = self.ssm_heads, self.ssm_groups
+            if not (H > 0 and self.ssm_head_dim > 0 and self.ssm_state > 0
+                    and G > 0 and H % G == 0 and self.ssm_conv_size >= 1
+                    and self.ssm_chunk >= 1
+                    and (H * self.ssm_head_dim) % G == 0
+                    and 0 < self.ssm_dt_min <= self.ssm_dt_max):
+                raise ValueError(
+                    "a state-space layer needs ssm_heads, ssm_head_dim and "
+                    "ssm_state > 0, ssm_groups dividing ssm_heads, "
+                    "ssm_conv_size and ssm_chunk >= 1 and 0 < ssm_dt_min <= "
+                    f"ssm_dt_max, got ({H}, {self.ssm_head_dim}, "
+                    f"{self.ssm_state}, {G}, {self.ssm_conv_size}, "
+                    f"{self.ssm_chunk}, {self.ssm_dt_min}, {self.ssm_dt_max})")
+        if self.layer_pattern and self.hc_mult > 1:
+            raise NotImplementedError(
+                "a layer_pattern does not run with hyper-connections (hc_mult)")
         if self.attn_kind == "mla" and not (
                 self.q_lora_rank >= 0 and self.kv_lora_rank > 0
                 and self.qk_nope_head_dim > 0 and self.qk_rope_head_dim > 0
@@ -262,8 +340,12 @@ class TransformerConfig:
             for name, on in (("an MTP module (mtp_layers)", self.mtp_layers),
                              ("hyper-connections (hc_mult)", self.hc_mult > 1),
                              ("a kda mixer", "kda" in self.mixers),
+                             ("a state-space mixer", "ssd" in self.mixers),
+                             ("a layer_pattern", bool(self.layer_pattern)),
                              ("learned positions (pos_emb)",
-                              self.pos_emb != "rope")):
+                              self.pos_emb == "learned"),
+                             ("no positions (pos_emb)",
+                              self.pos_emb == "none")):
                 if on:
                     raise NotImplementedError(
                         f"objective block_diffusion does not run with {name}")
@@ -292,7 +374,11 @@ class TransformerConfig:
 
     @property
     def layer_kinds(self) -> Tuple[str, ...]:
-        """"dense" or "moe" for each of the n_layers blocks, in order."""
+        """"dense" or "moe" for each of the n_layers blocks, in order; under
+        a ``layer_pattern`` also "none": the layer is a mixer alone."""
+        if self.layer_pattern:
+            return tuple({"E": "moe", "-": "dense"}.get(c, "none")
+                         for c in self.layer_pattern)
         if self.moe_experts <= 1:
             return ("dense",) * self.n_layers
         k = min(self.first_k_dense, self.n_layers)
@@ -300,7 +386,12 @@ class TransformerConfig:
 
     @property
     def mixers(self) -> Tuple[str, ...]:
-        """"gqa", "mla" or "kda" for each of the n_layers blocks, in order."""
+        """"gqa", "mla", "kda" or "ssd" for each of the n_layers blocks, in
+        order; under a ``layer_pattern`` also "none": the layer is a
+        feed-forward part alone."""
+        if self.layer_pattern:
+            return tuple({"M": "ssd", "*": self.attn_kind}.get(c, "none")
+                         for c in self.layer_pattern)
         G = self.layer_group_size
         if not G:
             return (self.attn_kind,) * self.n_layers
@@ -314,6 +405,18 @@ class TransformerConfig:
     @property
     def expert_d_ff(self) -> int:
         return self.moe_d_ff or self.d_ff
+
+    @property
+    def shared_d_ff(self) -> int:
+        """The width of what every token passes in an expert layer (0: no
+        shared expert)."""
+        if not self.moe_shared_experts:
+            return 0
+        return self.moe_shared_d_ff or self.expert_d_ff * self.moe_shared_experts
+
+    @property
+    def ssm_inner(self) -> int:
+        return self.ssm_heads * self.ssm_head_dim
 
     @staticmethod
     def llama2_7b() -> "TransformerConfig":
@@ -333,10 +436,13 @@ def train_flops_per_token(cfg: TransformerConfig, seq_len: int) -> float:
     forward, one multiply-add = 2 FLOPs: each layer's mixer (fused q, k, v
     and o with causal scores and values, a query seeing ``(seq_len + 1) / 2``
     keys on average; MLA's projections, with or without a q-LoRA; or a KDA
-    layer, :func:`kda_forward_flops_per_token`; the GQA head is
-    ``cfg.head_dim`` wide), each layer's SwiGLU (dense,
-    or the router, the shared experts and the expected share of the routed
-    experts held here), the hyper-connection maps and the output head (twice
+    layer, :func:`kda_forward_flops_per_token`; or a state-space layer,
+    :func:`ssd_forward_flops_per_token`; the GQA head is
+    ``cfg.head_dim`` wide; none where the layer has no mixer), each layer's
+    feed-forward part (dense, three matrices gated and two under ``relu2``,
+    or the router, the shared expert and the expected share of the routed
+    experts held here; none where the layer is a mixer alone), the
+    hyper-connection maps and the output head (twice
     with an MTP module, which also adds a block and its projection). The
     embedding is a row gather and costs none; backward is twice the forward;
     recomputation under remat is not counted. Under the block-diffusion
@@ -351,6 +457,12 @@ def train_flops_per_token(cfg: TransformerConfig, seq_len: int) -> float:
     pairs = (seq_len + cfg.bd_block) / rows if bd else (seq_len + 1) / 2
 
     def mixer(kind):
+        if kind == "none":
+            return 0
+        if kind == "ssd":
+            return ssd_forward_flops_per_token(
+                D, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups,
+                cfg.ssm_state, cfg.ssm_conv_size, cfg.ssm_chunk)
         if kind == "kda":
             from .kda import KDA_CHUNK
 
@@ -370,19 +482,39 @@ def train_flops_per_token(cfg: TransformerConfig, seq_len: int) -> float:
 
     n = cfg.hc_mult
     hyper = 2 * 2 * (n * D) * (2 * n + n * n) if n > 1 else 0
-    dense = 2 * 3 * D * cfg.d_ff
+    matrices = 3 if cfg.ffn_act == "swiglu" else 2
+    dense = 2 * matrices * D * cfg.d_ff
     held = cfg.moe_top_k * cfg.experts_held / max(cfg.moe_experts, 1)
-    expert = (2 * D * cfg.moe_experts
-              + (cfg.moe_shared_experts + held) * 2 * 3 * D * cfg.expert_d_ff)
+    expert = (2 * D * cfg.moe_experts + 2 * matrices * D * (
+        cfg.shared_d_ff + held * cfg.expert_d_ff))
     head = 2 * D * cfg.vocab_size
     kinds = cfg.layer_kinds
+    ffn = {"moe": expert, "dense": dense, "none": 0}
     forward = (sum(mixer(m) for m in cfg.mixers) + len(kinds) * hyper
-               + sum(expert if kind == "moe" else dense for kind in kinds))
+               + sum(ffn[kind] for kind in kinds))
     forward = rows * forward + head
     if cfg.mtp_layers:
         forward += (mixer(cfg.attn_kind) + hyper + 2 * 2 * D * D + head
                     + (expert if cfg.moe_experts > 1 else dense))
     return 3.0 * forward
+
+
+def ssd_forward_flops_per_token(d_model: int, heads: int, head_dim: int,
+                                groups: int, state: int, conv_size: int,
+                                chunk: int) -> float:
+    """Forward FLOPs a token of one state-space (Mamba-2) layer: the input
+    projection (``d_model`` x (2 ``heads head_dim`` + 2 ``groups state`` +
+    ``heads``)) and the output projection back, the depthwise convolution
+    over the ``heads head_dim + 2 groups state`` convolved channels, and the
+    chunked form at chunk length ``Q``: ``C B^T`` a group (``2 Q state``), the
+    pairs against the inputs a head (``2 Q head_dim``), the chunk's own state
+    and the read of the carried one (``2 head_dim state`` each a head)."""
+    inner, bc = heads * head_dim, 2 * groups * state
+    proj = 2 * d_model * (2 * inner + bc + heads) + 2 * inner * d_model
+    conv = 2 * conv_size * (inner + bc)
+    chunked = (groups * 2 * chunk * state
+               + heads * (2 * chunk * head_dim + 2 * 2 * head_dim * state))
+    return proj + conv + chunked
 
 
 def kda_forward_flops_per_token(d_model: int, heads: int, head_dim: int,
@@ -399,6 +531,18 @@ def kda_forward_flops_per_token(d_model: int, heads: int, head_dim: int,
     conv = 2 * conv_size * 3 * heads * hd
     chunked = heads * (2 * C * 5 * hd + 2 * C * C / 3 + 2 * 3 * hd * hd)
     return proj + conv + chunked
+
+
+def causal_depthwise_conv(x: jax.Array, taps: jax.Array,
+                          bias: Optional[jax.Array] = None) -> jax.Array:
+    """x: [B, L, C]; taps: [K, C], one filter a channel -> [B, L, C]: tap
+    ``j`` of ``K`` multiplies the token ``K - 1 - j`` places back, zeros
+    before the start of the sequence (the KDA and the state-space mixers'
+    short convolution)."""
+    K, L = taps.shape[0], x.shape[1]
+    padded = jnp.pad(x, ((0, 0), (K - 1, 0), (0, 0)))
+    y = sum(taps[j] * padded[:, j:j + L] for j in range(K))
+    return y if bias is None else y + bias
 
 
 def rms_norm(x: jax.Array, weight: jax.Array, eps: float) -> jax.Array:
@@ -894,9 +1038,10 @@ class Attention(nn.Module):
             with _scope("qk_norm"):
                 q = RMSNorm(cfg.norm_eps, name="q_norm")(q)
                 k = RMSNorm(cfg.norm_eps, name="k_norm")(k)
-        with _scope("rope"):
-            q = apply_rotary(q, cos, sin)
-            k = apply_rotary(k, cos, sin)
+        if cos is not None:  # pos_emb "none" hands no tables
+            with _scope("rope"):
+                q = apply_rotary(q, cos, sin)
+                k = apply_rotary(k, cos, sin)
 
         out = attend(cfg, q, k, v, mask)
         out = out.reshape(B, L, H * hd)
@@ -1035,11 +1180,7 @@ class KimiDeltaAttention(nn.Module):
         with _scope("kda"):
             qkv = jnp.einsum("bld,de->ble", x, wqkv)
             with _scope("kda_conv"):
-                # tap j of K multiplies the token K - 1 - j places back
-                taps = conv.astype(cfg.dtype)
-                padded = jnp.pad(qkv, ((0, 0), (K - 1, 0), (0, 0)))
-                qkv = nn.silu(sum(taps[j] * padded[:, j:j + L]
-                                  for j in range(K)))
+                qkv = nn.silu(causal_depthwise_conv(qkv, conv.astype(cfg.dtype)))
                 q, k, v = (a.reshape(B, L, H, hd).astype(jnp.float32)
                            for a in jnp.split(qkv, 3, axis=-1))
                 q = q * jax.lax.rsqrt((q * q).sum(-1, keepdims=True)
@@ -1064,6 +1205,112 @@ class KimiDeltaAttention(nn.Module):
                               o.astype(cfg.dtype).reshape(B, L, H * hd), wo)
 
 
+def _ssd_dt_bias_init(dt_min: float, dt_max: float, dt_floor: float):
+    """``dt_bias`` [heads], Mamba-2's: the inverse softplus of a step size
+    drawn log-uniform in [dt_min, dt_max] and floored at dt_floor, so that
+    at ``x W_in = 0`` the step sizes are those."""
+    def init(key, shape, dtype):
+        dt = jnp.exp(jax.random.uniform(
+            key, shape, minval=math.log(dt_min), maxval=math.log(dt_max)))
+        dt = jnp.maximum(dt, dt_floor)
+        return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
+
+    return init
+
+
+class Mamba2Mixer(nn.Module):
+    """A Mamba-2 state-space mixer (SSD, arXiv:2405.21060;
+    ``parallel/ssd.py`` has the recurrence and its chunked form). With ``H =
+    cfg.ssm_heads`` heads of ``P = cfg.ssm_head_dim``, ``G = cfg.ssm_groups``
+    groups of state size ``N = cfg.ssm_state``::
+
+        [z | xBC | dt] = x W_in          [H P | H P + 2 G N | H], no bias
+        xBC = silu(conv(xBC) + b)        causal depthwise, cfg.ssm_conv_size taps
+        (X, B, C) = split(xBC)           [H, P], [G, N], [G, N]
+        dt = softplus(dt + dt_bias),  A = -exp(A_log)            [H]
+        y = the recurrence's output for (X, dt, A, B, C) + D_h X
+        out = GroupRMSNorm(y * silu(z)) W_out     gate first; G groups, one weight
+
+    The layer takes no positions. The step sizes (their projection
+    accumulated and kept in float32), the decays, the state and the norm are
+    float32, the projections and the chunked form's products in
+    ``cfg.dtype``."""
+
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, x, cos=None, sin=None, mask=None):
+        from .ssd import ssd_chunked
+
+        del cos, sin  # the recurrence orders the tokens; no rotary here
+        if mask is not None:
+            raise NotImplementedError(
+                "a state-space layer takes whole sequences: no padding mask")
+        cfg = self.cfg
+        D, H, P = cfg.d_model, cfg.ssm_heads, cfg.ssm_head_dim
+        G, N, K = cfg.ssm_groups, cfg.ssm_state, cfg.ssm_conv_size
+        inner, conv_dim = cfg.ssm_inner, cfg.ssm_inner + 2 * G * N
+        init = nn.initializers.normal(0.02)
+
+        def weight(name, axes, shape, init=init, dtype=cfg.param_dtype):
+            return self.param(name, nn.with_partitioning(init, axes), shape, dtype)
+
+        w_in = weight("w_in", (EMBED, HEADS), (D, inner + conv_dim + H)
+                      ).astype(cfg.dtype)
+        w_out = weight("w_out", (HEADS, EMBED), (inner, D)).astype(cfg.dtype)
+        # torch's Conv1d default for a depthwise filter: U(+-1 / sqrt(taps)),
+        # weight and bias alike
+        conv_init = lambda key, shape, dtype: jax.random.uniform(  # noqa: E731
+            key, shape, dtype, -K ** -0.5, K ** -0.5)
+        conv = weight("conv", (None, HEADS), (K, conv_dim), conv_init)
+        conv_bias = weight("conv_bias", (HEADS,), (conv_dim,), conv_init)
+        A_log = weight(
+            "A_log", (None,), (H,), lambda key, shape, dtype: jnp.log(
+                jax.random.uniform(key, shape, dtype, 1.0, 16.0)), jnp.float32)
+        dt_bias = weight("dt_bias", (None,), (H,), _ssd_dt_bias_init(
+            cfg.ssm_dt_min, cfg.ssm_dt_max, cfg.ssm_dt_floor), jnp.float32)
+        skip = weight("D", (None,), (H,), nn.initializers.ones, jnp.float32)
+        norm = weight("norm", (None,), (inner,), nn.initializers.ones,
+                      jnp.float32)
+        B_, L, _ = x.shape
+        with _scope("mamba"):
+            with _scope("ssd_proj"):
+                zx = jnp.einsum("bld,de->ble", x, w_in[:, :inner + conv_dim])
+                z, xbc = zx[..., :inner], zx[..., inner:]
+                dt = jnp.einsum("bld,dh->blh", x, w_in[:, inner + conv_dim:],
+                                preferred_element_type=jnp.float32)
+            with _scope("ssd_conv"):
+                xbc = nn.silu(causal_depthwise_conv(
+                    xbc, conv.astype(cfg.dtype), conv_bias.astype(cfg.dtype)))
+            X = xbc[..., :inner].reshape(B_, L, H, P)
+            Bm = xbc[..., inner:inner + G * N].reshape(B_, L, G, N)
+            Cm = xbc[..., inner + G * N:].reshape(B_, L, G, N)
+            with _scope("ssd_chunk"):
+                y, _ = ssd_chunked(X, jax.nn.softplus(dt + dt_bias),
+                                   -jnp.exp(A_log), Bm, Cm, cfg.ssm_chunk,
+                                   dtype=cfg.dtype)
+                y = y + skip[:, None] * X.astype(jnp.float32)
+            with _scope("ssd_norm"):
+                # the gate first, then the norm over each group's channels
+                y = (y.reshape(B_, L, inner)
+                     * nn.silu(z.astype(jnp.float32))).reshape(B_, L, G, -1)
+                y = y * jax.lax.rsqrt(
+                    (y * y).mean(-1, keepdims=True) + cfg.norm_eps)
+                y = (y.reshape(B_, L, inner) * norm).astype(cfg.dtype)
+            with _scope("ssd_proj"):
+                return jnp.einsum("ble,ed->bld", y, w_out)
+
+
+def ffn_activation(act: str, h: jax.Array) -> jax.Array:
+    """What stands between a feed-forward's two products, dense and expert
+    alike: ``swiglu`` splits ``h`` [..., 2F] into gate and up and returns
+    ``silu(gate) * up``; ``relu2`` squares ``relu(h)`` [..., F]."""
+    if act == "relu2":
+        return jnp.square(nn.relu(h))
+    gate, up = jnp.split(h, 2, axis=-1)
+    return nn.silu(gate) * up
+
+
 class FeedForward(nn.Module):
     cfg: TransformerConfig
     d_ff: int = 0  # 0: cfg.d_ff (a shared expert gives its own width)
@@ -1073,11 +1320,12 @@ class FeedForward(nn.Module):
         cfg = self.cfg
         d_ff = self.d_ff or cfg.d_ff
         init = nn.initializers.normal(0.02)
-        # fused gate+up: one [D, 2*F] matmul
-        w_gate_up = self.param(
-            "w_gate_up",
+        gated = cfg.ffn_act == "swiglu"
+        # fused gate+up: one [D, 2*F] matmul; relu2 has no gate: [D, F]
+        w_up = self.param(
+            "w_gate_up" if gated else "w_up",
             nn.with_partitioning(init, (EMBED, MLP)),
-            (cfg.d_model, 2 * d_ff),
+            (cfg.d_model, 2 * d_ff if gated else d_ff),
             cfg.param_dtype,
         )
         w_down = self.param(
@@ -1086,9 +1334,8 @@ class FeedForward(nn.Module):
             (d_ff, cfg.d_model),
             cfg.param_dtype,
         )
-        gu = jnp.einsum("bld,df->blf", x, w_gate_up.astype(cfg.dtype))
-        gate, up = jnp.split(gu, 2, axis=-1)
-        h = nn.silu(gate) * up
+        h = ffn_activation(
+            cfg.ffn_act, jnp.einsum("bld,df->blf", x, w_up.astype(cfg.dtype)))
         return jnp.einsum("blf,fd->bld", h, w_down.astype(cfg.dtype))
 
 
@@ -1174,33 +1421,37 @@ class HyperConnection(nn.Module):
 
 
 class Block(nn.Module):
-    """One decoder block: a mixer (attention of the configuration's kind, or
-    the linear mixer), then a feed-forward or expert layer,
+    """One decoder block: a mixer (attention of the configuration's kind, a
+    linear or a state-space mixer), then a feed-forward or expert layer, or
+    one of the two alone (``cfg.layer_pattern``),
     each a pre-norm residual sublayer (``x + F(norm(x))``), or, where
     ``cfg.hc_mult`` > 1, a hyper-connected one over the residual streams
     (``x``: [B, n, L, C])."""
 
     cfg: TransformerConfig
-    # None: an expert layer wherever the configuration has experts (the
-    # pipeline's and the Switch stacks' uniform blocks); the Transformer's
-    # layer list says it per layer
-    moe: Optional[bool] = None
-    # None: ``cfg.attn_kind`` (uniform blocks, the MTP module's block); the
-    # Transformer's ``cfg.mixers`` says it per layer
+    # "dense", "moe" or "none" (no feed-forward part). None: an expert layer
+    # wherever the configuration has experts (the pipeline's and the Switch
+    # stacks' uniform blocks, the MTP module's block); the Transformer's
+    # ``cfg.layer_kinds`` says it per layer
+    ffn: Optional[str] = None
+    # a mixer's name or "none" (no mixer). None: ``cfg.attn_kind`` (uniform
+    # blocks, the MTP module's block); the Transformer's ``cfg.mixers`` says
+    # it per layer
     mixer: Optional[str] = None
 
     @nn.compact
     def __call__(self, x, cos, sin, mask=None):
         cfg = self.cfg
-        moe = cfg.moe_experts > 1 if self.moe is None else self.moe
-        attn_cls = {"gqa": Attention, "mla": LatentAttention,
-                    "kda": KimiDeltaAttention}[self.mixer or cfg.attn_kind]
+        ffn = self.ffn or ("moe" if cfg.moe_experts > 1 else "dense")
+        mixer = self.mixer or cfg.attn_kind
 
         def attention(h):
+            attn_cls = {"gqa": Attention, "mla": LatentAttention,
+                        "kda": KimiDeltaAttention, "ssd": Mamba2Mixer}[mixer]
             return attn_cls(cfg)(h, cos, sin, mask)
 
         def feed_forward(h):
-            if not moe:
+            if ffn == "dense":
                 return FeedForward(cfg)(h)
             from .moe import MoEFeedForward
 
@@ -1210,7 +1461,9 @@ class Block(nn.Module):
             self.sow("losses", "moe_aux", aux)
             return y
 
-        for sublayer in (attention, feed_forward):
+        sublayers = ((attention,) if mixer != "none" else ()) + (
+            (feed_forward,) if ffn != "none" else ())
+        for sublayer in sublayers:
             if cfg.hc_mult > 1:
                 pre, post, res = HyperConnection(cfg)(x)
                 y = sublayer(RMSNorm(cfg.norm_eps)(streams_read(x, pre)))
@@ -1261,7 +1514,7 @@ class MultiTokenPrediction(nn.Module):
                              RMSNorm(cfg.norm_eps, name="h_norm")(h)], axis=-1)
         z = jnp.einsum("ble,ed->bld", z, w_eh.astype(cfg.dtype))
         z = _constrain_batch_activations(_to_streams(z, cfg.hc_mult))
-        z = _block_class(cfg)(cfg, moe=cfg.moe_experts > 1)(z, cos, sin, mask)
+        z = _block_class(cfg)(cfg)(z, cos, sin, mask)
         if cfg.hc_mult > 1:
             z = z.sum(axis=1)
         return RMSNorm(cfg.norm_eps, name="final_norm")(z)
@@ -1334,6 +1587,8 @@ class Transformer(nn.Module):
                             jnp.float32)
             with _scope("rope"):
                 cos, sin = jnp.cos(ang), jnp.sin(ang)
+        elif cfg.pos_emb == "none":
+            cos = sin = None  # attention runs position-free: no rotation
         else:
             with _scope("rope"):
                 cos, sin = rope_tables(cfg, positions)
@@ -1343,8 +1598,7 @@ class Transformer(nn.Module):
         block_cls = _block_class(cfg)
         for kind, mixer in zip(cfg.layer_kinds, cfg.mixers, strict=True):
             x = _constrain_batch_activations(
-                block_cls(cfg, moe=kind == "moe", mixer=mixer)(
-                    x, cos, sin, mask)
+                block_cls(cfg, ffn=kind, mixer=mixer)(x, cos, sin, mask)
             )
         if cfg.hc_mult > 1:
             x = x.sum(axis=1)  # the streams are summed before the final norm

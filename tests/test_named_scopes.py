@@ -81,7 +81,8 @@ def test_train_step_names_what_a_configuration_adds_to_the_blocks():
         first_k_dense=1, mtp_layers=1))
     kda = {"kda", "kda_conv", "kda_gate", "kda_chunk"}  # no such layer here
     bd = {"qk_norm", "bd_noise"}   # nor q/k norms or the block-diffusion draw
-    assert set(scopes.TRAIN_STEP) - names == {"grad_accum"} | kda | bd
+    ssd = {"mamba", "ssd_proj", "ssd_conv", "ssd_chunk", "ssd_norm"}
+    assert set(scopes.TRAIN_STEP) - names == {"grad_accum"} | kda | bd | ssd
     assert {"LatentAttention_0", "HyperConnection_0", "MoEFeedForward_0",
             "FeedForward_0", "mtp"} <= names
 
@@ -106,6 +107,25 @@ def test_train_step_names_the_block_diffusion_draw_and_the_qk_norms():
         moe_capacity_factor=0.0, moe_d_ff=32))
     assert {"bd_noise", "qk_norm", "moe_route", "moe_experts", "loss",
             "Attention_0", "q_norm", "k_norm"} <= names
+
+
+def test_train_step_names_the_state_space_mixer_and_its_parts():
+    """A layer list given as a pattern, one sublayer a layer: the mixer's
+    scopes under its module, the expert layer and attention without a rotation
+    beside it, and no stale name in the compiled step's map."""
+    lowered = lowered_step(
+        1, n_layers=4, n_kv_heads=2, pos_emb="none", layer_pattern="ME*-",
+        ssm_heads=4, ssm_head_dim=16, ssm_groups=2, ssm_state=16, ssm_chunk=16,
+        ffn_act="relu2", moe_experts=4, moe_top_k=2, moe_router="sigmoid",
+        moe_capacity_factor=0.0, moe_d_ff=32, moe_shared_experts=1,
+        moe_shared_d_ff=48)
+    names = scope_names(lowered)
+    assert {"mamba", "ssd_proj", "ssd_conv", "ssd_chunk", "ssd_norm",
+            "Mamba2Mixer_0", "MoEFeedForward_0", "Attention_0",
+            "FeedForward_0", "moe_route", "shared_expert"} <= names
+    assert "rope" not in names
+    # each block holds one norm: one sublayer a layer
+    assert "RMSNorm_1" not in names
 
 
 def test_train_step_learned_positions_are_embed_and_rope():

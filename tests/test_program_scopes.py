@@ -169,7 +169,16 @@ def instruction_names(text: str) -> set:
     (EXPERTS, {"optimizer", "loss", "moe_experts", "moe_route",
                "shared_expert"}, {"fwd", "bwd"}),
     (dict(remat=True), {"optimizer", "loss", "rope"}, {"fwd", "bwd", "remat"}),
-], ids=["dense", "experts", "remat"])
+    # a layer list given as a pattern, one sublayer a layer (ISSUE 38): the
+    # state-space mixer's scopes, every instruction of the step still mapped
+    (dict(remat=True, n_layers=3, pos_emb="none", layer_pattern="ME*",
+          ssm_heads=4, ssm_head_dim=16, ssm_groups=2, ssm_state=16,
+          ssm_chunk=16, ffn_act="relu2", moe_experts=4, moe_top_k=2,
+          moe_router="sigmoid", moe_capacity_factor=0.0, moe_d_ff=32,
+          moe_shared_experts=1, moe_shared_d_ff=48),
+     {"mamba", "ssd_proj", "ssd_conv", "ssd_chunk", "ssd_norm", "Mamba2Mixer",
+      "moe_experts", "shared_expert"}, {"fwd", "bwd", "remat"}),
+], ids=["dense", "experts", "remat", "state_space"])
 def test_compiled_step_maps_every_instruction(config, holds, passes):
     cfg = dataclasses.replace(TransformerConfig.tiny(), **config)
     trainer = CheetahTrainer(cfg, make_mesh(None))
