@@ -42,7 +42,7 @@ from .sharding import (
     replicated,
     unbox,
 )
-from .ssd import SSD_PATH
+from .ssd import scan_path as ssd_scan_path
 from .transformer import (EMBED, VOCAB, Transformer, TransformerConfig,
                           splash_forward_tiles)
 
@@ -328,8 +328,9 @@ class CheetahTrainer:
         self.kda_path = scan_path(
             cfg.n_heads, cfg.kda_head_dim, cfg.kda_head_dim, cfg.max_seq_len,
             KDA_CHUNK, mesh, seq_sharded) if "kda" in cfg.mixers else ""
-        # the state-space layers' sizes and the form their recurrence takes
-        # ({} without such a layer)
+        # the state-space layers' sizes and the form their recurrence takes,
+        # as parallel/ssd.py decides it when the step is traced at sequences
+        # of cfg.max_seq_len ({} without such a layer)
         self.ssd = {}
         if "ssd" in cfg.mixers:
             if seq_sharded:
@@ -338,7 +339,10 @@ class CheetahTrainer:
                     "sequence: it does not run under sequence sharding yet")
             self.ssd = {"heads": cfg.ssm_heads, "head_dim": cfg.ssm_head_dim,
                         "groups": cfg.ssm_groups, "state": cfg.ssm_state,
-                        "chunk": cfg.ssm_chunk, "path": SSD_PATH}
+                        "chunk": cfg.ssm_chunk, "path": ssd_scan_path(
+                            cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups,
+                            cfg.ssm_state, cfg.max_seq_len, cfg.ssm_chunk,
+                            mesh)}
 
         # the attention mask of a step at sequences of cfg.max_seq_len, by
         # name, with the share of the [rows, rows] pairs it lets through
@@ -427,6 +431,13 @@ class CheetahTrainer:
                 "parallel/kda.scan_path", dict(self.mesh.shape),
                 self.seq_sharded, self.cfg.n_heads, self.cfg.kda_head_dim,
                 self.cfg.max_seq_len, KDA_CHUNK)
+        if (self.ssd.get("path") == "xla"
+                and jax.devices()[0].platform == "tpu"):
+            logger.warning(
+                "cheetah init: the state-space layers run the XLA form of the "
+                "chunked recurrence, not the Pallas kernels (mesh %s, sizes "
+                "%s, %d tokens): see parallel/ssd.scan_path",
+                dict(self.mesh.shape), self.ssd, self.cfg.max_seq_len)
         mlops.log_cheetah_init(
             {k: int(v) for k, v in self.mesh.shape.items()},
             self.loss_head_gathers_per_step,

@@ -1288,8 +1288,7 @@ class Mamba2Mixer(nn.Module):
             with _scope("ssd_chunk"):
                 y, _ = ssd_chunked(X, jax.nn.softplus(dt + dt_bias),
                                    -jnp.exp(A_log), Bm, Cm, cfg.ssm_chunk,
-                                   dtype=cfg.dtype)
-                y = y + skip[:, None] * X.astype(jnp.float32)
+                                   dtype=cfg.dtype, skip=skip)
             with _scope("ssd_norm"):
                 # the gate first, then the norm over each group's channels
                 y = (y.reshape(B_, L, inner)
