@@ -104,6 +104,14 @@ class PipelineCheetah:
                 "PipelineCheetah stacks one block: a layer list given as a "
                 "pattern (layer_pattern), one sublayer a layer, does not run "
                 "under it")
+        if cfg.layer_types:
+            raise NotImplementedError(
+                "PipelineCheetah stacks one block: a mixer chosen per layer "
+                "(layer_types) does not run under it")
+        if cfg.tie_embeddings:
+            raise NotImplementedError(
+                "PipelineCheetah keeps its own head: a head tied to the "
+                "embedding (tie_embeddings) does not run under it")
         self.schedule = schedule
         self.cfg = cfg
         self.mesh = mesh

@@ -44,7 +44,7 @@ from .sharding import (
 )
 from .ssd import scan_path as ssd_scan_path
 from .transformer import (EMBED, VOCAB, Transformer, TransformerConfig,
-                          splash_forward_tiles)
+                          output_head, splash_forward_tiles)
 
 logger = logging.getLogger(__name__)
 
@@ -451,6 +451,7 @@ class CheetahTrainer:
             attn_mask=self.attn_mask, head_dim=int(self.cfg.head_dim),
             layer_pattern=self.cfg.layer_pattern, ssd=self.ssd,
             ffn_act=self.cfg.ffn_act,
+            tie_embeddings=bool(self.cfg.tie_embeddings),
         )
         # step must be committed to the mesh (replicated) — a default-device
         # scalar breaks jit after checkpoint restore (mixed device sets)
@@ -516,7 +517,7 @@ class CheetahTrainer:
             hidden, mtp_hidden = out if mtp else (out, None)
             with _scope("loss"):
                 loss = lm_loss_chunked(
-                    hidden, params["w_lm_head"], tokens, mask,
+                    hidden, output_head(cfg, params), tokens, mask,
                     self.loss_chunk
                 )
         else:
@@ -534,12 +535,13 @@ class CheetahTrainer:
                 nxt_mask = jnp.roll(mask, -1, axis=1).at[:, -1].set(0)
                 if self.loss_chunk > 0:
                     mtp_loss = lm_loss_chunked(
-                        mtp_hidden, params["w_lm_head"], nxt, nxt_mask,
+                        mtp_hidden, output_head(cfg, params), nxt, nxt_mask,
                         self.loss_chunk)
                 else:
                     mtp_loss = lm_loss(
                         jnp.einsum("bld,dv->blv", mtp_hidden,
-                                   params["w_lm_head"].astype(mtp_hidden.dtype)
+                                   output_head(cfg, params).astype(
+                                       mtp_hidden.dtype)
                                    ).astype(jnp.float32), nxt, nxt_mask)
                 loss = loss + cfg.mtp_weight * mtp_loss
         return self._with_aux(loss, var_col), var_col.get("moe_stats", {})
@@ -571,8 +573,8 @@ class CheetahTrainer:
         hidden, var_col = out if mutable else (out, {})
         with _scope("loss"):
             loss = block_diffusion.loss(
-                hidden, variables["params"]["w_lm_head"], tokens, coefficient,
-                self.loss_chunk)
+                hidden, output_head(cfg, variables["params"]), tokens,
+                coefficient, self.loss_chunk)
         stats = dict(var_col.get("moe_stats", {}))
         stats["bd"] = {"masked_tokens": masked.sum().astype(jnp.int32),
                        "weight_sum": (masked * weight).sum()}

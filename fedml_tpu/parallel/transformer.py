@@ -29,8 +29,11 @@ objective's doubled sequence under its structured attention mask
 data (``cfg.layer_pattern``) whose layers are one sublayer alone, a
 state-space mixer (``Mamba2Mixer``, ``parallel/ssd.py``), an expert layer, a
 dense feed-forward or attention; a squared-ReLU feed-forward with no gate
-matrix (``cfg.ffn_act``). A configuration that names none of them builds the
-block it always built.
+matrix (``cfg.ffn_act``); a mixer list under the published layer names
+(``cfg.layer_types``) whose layers are a mixer and a feed-forward part, the
+mixer LFM2's gated short convolution (``ShortConvMixer``) or attention; an
+output head tied to the embedding (``cfg.tie_embeddings``). A configuration
+that names none of them builds the block it always built.
 """
 
 from __future__ import annotations
@@ -64,6 +67,10 @@ LORA = "lora"  # the low-rank side of a latent projection: never sharded
 
 # cfg.layer_pattern's letters: one sublayer a layer
 _PATTERN_LETTERS = "M*E-"
+# cfg.layer_types' names (LFM2's ``layer_types``)
+_LAYER_TYPES = ("conv", "full_attention")
+# ShortConvMixer's taps a channel: LFM2's conv_L_cache
+SHORTCONV_TAPS = 3
 
 
 @dataclasses.dataclass(frozen=True)
@@ -160,6 +167,11 @@ class TransformerConfig:
     # two rules above (first_k_dense, layer_group_size), every layer a mixer
     # and a feed-forward part
     layer_pattern: str = ""
+    # the mixers as data under the published names, one a layer, each layer
+    # a mixer and a feed-forward part: "conv" a gated short convolution
+    # (ShortConvMixer), "full_attention" attention of ``attn_kind``;
+    # first_k_dense gives the feed-forward parts. (): the rules above
+    layer_types: Tuple[str, ...] = ()
     # Mamba2Mixer: ssm_heads heads of ssm_head_dim, B and C in ssm_groups
     # groups of ssm_state, a causal depthwise convolution of ssm_conv_size
     # taps, the recurrence in chunks of ssm_chunk; dt_bias drawn so that the
@@ -224,6 +236,9 @@ class TransformerConfig:
     # (Qwen3's q_norm / k_norm)
     attn_head_dim: int = 0
     qk_norm: bool = False
+    # the output head is the embedding's transpose: one [vocab, d_model]
+    # table, no w_lm_head (``output_head``)
+    tie_embeddings: bool = False
     # -- objective -----------------------------------------------------------
     # "next_token": causal cross entropy. "block_diffusion"
     # (parallel/block_diffusion.py): the model reads the 2L rows
@@ -265,6 +280,22 @@ class TransformerConfig:
                     "and layer_group_size are the other spelling")
             if "E" in self.layer_pattern and self.moe_experts <= 1:
                 raise ValueError("an E layer needs moe_experts > 1")
+        if self.layer_types:
+            unknown = sorted(set(self.layer_types) - set(_LAYER_TYPES))
+            if unknown:
+                raise ValueError(
+                    f"layer_types names {list(_LAYER_TYPES)}, got {unknown}")
+            if len(self.layer_types) != self.n_layers:
+                raise ValueError(
+                    f"layer_types names {len(self.layer_types)} layers, "
+                    f"n_layers is {self.n_layers}")
+            if self.layer_pattern or self.layer_group_size:
+                raise ValueError(
+                    "layer_types is the mixers' list: layer_pattern and "
+                    "layer_group_size are the other spellings")
+            if self.attn_kind == "kda":
+                raise ValueError("a full_attention layer runs attn_kind: "
+                                 "name the attention there (gqa|mla)")
         if "ssd" in self.mixers:
             H, G = self.ssm_heads, self.ssm_groups
             if not (H > 0 and self.ssm_head_dim > 0 and self.ssm_state > 0
@@ -341,6 +372,8 @@ class TransformerConfig:
                              ("hyper-connections (hc_mult)", self.hc_mult > 1),
                              ("a kda mixer", "kda" in self.mixers),
                              ("a state-space mixer", "ssd" in self.mixers),
+                             ("a short-convolution mixer",
+                              "shortconv" in self.mixers),
                              ("a layer_pattern", bool(self.layer_pattern)),
                              ("learned positions (pos_emb)",
                               self.pos_emb == "learned"),
@@ -386,9 +419,12 @@ class TransformerConfig:
 
     @property
     def mixers(self) -> Tuple[str, ...]:
-        """"gqa", "mla", "kda" or "ssd" for each of the n_layers blocks, in
-        order; under a ``layer_pattern`` also "none": the layer is a
-        feed-forward part alone."""
+        """"gqa", "mla", "kda", "ssd" or "shortconv" for each of the n_layers
+        blocks, in order; under a ``layer_pattern`` also "none": the layer is
+        a feed-forward part alone."""
+        if self.layer_types:
+            return tuple("shortconv" if t == "conv" else self.attn_kind
+                         for t in self.layer_types)
         if self.layer_pattern:
             return tuple({"M": "ssd", "*": self.attn_kind}.get(c, "none")
                          for c in self.layer_pattern)
@@ -459,6 +495,8 @@ def train_flops_per_token(cfg: TransformerConfig, seq_len: int) -> float:
     def mixer(kind):
         if kind == "none":
             return 0
+        if kind == "shortconv":
+            return shortconv_forward_flops_per_token(D, SHORTCONV_TAPS)
         if kind == "ssd":
             return ssd_forward_flops_per_token(
                 D, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups,
@@ -517,6 +555,15 @@ def ssd_forward_flops_per_token(d_model: int, heads: int, head_dim: int,
     return proj + conv + chunked
 
 
+def shortconv_forward_flops_per_token(d_model: int, taps: int) -> float:
+    """Forward FLOPs a token of one gated short-convolution layer: the input
+    projection (``d_model`` x 3 ``d_model``) and the output projection back,
+    ``taps`` multiply-adds a channel of the depthwise convolution, and the two
+    gates' products (one multiply a channel each)."""
+    return (2 * d_model * 3 * d_model + 2 * d_model * d_model
+            + 2 * taps * d_model + 2 * d_model)
+
+
 def kda_forward_flops_per_token(d_model: int, heads: int, head_dim: int,
                                 conv_size: int, chunk: int) -> float:
     """Forward FLOPs a token of one KDA layer: the projections (q, k, v and
@@ -538,7 +585,7 @@ def causal_depthwise_conv(x: jax.Array, taps: jax.Array,
     """x: [B, L, C]; taps: [K, C], one filter a channel -> [B, L, C]: tap
     ``j`` of ``K`` multiplies the token ``K - 1 - j`` places back, zeros
     before the start of the sequence (the KDA and the state-space mixers'
-    short convolution)."""
+    short convolution, and the gated short convolution's)."""
     K, L = taps.shape[0], x.shape[1]
     padded = jnp.pad(x, ((0, 0), (K - 1, 0), (0, 0)))
     y = sum(taps[j] * padded[:, j:j + L] for j in range(K))
@@ -1300,6 +1347,50 @@ class Mamba2Mixer(nn.Module):
                 return jnp.einsum("ble,ed->bld", y, w_out)
 
 
+class ShortConvMixer(nn.Module):
+    """LFM2's gated short convolution (``Lfm2ShortConv``), with ``K =
+    SHORTCONV_TAPS`` taps a channel::
+
+        [B | C | x] = h W_in             [D, 3 D], no bias; B, C, x in that order
+        v = conv(B * x)                  causal depthwise, no bias
+        y = (C * v) W_out                [D, D], no bias
+
+    There is no activation anywhere, and the layer takes no positions. The
+    gates and the convolution run in ``cfg.dtype``."""
+
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, x, cos=None, sin=None, mask=None):
+        del cos, sin  # the convolution orders the tokens; no rotary here
+        if mask is not None:
+            raise NotImplementedError(
+                "a short-convolution layer takes whole sequences: no padding "
+                "mask")
+        cfg = self.cfg
+        D, K = cfg.d_model, SHORTCONV_TAPS
+        init = nn.initializers.normal(0.02)
+
+        def weight(name, axes, shape, init=init):
+            return self.param(name, nn.with_partitioning(init, axes), shape,
+                              cfg.param_dtype).astype(cfg.dtype)
+
+        w_in = weight("w_in", (EMBED, HEADS), (D, 3 * D))
+        w_out = weight("w_out", (HEADS, EMBED), (D, D))
+        # torch's Conv1d default for a depthwise filter: U(+-1 / sqrt(taps))
+        conv = weight("conv", (None, HEADS), (K, D),
+                      lambda key, shape, dtype: jax.random.uniform(
+                          key, shape, dtype, -K ** -0.5, K ** -0.5))
+        with _scope("shortconv"):
+            with _scope("shortconv_proj"):
+                bcx = jnp.einsum("bld,de->ble", x, w_in)
+            with _scope("shortconv_conv"):
+                b, c, v = jnp.split(bcx, 3, axis=-1)
+                y = c * causal_depthwise_conv(b * v, conv)
+            with _scope("shortconv_proj"):
+                return jnp.einsum("ble,ed->bld", y, w_out)
+
+
 def ffn_activation(act: str, h: jax.Array) -> jax.Array:
     """What stands between a feed-forward's two products, dense and expert
     alike: ``swiglu`` splits ``h`` [..., 2F] into gate and up and returns
@@ -1421,8 +1512,8 @@ class HyperConnection(nn.Module):
 
 class Block(nn.Module):
     """One decoder block: a mixer (attention of the configuration's kind, a
-    linear or a state-space mixer), then a feed-forward or expert layer, or
-    one of the two alone (``cfg.layer_pattern``),
+    linear, a state-space or a short-convolution mixer), then a feed-forward
+    or expert layer, or one of the two alone (``cfg.layer_pattern``),
     each a pre-norm residual sublayer (``x + F(norm(x))``), or, where
     ``cfg.hc_mult`` > 1, a hyper-connected one over the residual streams
     (``x``: [B, n, L, C])."""
@@ -1446,7 +1537,8 @@ class Block(nn.Module):
 
         def attention(h):
             attn_cls = {"gqa": Attention, "mla": LatentAttention,
-                        "kda": KimiDeltaAttention, "ssd": Mamba2Mixer}[mixer]
+                        "kda": KimiDeltaAttention, "ssd": Mamba2Mixer,
+                        "shortconv": ShortConvMixer}[mixer]
             return attn_cls(cfg)(h, cos, sin, mask)
 
         def feed_forward(h):
@@ -1517,6 +1609,14 @@ class MultiTokenPrediction(nn.Module):
         if cfg.hc_mult > 1:
             z = z.sum(axis=1)
         return RMSNorm(cfg.norm_eps, name="final_norm")(z)
+
+
+def output_head(cfg: TransformerConfig, params) -> jax.Array:
+    """The [d_model, vocab] output head of a :class:`Transformer`'s
+    parameters: the embedding's transpose under ``cfg.tie_embeddings`` (its
+    gradient then reaches the embedding beside the lookup's), else
+    ``w_lm_head``."""
+    return params["embed"].T if cfg.tie_embeddings else params["w_lm_head"]
 
 
 class Transformer(nn.Module):
@@ -1624,8 +1724,9 @@ class Transformer(nn.Module):
             # never CREATE the [d_model, vocab] LM head — at 7B scale a
             # ~131M-param dead weight every FL round would otherwise ship
             return (x, mtp_hidden) if return_mtp else x
-        # tied-untied choice: separate output head (Llama unties)
-        w_out = self.param(
+        # tied-untied choice: a separate output head (Llama unties), or the
+        # embedding's transpose (output_head)
+        w_out = embed.T if cfg.tie_embeddings else self.param(
             "w_lm_head",
             nn.with_partitioning(nn.initializers.normal(0.02), (EMBED, VOCAB)),
             (cfg.d_model, cfg.vocab_size),

@@ -82,7 +82,9 @@ def test_train_step_names_what_a_configuration_adds_to_the_blocks():
     kda = {"kda", "kda_conv", "kda_gate", "kda_chunk"}  # no such layer here
     bd = {"qk_norm", "bd_noise"}   # nor q/k norms or the block-diffusion draw
     ssd = {"mamba", "ssd_proj", "ssd_conv", "ssd_chunk", "ssd_norm"}
-    assert set(scopes.TRAIN_STEP) - names == {"grad_accum"} | kda | bd | ssd
+    conv = {"shortconv", "shortconv_proj", "shortconv_conv"}
+    assert set(scopes.TRAIN_STEP) - names == (
+        {"grad_accum"} | kda | bd | ssd | conv)
     assert {"LatentAttention_0", "HyperConnection_0", "MoEFeedForward_0",
             "FeedForward_0", "mtp"} <= names
 
@@ -126,6 +128,22 @@ def test_train_step_names_the_state_space_mixer_and_its_parts():
     assert "rope" not in names
     # each block holds one norm: one sublayer a layer
     assert "RMSNorm_1" not in names
+
+
+def test_train_step_names_the_short_convolution_and_its_parts():
+    """A mixer list under the published names (ISSUE 40): the gated short
+    convolution's scopes under its module, attention with q/k norms beside
+    it, and the tied head's loss."""
+    lowered = lowered_step(
+        1, n_layers=2, layer_types=("conv", "full_attention"),
+        first_k_dense=1, qk_norm=True, tie_embeddings=True, moe_experts=4,
+        moe_top_k=2, moe_router="sigmoid", moe_capacity_factor=0.0,
+        moe_d_ff=32)
+    names = scope_names(lowered)
+    assert {"shortconv", "shortconv_proj", "shortconv_conv",
+            "ShortConvMixer_0", "Attention_0", "qk_norm", "rope",
+            "moe_route", "loss"} <= names
+    assert "mamba" not in names and "shared_expert" not in names
 
 
 def test_train_step_learned_positions_are_embed_and_rope():

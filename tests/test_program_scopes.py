@@ -178,7 +178,14 @@ def instruction_names(text: str) -> set:
           moe_shared_experts=1, moe_shared_d_ff=48),
      {"mamba", "ssd_proj", "ssd_conv", "ssd_chunk", "ssd_norm", "Mamba2Mixer",
       "moe_experts", "shared_expert"}, {"fwd", "bwd", "remat"}),
-], ids=["dense", "experts", "remat", "state_space"])
+    # a mixer list under the published names and a tied head (ISSUE 40)
+    (dict(remat=True, n_layers=2, layer_types=("conv", "full_attention"),
+          first_k_dense=1, qk_norm=True, tie_embeddings=True, moe_experts=4,
+          moe_top_k=2, moe_router="sigmoid", moe_capacity_factor=0.0,
+          moe_d_ff=32),
+     {"shortconv", "shortconv_proj", "shortconv_conv", "ShortConvMixer",
+      "qk_norm", "moe_experts"}, {"fwd", "bwd", "remat"}),
+], ids=["dense", "experts", "remat", "state_space", "short_conv"])
 def test_compiled_step_maps_every_instruction(config, holds, passes):
     cfg = dataclasses.replace(TransformerConfig.tiny(), **config)
     trainer = CheetahTrainer(cfg, make_mesh(None))
