@@ -200,7 +200,8 @@ def log_cheetah_init(mesh: Dict[str, int],
                      mixers: str, kda_path: str, kda_chunk: int,
                      objective: str, bd_block: int, attn_mask: Dict[str, Any],
                      head_dim: int, layer_pattern: str, ssd: Dict[str, Any],
-                     ffn_act: str, tie_embeddings: bool) -> None:
+                     ffn_act: str, tie_embeddings: bool,
+                     experts: Dict[str, Any]) -> None:
     """What a Cheetah trainer decided from its mesh and its configuration at
     trace time, once a run (docs/telemetry.md, ``cheetah_init``)."""
     _emit({"kind": "cheetah_init", "mesh": mesh,
@@ -211,7 +212,7 @@ def log_cheetah_init(mesh: Dict[str, int],
            "objective": objective, "bd_block": bd_block,
            "attn_mask": attn_mask, "head_dim": head_dim,
            "layer_pattern": layer_pattern, "ssd": ssd, "ffn_act": ffn_act,
-           "tie_embeddings": tie_embeddings})
+           "tie_embeddings": tie_embeddings, "experts": experts})
 
 
 def log_training_status(status: str) -> None:

@@ -39,7 +39,8 @@ TRAIN_STEP_BLOCKS: Tuple[str, ...] = (
     "mhc",            # hyper-connection maps, stream reads and writes
     "sinkhorn",       # inside mhc: the Sinkhorn-Knopp sweeps of H_res
     "moe_route",      # router scores, top-k, the sort by expert
-    "moe_experts",    # dispatch gather, grouped products, combine
+    "moe_experts",    # dispatch gather, the activation, combine
+    "moe_products",   # the experts' grouped products (expert_gmm_* kernels)
     "shared_expert",  # the expert every token passes (FeedForward "shared")
     "mtp",            # the multi-token-prediction module and its loss
     "kda",            # KimiDeltaAttention_N: the linear-attention mixer

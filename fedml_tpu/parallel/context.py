@@ -13,6 +13,7 @@ import contextlib
 from dataclasses import dataclass
 from typing import Optional
 
+import jax
 from jax.sharding import Mesh
 
 from .. import constants
@@ -76,3 +77,12 @@ def mesh_context(mesh: Optional[Mesh]):
 
 def get_mesh_context() -> Optional[Mesh]:
     return _MESH.get()
+
+
+def one_tpu_device(mesh: Optional[Mesh]) -> bool:
+    """Whether a program traced under ``mesh`` (the ambient one, or a
+    trainer's) runs on one TPU device: no mesh, or one of a single device,
+    on the TPU back-end. The one condition every kernel family's path
+    function shares (Mosaic kernels are not partitioned by pjit, and no
+    wrapper shards the newer ones yet)."""
+    return (mesh is None or mesh.size == 1) and jax.devices()[0].platform == "tpu"

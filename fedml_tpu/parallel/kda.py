@@ -77,7 +77,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh
 
-from .context import get_mesh_context, get_seq_context
+from .context import get_mesh_context, get_seq_context, one_tpu_device
 
 # tokens in a chunk. 64 is what ``benchmark/configs/ling3.0_flash_ep64_l7.json``
 # states and what the cell's FLOP and byte counts are taken at. With the
@@ -112,8 +112,7 @@ def scan_path(heads: int, dk: int, dv: int, seq_len: int, chunk: int,
              and chunk % SUB == 0
              and heads % HEADS_PER_STEP == 0
              and n_chunks % CHUNKS_PER_STEP == 0
-             and (mesh is None or mesh.size == 1)
-             and jax.devices()[0].platform == "tpu")
+             and one_tpu_device(mesh))
     return "fused" if fused else "xla"
 
 

@@ -28,7 +28,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh
 
 from ..core.mlops.scopes import train_step_scope as _scope
-from .context import get_mesh_context
+from .context import get_mesh_context, one_tpu_device
 
 _LANES = 128
 # bytes of one grid step's blocks (all streams in, all streams out): large
@@ -46,8 +46,7 @@ def backward_path(n: int, channels: int, mesh: Optional[Mesh]) -> str:
     yet). ``mesh`` is the ambient one (``context.get_mesh_context()``), or
     the trainer's."""
     fused = (n > 1 and channels % _LANES == 0
-             and (mesh is None or mesh.size == 1)
-             and jax.devices()[0].platform == "tpu")
+             and one_tpu_device(mesh))
     return "fused" if fused else "xla"
 
 

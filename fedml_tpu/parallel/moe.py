@@ -25,9 +25,11 @@ constant only). One layer, a routing rule and a capacity rule:
   over-capacity assignments dropped (they pass through the residual
   unchanged), second choices before first and later tokens before earlier.
   0: no capacity and no drop: the assignments are sorted by expert and the
-  experts run as grouped matrix products (``jax.lax.ragged_dot``, on a TPU a
-  Mosaic kernel whose grid follows the group sizes) over the tokens that
-  arrived; the buffer is the worst case ``k * T`` rows, the work is not.
+  experts run as grouped matrix products over the tokens that arrived (on
+  one TPU device the kernels of ``parallel/expert_gmm.py``, whose grid visits
+  each held expert's row tiles with its matrix in VMEM; elsewhere
+  ``jax.lax.ragged_dot``); the buffer is the worst case ``k * T`` rows, the
+  work is not.
 - **the experts held** (``cfg.moe_experts_held``, ``cfg.moe_expert_offset``):
   expert parallelism seen from one chip. The router keeps its width and
   routes over all ``cfg.moe_experts``; this program holds the weights of
@@ -62,6 +64,7 @@ The layer sows what a step reports into the ``moe_stats`` collection:
 
 from __future__ import annotations
 
+import functools
 from typing import Tuple
 
 import jax
@@ -69,6 +72,8 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from ..core.mlops.scopes import train_step_scope as _scope
+from . import expert_gmm
+from .context import get_mesh_context
 from .transformer import (EMBED, MLP, FeedForward, TransformerConfig,
                           ffn_activation)
 
@@ -195,10 +200,12 @@ class MoEFeedForward(nn.Module):
 
         with _scope("moe_experts"):
             rows = _dispatch(xt.astype(cfg.dtype), token, src)       # [kT, D]
-            if slot is None:
-                out = _grouped_experts(cfg, rows, w_up, w_down, counts)
-            else:
+            if slot is not None:
                 out = _slotted_experts(cfg, rows, w_up, w_down, slot)
+        if slot is None:
+            # the products beside dispatch and combine, not inside them
+            out = _grouped_experts(cfg, rows, w_up, w_down, counts)
+        with _scope("moe_experts"):
             # each choice's row back at its token, weighed by its gate;
             # dropped and absent assignments add nothing
             # in the result's own shape: a reshape after the sum over the
@@ -342,10 +349,25 @@ def _grouped_experts(cfg, rows, w_up, w_down, counts):
     """No capacity: the sorted rows, held experts first, go through two
     grouped products whose groups are the experts' arrivals. Rows past the
     last group (assignments to experts held elsewhere) belong to no group:
-    the kernel computes nothing for them, and :func:`_combine` never reads
-    what it leaves there."""
-    h = jax.lax.ragged_dot(rows, w_up.astype(cfg.dtype), counts,
-                           preferred_element_type=cfg.dtype)
-    return jax.lax.ragged_dot(ffn_activation(cfg.ffn_act, h),
-                              w_down.astype(cfg.dtype), counts,
-                              preferred_element_type=cfg.dtype)
+    the products compute nothing for them, and :func:`_combine` never reads
+    what they leave there. On one TPU device, at shapes the kernels take
+    (``expert_gmm.layer_path``), the products are ``expert_gmm``'s kernels,
+    elsewhere ``jax.lax.ragged_dot``. They alone stand under ``moe_products``;
+    the weights' casts and the activation between them stay under
+    ``moe_experts`` with dispatch and combine."""
+    kT, D = rows.shape
+    itemsize = jnp.dtype(cfg.dtype).itemsize
+    if expert_gmm.layer_path(kT, D, w_up.shape[-1], w_down.shape[1],
+                             get_mesh_context(), itemsize) == "kernel":
+        product = expert_gmm.grouped_product
+    else:
+        product = functools.partial(jax.lax.ragged_dot,
+                                    preferred_element_type=cfg.dtype)
+    with _scope("moe_experts"):
+        w_up = w_up.astype(cfg.dtype)
+    with _scope("moe_products"):
+        h = product(rows, w_up, counts)
+    with _scope("moe_experts"):
+        a, w_down = ffn_activation(cfg.ffn_act, h), w_down.astype(cfg.dtype)
+    with _scope("moe_products"):
+        return product(a, w_down, counts)

@@ -83,7 +83,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh
 
-from .context import get_mesh_context
+from .context import get_mesh_context, one_tpu_device
 from .kda import _grid2, _nn, _nt, _params, _tn
 
 # chunks one grid step of the kernels walks (a block of CHUNKS_PER_STEP x
@@ -111,8 +111,7 @@ def scan_path(heads: int, head_dim: int, groups: int, state: int, seq_len: int,
              and state > 0 and state % _LANES == 0
              and chunk > 0 and chunk % _LANES == 0
              and seq_len > 0 and seq_len % (chunk * CHUNKS_PER_STEP) == 0
-             and (mesh is None or mesh.size == 1)
-             and jax.devices()[0].platform == "tpu")
+             and one_tpu_device(mesh))
     return "fused" if fused else "xla"
 
 

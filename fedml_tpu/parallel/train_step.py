@@ -27,7 +27,7 @@ from ..core.mlops import telemetry
 # names for the step's device work outside the flax modules
 from ..core.mlops.scopes import TRAIN_STEP
 from ..core.mlops.scopes import train_step_scope as _scope
-from . import block_diffusion
+from . import block_diffusion, expert_gmm
 from .context import get_mesh_context, mesh_context, sequence_parallelism
 from .kda import KDA_CHUNK, scan_path
 from .mhc_streams import backward_path
@@ -344,6 +344,18 @@ class CheetahTrainer:
                             cfg.ssm_state, cfg.max_seq_len, cfg.ssm_chunk,
                             mesh)}
 
+        # the form the dropless expert layers' grouped products take, as
+        # moe._grouped_experts decides it when the step is traced at
+        # sequences of cfg.max_seq_len, with the kernels' lane tiles for
+        # each of the layer's two matrix shapes ({} without such a layer)
+        self.experts = {}
+        if "moe" in cfg.layer_kinds and cfg.moe_capacity_factor <= 0:
+            D, F = cfg.d_model, cfg.expert_d_ff
+            up = 2 * F if cfg.ffn_act == "swiglu" else F
+            rows = int(cfg.moe_top_k) * cfg.max_seq_len
+            self.experts = expert_gmm.layer_plan(
+                rows, D, up, F, mesh, jnp.dtype(cfg.dtype).itemsize)
+
         # the attention mask of a step at sequences of cfg.max_seq_len, by
         # name, with the share of the [rows, rows] pairs it lets through
         self.bd = cfg.objective == "block_diffusion"
@@ -438,6 +450,13 @@ class CheetahTrainer:
                 "chunked recurrence, not the Pallas kernels (mesh %s, sizes "
                 "%s, %d tokens): see parallel/ssd.scan_path",
                 dict(self.mesh.shape), self.ssd, self.cfg.max_seq_len)
+        if (self.experts.get("path") == "xla"
+                and jax.devices()[0].platform == "tpu"):
+            logger.warning(
+                "cheetah init: the expert layers' grouped products run as "
+                "jax.lax.ragged_dot, not the Pallas kernels (mesh %s, "
+                "products %s): see parallel/expert_gmm.path",
+                dict(self.mesh.shape), self.experts)
         mlops.log_cheetah_init(
             {k: int(v) for k, v in self.mesh.shape.items()},
             self.loss_head_gathers_per_step,
@@ -452,6 +471,7 @@ class CheetahTrainer:
             layer_pattern=self.cfg.layer_pattern, ssd=self.ssd,
             ffn_act=self.cfg.ffn_act,
             tie_embeddings=bool(self.cfg.tie_embeddings),
+            experts=self.experts,
         )
         # step must be committed to the mesh (replicated) — a default-device
         # scalar breaks jit after checkpoint restore (mixed device sets)

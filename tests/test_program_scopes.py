@@ -167,7 +167,7 @@ def instruction_names(text: str) -> set:
 @pytest.mark.parametrize("config, holds, passes", [
     (dict(), {"optimizer", "loss", "embed"}, {"fwd", "bwd"}),
     (EXPERTS, {"optimizer", "loss", "moe_experts", "moe_route",
-               "shared_expert"}, {"fwd", "bwd"}),
+               "moe_products", "shared_expert"}, {"fwd", "bwd"}),
     (dict(remat=True), {"optimizer", "loss", "rope"}, {"fwd", "bwd", "remat"}),
     # a layer list given as a pattern, one sublayer a layer (ISSUE 38): the
     # state-space mixer's scopes, every instruction of the step still mapped
@@ -212,7 +212,9 @@ def test_an_expert_layers_backward_and_recomputation_stay_under_its_scopes():
     and the sort that returns it to ``[T, k]``): every instruction of it is
     traced under ``moe_experts`` like the forward's, so the seconds
     ``moe_experts.dispatch_combine_s_per_step`` reads hold all of it. Only the
-    forward has instructions under the module alone (the sown counters)."""
+    forward has instructions under the module alone (the sown counters). The
+    grouped products stand beside it under ``moe_products``, in
+    every pass, and none of their instructions under ``moe_experts``."""
     cfg = dataclasses.replace(TransformerConfig.tiny(), remat=True, **EXPERTS)
     # one device: under the tests' eight the module's own reshape of the
     # tokens becomes a collective of the backward pass too
@@ -224,13 +226,24 @@ def test_an_expert_layers_backward_and_recomputation_stay_under_its_scopes():
     of = {name: tuple(found["scopes"][i]) for name, i in found["ops"].items()}
     layer = {name: key for name, key in of.items()
              if "MoEFeedForward" in key[0].split("/")}
-    inner = {"moe_route", "moe_experts", "shared_expert"}
+    inner = {"moe_route", "moe_experts", "moe_products", "shared_expert"}
     bare = {which for path, which in layer.values()
             if not inner & set(path.split("/"))}
     assert bare == {"fwd"}
     passes = {which for path, which in layer.values()
               if path.endswith("/moe_experts")}
     assert passes == {"fwd", "bwd", "remat"}
+    products = {name: key for name, key in layer.items()
+                if "moe_products" in key[0].split("/")}
+    assert {which for _, which in products.values()} == {"fwd", "bwd", "remat"}
+    assert not any("moe_experts" in path.split("/")
+                   for path, _ in products.values())
+    # on this CPU the products are ``ragged_dot``'s lowering, dots among it;
+    # dispatch and combine hold none
+    dots = set(re.findall(r"^\s+%?(\S+) = .* dot\(", text, re.M))
+    assert dots & set(products)
+    assert not any(layer[name][0].endswith("/moe_experts")
+                   for name in dots & set(layer))
     # the one sort of the backward pass is the gate gradient's way back
     sorts = [name for name in re.findall(r"^\s+%?(\S+) = .* sort\(", text, re.M)
              if layer.get(name, ("", ""))[1] == "bwd"]
