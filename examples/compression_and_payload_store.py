@@ -37,4 +37,11 @@ threads = [threading.Thread(target=c.run, daemon=True) for c in clients]
 for t in threads:
     t.start()
 time.sleep(0.1)
-print(server.run())
+result = server.run()
+# each client's receive loop ends on the server's finish message: wait
+# for it, so no client thread is still inside JAX at interpreter exit
+for t in threads:
+    t.join(timeout=60)
+if any(t.is_alive() for t in threads):
+    raise SystemExit("a client did not finish")
+print(result)

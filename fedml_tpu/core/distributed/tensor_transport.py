@@ -16,8 +16,7 @@ ZERO-COPY numpy views over the received buffer — the receive path does no
 per-element work at all. ``fedml_tpu.Comm/SendStream`` (grpc_backend)
 streams these bodies in bounded chunks so a GB-scale weight blob never
 needs a single contiguous gRPC message buffer — the pinned-host-staging
-analog. Measured by ``tools/bench_tensor_transport.py`` →
-``TENSOR_TRANSPORT_BENCH.json``.
+analog.
 """
 
 from __future__ import annotations

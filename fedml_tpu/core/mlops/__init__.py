@@ -298,19 +298,3 @@ def read_events(path: Optional[str] = None) -> List[Dict[str, Any]]:
         return []
     with open(p) as f:
         return [json.loads(line) for line in f if line.strip()]
-
-
-def phase_totals(events: List[Dict[str, Any]]) -> tuple:
-    """Sum ``round_record`` phase durations over an event list.
-
-    Returns ``({phase: total_seconds}, record_count)`` — the per-phase
-    breakdown bench legs attach to BENCH_*.json."""
-    totals: Dict[str, float] = {}
-    n = 0
-    for e in events:
-        if e.get("kind") != "round_record":
-            continue
-        n += 1
-        for name, dur in (e.get("phases") or {}).items():
-            totals[name] = totals.get(name, 0.0) + float(dur)
-    return totals, n

@@ -123,8 +123,8 @@ def make_ring_attention(static_ring_size: int, axis_name: str,
         my = jax.lax.axis_index(axis_name)
 
         # kernel tiles: config knobs when set, else the (512, 512) blocks
-        # that took the single-device splash path from 42% to 76% MFU
-        # (bench.py) — the kernel defaults underfeed the MXU
+        # of the single-device splash path — the kernel defaults underfeed
+        # the MXU
         from .transformer import _splash_blocks
 
         blocks = _splash_blocks(Lb, block_q or 512, block_kv or 512, Dh)

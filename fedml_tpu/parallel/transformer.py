@@ -110,7 +110,7 @@ class TransformerConfig:
     attn_impl: str = "auto"
     # splash kernel tile sizes (None = kernel defaults). The q/kv block pair
     # is the main lever for small head_dim: at hd 128 the defaults leave the
-    # MXU underfed (tools/mfu_sweep.py sweeps these)
+    # MXU underfed
     attn_block_q: int = 0
     attn_block_kv: int = 0
     # False = bidirectional encoder attention (FedNLP heads like span
@@ -1009,10 +1009,11 @@ def attend(cfg: TransformerConfig, q, k, v, mask=None,
             None,
         )
         # splash kernel inside the ring when the per-device block is in
-        # the kernel's winning regime (tools/bench_ring_kernel.py). The
-        # r5 backward is the splash dq/dkv kernels too (ring_attention
-        # ._bwd_kernel), so the threshold is no longer bwd-limited; 4096
-        # stands until the TPU block sweep re-measures the crossover
+        # the kernel's winning regime (measured on the TPU against the
+        # einsum path per block length). The r5 backward is the splash
+        # dq/dkv kernels too (ring_attention._bwd_kernel), so the threshold
+        # is no longer bwd-limited; 4096 stands until the TPU block sweep
+        # re-measures the crossover
         Lb = L // seq_ctx.size
         use_kernel = (
             _attn_backend(cfg.attn_impl) == "splash"

@@ -39,7 +39,8 @@ def make_args(run_id, **kw):
     return fedml.init(Arguments(overrides=base), should_init_logs=False)
 
 
-def run_world(run_id: str, n_clients: int = 2, **kw):
+def run_world(run_id: str, n_clients: int = 2, silos=None, **kw):
+    """``silos``: per client, the device indices its local steps run on."""
     kw.setdefault("client_num_per_round", n_clients)
     args_s = make_args(run_id, role="server", client_num_in_total=n_clients,
                        **kw)
@@ -48,8 +49,10 @@ def run_world(run_id: str, n_clients: int = 2, **kw):
     server = FedMLCrossSiloServer(args_s, None, ds, bundle)
     clients = []
     for rank in range(1, n_clients + 1):
+        silo = {} if silos is None else dict(
+            silo_device_indices=silos[rank - 1])
         args_c = make_args(run_id, role="client", rank=rank,
-                           client_num_in_total=n_clients, **kw)
+                           client_num_in_total=n_clients, **silo, **kw)
         clients.append(FedMLCrossSiloClient(args_c, None, ds, bundle))
     threads = [threading.Thread(target=c.run, daemon=True) for c in clients]
     for t in threads:
@@ -217,6 +220,28 @@ def test_fedllm_sharded_silo_mesh():
     assert dict(tr.mesh.shape)["fsdp"] == 4
     assert dict(tr.mesh.shape)["tensor"] == 2
     assert np.isfinite(result["test_loss"])
+
+
+def test_fedllm_two_silos_of_two_devices():
+    """Two silos, each training over its own two devices (``fsdp:2``): each
+    silo's mesh holds exactly its devices, no frame falls back to the host
+    codec, and round 1's sync reaches both silos as S2C delta frames."""
+    from fedml_tpu.core.mlops import telemetry
+
+    silos = [[0, 1], [2, 3]]
+    before = telemetry.registry().snapshot()["counters"]
+    result, server, clients = run_world(
+        "fedllm-2x2", silos=silos, comm_round=2, local_steps=1,
+        client_optimizer="sgd", mesh_shape="fsdp:2",
+    )
+    after = telemetry.registry().snapshot()["counters"]
+    assert np.isfinite(result["test_loss"])
+    for client, devices in zip(clients, silos):
+        mesh = client.manager.trainer.mesh
+        assert sorted(d.id for d in mesh.devices.flat) == devices
+    for name, want in (("comm.wire.host_fallbacks", 0),
+                       ("comm.delta.s2c_delta_frames", 2)):
+        assert after.get(name, 0) - before.get(name, 0) == want, name
 
 
 @pytest.mark.slow

@@ -300,6 +300,50 @@ class TestChunkedLossUnderMesh:
             assert not outside
 
 
+def test_sequence_sharded_runner(tmp_path):
+    """``mesh_shape`` with ``sequence:2`` through FedMLRunner splits the
+    batch over ``sequence`` (it used to build the axis and replicate the step
+    over it) and trains to the losses of the run without the axis, with no
+    compile after the two warm-up steps."""
+    import fedml_tpu as fedml
+    from fedml_tpu import data as data_mod
+    from fedml_tpu.arguments import Arguments
+    from fedml_tpu.core import mlops
+    from fedml_tpu.runner import FedMLRunner
+
+    def run(mesh_shape):
+        args = fedml.init(Arguments(overrides=dict(
+            training_type="distributed", dataset="shakespeare",
+            model="transformer", model_size="tiny", vocab_size=90,
+            seq_len=64, batch_size=8, total_steps=4, learning_rate=3e-3,
+            warmup_steps=2, remat=True, remat_policy="full",
+            client_num_in_total=8, client_num_per_round=8,
+            mesh_shape=mesh_shape, enable_tracking=True,
+            tracking_dir=str(tmp_path), run_id=f"seq-{mesh_shape}",
+        )), should_init_logs=False)
+        ds, _ = data_mod.load(args)
+        runner = FedMLRunner(args, fedml.get_device(args), ds, None)
+        runner.run()
+        records = [e for e in mlops.read_events()
+                   if e.get("kind") == "round_record"]
+        mlops.close()
+        trainer = runner.runner.trainer
+        tok = np.zeros((8, 64), np.int32)
+        batch, _ = trainer.shard_batch(tok, np.ones_like(tok))
+        return trainer, records, batch
+
+    seq_trainer, seq, seq_batch = run("data:4,sequence:2")
+    flat_trainer, flat, flat_batch = run("data:8")
+    assert seq_trainer.seq_sharded and not flat_trainer.seq_sharded
+    assert "'sequence'" in str(seq_batch.sharding.spec)
+    assert seq_batch.addressable_shards[0].data.shape == (2, 32)
+    assert flat_batch.addressable_shards[0].data.shape == (1, 64)
+    assert [r["train_loss"] for r in seq] == pytest.approx(
+        [r["train_loss"] for r in flat], abs=5e-3)
+    assert len(seq) == 4 and seq[0]["compiles"] > 0
+    assert sum(r["compiles"] for r in seq[2:]) == 0  # steps 0 and 1 compile
+
+
 class TestGraftEntry:
     def test_entry_compiles(self):
         import __graft_entry__ as g

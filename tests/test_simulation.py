@@ -86,6 +86,43 @@ class TestMeshSimulator:
         assert res["test_acc"] > 0.4
 
 
+@pytest.mark.parametrize("extra", [
+    dict(backend="sp"),
+    dict(backend="mesh", mesh_shape="clients:8"),
+], ids=["sp", "mesh"])
+def test_runner_rounds_are_fused(tmp_path, extra):
+    """Through FedMLRunner with telemetry on: every round is the jitted
+    round, nothing compiles after round 0, the loss falls, and under mesh
+    each device holds one client of the cohort."""
+    from fedml_tpu.core import mlops
+
+    args = fedml.init(Arguments(overrides=dict(
+        dataset="synthetic", model="lr", client_num_in_total=16,
+        client_num_per_round=8, comm_round=3, epochs=1, batch_size=16,
+        learning_rate=0.1, frequency_of_the_test=1000,
+        enable_tracking=True, tracking_dir=str(tmp_path),
+        run_id=f"fused-{extra['backend']}", **extra,
+    )), should_init_logs=False)
+    ds, od = data_mod.load(args)
+    runner = FedMLRunner(args, fedml.get_device(args), ds,
+                         model_mod.create(args, od))
+    runner.run()
+    records = [e for e in mlops.read_events()
+               if e.get("kind") == "round_record"]
+    mlops.close()
+    api = runner.runner.fl_trainer
+    assert api._round_step is not None
+    assert len(records) == 3 and all(r["fused"] for r in records)
+    assert records[0]["compiles"] > 0
+    assert sum(r["compiles"] for r in records[1:]) == 0
+    assert records[-1]["train_loss"] < records[0]["train_loss"]
+    if extra["backend"] == "mesh":
+        cohort, _ = api._pad_cohort(np.arange(8))
+        cohort_x, _, _ = api._gather_cohort(cohort)
+        assert {d.id for d in cohort_x.sharding.device_set} == set(range(8))
+        assert cohort_x.addressable_shards[0].data.shape[0] == 1
+
+
 class TestTrustHooks:
     """The attack → defend → aggregate → DP pipeline must behave identically
     on the single-device (sp) and client-sharded (mesh) engines — the mesh

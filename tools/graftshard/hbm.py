@@ -20,8 +20,8 @@ extents (XLA pads the shard; the budget then lies per-device). Totals are
 compared against the chip HBM table — exceeding a requested chip's budget
 is an S005 finding; the full report rides the ``--json`` payload either
 way. Activations/workspace are deliberately NOT estimated: they belong to
-the compiler (``tools/check_7b_readiness.py`` measures them with the real
-TPU compiler's ``memory_analysis()``); S005 bounds the *state* floor, which
+the compiler (a compile for a described TPU reports them in its
+``memory_analysis()``); S005 bounds the *state* floor, which
 is what the partition rules control.
 """
 
@@ -41,8 +41,7 @@ CHIP_HBM: Dict[str, Optional[int]] = {
     "cpu": None,
 }
 
-# leave headroom for XLA workspace/fragmentation, same margin as
-# tools/check_7b_readiness.py applies to the compiler's own verdict
+# leave headroom for XLA workspace/fragmentation
 HBM_FILL_FRACTION = 0.95
 
 _SHARDING_REL = "fedml_tpu/parallel/sharding.py"
@@ -64,8 +63,8 @@ def parse_mesh_arg(text: str) -> List[Tuple[Optional[str], str, Dict[str, int]]]
     """``--mesh`` → ``[(chip|None, label, axis extents)]``.
 
     Comma- or ``;``-separated entries; each is ``[chip:]shape`` where shape
-    is either a topology product (``4x4`` → 16 chips, all on ``fsdp`` — the
-    check_7b_readiness row convention) or explicit ``+``-joined axes
+    is either a topology product (``4x4`` → 16 chips, all on ``fsdp``) or
+    explicit ``+``-joined axes
     (``fsdp=8+tensor=2``). A chipless entry is priced against every chip
     in the table.
     """
@@ -242,8 +241,8 @@ def estimate_budget(model_name: str, mesh_text: str, *,
         "batch_per_device": int(batch_per_device), "mu_dtype": mu_dtype,
         "headroom": HBM_FILL_FRACTION,
         "accounting": "params + grads + optimizer + batch (resident "
-                      "state; compiler temps measured separately by "
-                      "tools/check_7b_readiness.py)",
+                      "state; compiler temps are the compile's own "
+                      "memory_analysis())",
         "rows": rows,
     }
     return findings, report

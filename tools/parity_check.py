@@ -12,9 +12,8 @@ Three parity grades, strongest applicable used per experiment:
    FedProx@mu=0): full-batch local steps make batch order irrelevant, so the
    two stacks compute the same math and the per-round global parameter
    vectors must agree to float32 accumulation error (rel L2 < 1e-3).
-   The reference's own ``FedAvgAPI`` runs in-process (torch CPU), exactly as
-   ``tools/measure_ref_baseline.py`` drives it. NOTE: as shipped, the
-   reference's sp loop is NOT textbook FedAvg — ``get_model_params()``
+   The reference's own ``FedAvgAPI`` runs in-process (torch CPU). NOTE: as
+   shipped, the reference's sp loop is NOT textbook FedAvg — ``get_model_params()``
    (``ml/trainer/my_model_trainer_classification.py:10``) returns live
    tensor references and ``load_state_dict`` writes through them, so each
    client's "copy of w_global" is really the previous client's trained
@@ -207,7 +206,7 @@ def _set_lr_params(params, W, b):
 
 
 # ---------------------------------------------------------------------------
-# stack B: the reference (torch CPU), driven exactly like measure_ref_baseline
+# stack B: the reference (torch CPU), imported with its missing deps stubbed
 # ---------------------------------------------------------------------------
 
 
