@@ -28,7 +28,7 @@ remat is not counted.
 from __future__ import annotations
 
 # the experts' grouped products are what they are in every expert-layer cell:
-# ``moe_experts.kernel_roofline`` asks the configuration's module for this
+# ``moe_products.kernel_roofline`` asks the configuration's module for this
 from benchmark.flops.mla_moe import grouped_product_least_seconds  # noqa: F401
 
 

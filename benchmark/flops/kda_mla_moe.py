@@ -32,6 +32,10 @@ embedding is a row gather; recomputation under remat is not counted.
 
 from __future__ import annotations
 
+# the experts' grouped products are what they are in every expert-layer cell:
+# ``moe_products.kernel_roofline`` asks the configuration's module for this
+from benchmark.flops.mla_moe import grouped_product_least_seconds  # noqa: F401
+
 
 def mixer_of(layer: int, config: dict) -> str:
     G = int(config["layer_group_size"])
@@ -108,19 +112,6 @@ def attention_kernel_flops(config: dict, seq_len: int, sequences: int) -> dict:
     return {"fwd": 2 * pairs * (dqk + dv),
             "dq": 2 * pairs * (2 * dqk + dv),
             "dkv": 2 * pairs * (2 * dqk + 2 * dv)}
-
-
-def grouped_product_least_seconds(rows: float, groups: int, k: int, n: int,
-                                  peaks: dict, itemsize: int = 2):
-    """The least time one grouped product can take: ``rows`` arrived rows in
-    all against ``groups`` [k, n] matrices. Operations ``2 rows k n``; bytes
-    the matrices once, the rows in and the rows out. Returns (seconds, which
-    bound: "flops" or "bytes")."""
-    flops = 2.0 * rows * k * n
-    moved = itemsize * (groups * k * n + rows * (k + n))
-    by_flops = flops / peaks["bf16_flops_per_s"]
-    by_bytes = moved / peaks["hbm_bytes_per_s"]
-    return max(by_flops, by_bytes), "flops" if by_flops >= by_bytes else "bytes"
 
 
 def kda_kernel_least_seconds(config: dict, seq_len: int, sequences: int,

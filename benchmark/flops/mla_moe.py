@@ -104,15 +104,19 @@ def attention_kernel_flops(config: dict, seq_len: int, sequences: int) -> dict:
             "dkv": 2 * pairs * (2 * dqk + 2 * dv)}
 
 
-def grouped_product_least_seconds(rows: float, groups: int, k: int, n: int,
-                                  peaks: dict, itemsize: int = 2):
+def grouped_product_least_seconds(rows: float, matrices: float, k: int,
+                                  n: int, peaks: dict, itemsize: int = 2):
     """The least time one grouped product can take: ``rows`` arrived rows in
-    all against ``groups`` [k, n] matrices. Operations ``2 rows k n``; bytes
-    the matrices once, the rows in and the rows out (for the product that
-    yields the matrices' gradient the same three arrays). Returns (seconds,
-    which bound: "flops" or "bytes")."""
+    all, and ``matrices`` [k, n] matrices that the call must touch.
+    Operations ``2 rows k n``; bytes the matrices once, the rows in and the
+    rows out. A product of the rows by the matrices (forward, rows' gradient)
+    must read the matrices of the groups that received rows and no other;
+    the product that yields the matrices' gradient reads two row arrays and
+    writes every group's matrix, an empty group's as zeros, and reads none.
+    One definition for every configuration's module. Returns (seconds, which
+    bound: "flops" or "bytes")."""
     flops = 2.0 * rows * k * n
-    moved = itemsize * (groups * k * n + rows * (k + n))
+    moved = itemsize * (matrices * k * n + rows * (k + n))
     by_flops = flops / peaks["bf16_flops_per_s"]
     by_bytes = moved / peaks["hbm_bytes_per_s"]
     return max(by_flops, by_bytes), "flops" if by_flops >= by_bytes else "bytes"

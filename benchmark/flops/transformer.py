@@ -8,11 +8,12 @@ Forward, per token, one multiply-add = 2 FLOPs:
   keys, ``(seq + 1) / 2`` on average, so ``2 * 2 * heads * head_dim * (seq + 1) / 2``
 - output head: ``2 * hidden * vocab``
 
-The embedding table is a row gather and costs no FLOPs (the program's
-``telemetry.flops_per_token`` counts it as a matmul and attention as
-non-causal; both are why the benchmark keeps its own). Backward is twice the
-forward; recomputation under remat is not counted, so utilization built on
-this is model FLOP/s utilization, not hardware utilization.
+The embedding table is a row gather and costs no FLOPs. The program's
+``parallel.transformer.train_flops_per_token`` counts the same from its own
+configuration; the benchmark keeps its count from the published keys, where a
+PR that claims a gain cannot change it. Backward is twice the forward;
+recomputation under remat is not counted, so utilization built on this is
+model FLOP/s utilization, not hardware utilization.
 """
 
 from __future__ import annotations

@@ -11,10 +11,23 @@ window's last ``CLOCK_STEPS`` steps (the end-to-end rate is read on the
 device's clock, see ``Job.throughput``). Neither wrapper touches what runs.
 
 Reads from the configuration file the model's sizes under their Hugging Face
-names, ``program`` (recipe: remat, kernel blocks, learning rate, mesh) and
-``schedule_total_steps``; from the traffic file ``seq_len``,
+names, ``program`` (recipe: remat, kernel blocks, learning rate, mesh),
+``schedule_total_steps`` and, where it has one, ``program_argument_of`` (each
+published key beside the program argument that carries it: the job refuses a
+value that differs and an argument the program lacks). A ``sliding_window``
+shorter than ``seq_len`` is refused unless that mapping hands it to the
+program. From the traffic file ``seq_len``,
 ``batch_per_chip``, ``program`` (e.g. ``accum_steps``), ``warmup_steps``,
 ``trace_units``, ``min_units`` and ``reference_tail``.
+
+The program's own seed (``random_seed``: its weights, its synthetic corpus
+and the windows each step reads from it) is ``--seed``, or, where the
+configuration has one, its ``window_seed`` for every ``--seed``; the
+reference check runs on the program's draw under ``--seed`` and on a batch
+drawn by ``--seed`` in either case. A pinned seed is for a cell whose work
+follows the values: which experts a drawn router favours, and how that
+shifts as the steps learn, decides how many rows reach this chip's experts
+(the configuration says why it is pinned).
 """
 
 from __future__ import annotations
@@ -88,6 +101,38 @@ def reference_params(params, config):
             "lm_head": params["w_lm_head"]}
 
 
+def _published(config, dotted):
+    value = config
+    for key in dotted.split("."):
+        value = value[key]
+    return value
+
+
+def _check_program_arguments(config, handed):
+    """Refuse a file whose ``program_argument_of`` pairs a published key with
+    a program argument of another value, and a program whose
+    ``TransformerConfig`` lacks one of those arguments (the parent of the PR
+    that brought a configuration: it cannot build it, and says so at once;
+    the program ignores an argument it has no field for)."""
+    for key, arg in handed.items():
+        want, have = _published(config, key), config["program"][arg]
+        same = (float(want) == float(have)
+                if isinstance(have, (int, float)) and not isinstance(have, bool)
+                else want == have)
+        if not same:
+            raise ValueError(
+                f"{config['name']}: {key} is {want!r} but the program's "
+                f"argument {arg} is {have!r}")
+    from fedml_tpu.parallel.transformer import TransformerConfig
+
+    fields = set(getattr(TransformerConfig, "__dataclass_fields__", ()))
+    lacking = sorted(set(handed.values()) - fields)
+    if lacking:
+        raise RuntimeError(
+            f"this program cannot build {config['name']}: its "
+            f"TransformerConfig has no {lacking}")
+
+
 class Job:
     unit = "step"
 
@@ -96,11 +141,15 @@ class Job:
         self.work_dir = work_dir
         traffic, config = cell.traffic, cell.config
         self.seq_len = int(traffic["seq_len"])
+        handed = config.get("program_argument_of", {})
         window = config.get("sliding_window")
-        if window is not None and self.seq_len > int(window):
+        if (window is not None and self.seq_len > int(window)
+                and "sliding_window" not in handed):
             raise ValueError(
                 f"seq_len {self.seq_len} exceeds the configuration's "
-                f"sliding_window {window}; the program has no window attention")
+                f"sliding_window {window}, and its program_argument_of hands "
+                "sliding_window to no program argument")
+        _check_program_arguments(config, handed)
         self.batch = int(traffic["batch_per_chip"]) * cell.chips
         self.program = {
             **BASE, **{arg: int(config[key]) for key, arg in SIZES.items()},
@@ -158,8 +207,9 @@ class Job:
         from fedml_tpu.arguments import Arguments
         from fedml_tpu.runner import FedMLRunner
 
+        window_seed = int(self.cell.config.get("window_seed", self.seed))
         args = fedml.init(Arguments(overrides={
-            **self.program, "random_seed": self.seed,
+            **self.program, "random_seed": window_seed,
             **harness.tracking_arguments(self.cell, self.seed, self.tracked,
                                          self.work_dir),
         }), should_init_logs=False)

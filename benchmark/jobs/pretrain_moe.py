@@ -8,9 +8,10 @@ configuration file and the reference module here:
 
 - the program's arguments are all under the configuration's ``program``;
   ``program_argument_of`` pairs each published key with the argument that
-  carries it, and the job refuses a file in which the two disagree, and a
-  program whose ``TransformerConfig`` lacks an argument (the parent of the PR
-  that brought a configuration: it cannot build it, and says so at once);
+  carries it, and ``pretrain.Job`` refuses a file in which the two disagree,
+  and a program whose ``TransformerConfig`` lacks an argument (the parent of
+  the PR that brought a configuration: it cannot build it, and says so at
+  once);
 - the parameter mapping is the reference module's ``reference_params``;
 - the tolerances are the configuration's ``tolerances``, each beside its
   reason.
@@ -41,35 +42,10 @@ from benchmark import harness
 pretrain = harness.load_module(harness.ROOT, "jobs", "pretrain")
 
 
-def _published(config, dotted):
-    value = config
-    for key in dotted.split("."):
-        value = value[key]
-    return value
-
-
 class Job(pretrain.Job):
     def __init__(self, cell, seed, tracked, work_dir, log):
         super().__init__(cell, seed, tracked, work_dir, log)
-        config = cell.config
-        for key, arg in config["program_argument_of"].items():
-            want, have = _published(config, key), config["program"][arg]
-            same = (float(want) == float(have)
-                    if isinstance(have, (int, float)) and not isinstance(have, bool)
-                    else want == have)
-            if not same:
-                raise ValueError(
-                    f"{config['name']}: {key} is {want!r} but the program's "
-                    f"argument {arg} is {have!r}")
-        from fedml_tpu.parallel.transformer import TransformerConfig
-
-        fields = set(getattr(TransformerConfig, "__dataclass_fields__", ()))
-        lacking = sorted(set(config["program_argument_of"].values()) - fields)
-        if lacking:
-            raise RuntimeError(
-                f"this program cannot build {config['name']}: its "
-                f"TransformerConfig has no {lacking}")
-        self.tolerances = config["tolerances"]
+        self.tolerances = cell.config["tolerances"]
 
     # -- instrumentation: the routing counter beside the loss ----------------
     def _instrument(self):

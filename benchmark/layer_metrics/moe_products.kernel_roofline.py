@@ -1,14 +1,24 @@
 """Roofline share of the experts' grouped products, in percent, whatever
 implements them: the least time their calls can take
 (``grouped_product_least_seconds`` of the configuration's flops module: per
-call the larger of ``2 x rows x k x n`` over the bf16 peak and the bytes of the
-held experts' matrices, the rows in and the rows out over the HBM peak) over
-their self time on chip 0. ``rows`` is the mean number of assignments that
-reached a held expert in one expert layer (the program's
+call the larger of ``2 x rows x k x n`` over the bf16 peak and the bytes of
+the matrices the call must touch, the rows in and the rows out over the HBM
+peak) over their self time on chip 0. ``rows`` is the mean number of
+assignments that reached a held expert in one expert layer (the program's
 ``moe_assignments_held`` counter over the expert layers); ``k x n`` is read
-from the one 3-D ``[experts held, k, n]`` shape in the call's HLO text (the
-matrices operand, or the result where the call yields their gradient). The
-calls are the custom calls to ``tpu_custom_call`` whose scope path holds
+from the one 3-D ``[experts held, k, n]`` shape in the call's HLO text.
+
+Which matrices a call must touch follows from where that shape stands. Where
+it is an operand (the forward product and the rows' gradient), the call must
+read the matrices of the held experts that received rows, and no other: the
+program's ``moe_held_experts_with_rows`` counter ((expert layer, held expert)
+pairs with at least one assignment in the step) over the expert layers. A
+program without that counter is charged every held matrix, which counts too
+much wherever a held expert gets no rows. Where the shape is the call's
+result (the matrices' gradient), the call reads two row arrays and writes
+every held matrix, an empty expert's as zeros, and reads none.
+
+The calls are the custom calls to ``tpu_custom_call`` whose scope path holds
 ``moe_products`` (the program's ``program_scopes`` map,
 ``benchmark/scope_time.py``), and those named ``ragged-dot*`` (XLA's kernel of
 ``jax.lax.ragged_dot``, its ``ragged-dot-metadata`` companions' time included)
@@ -39,13 +49,21 @@ def _in_scope(run):
             for name, key in published["ops"].items()}
 
 
+def _mean_counter(run, name):
+    found = [r["counters"][name] for r in run.records
+             if name in (r.get("counters") or {})]
+    return statistics.fmean(found) if found else None
+
+
 def read(run):
-    held = [r["counters"]["moe_assignments_held"] for r in run.records
-            if "moe_assignments_held" in (r.get("counters") or {})]
-    if run.trace is None or not held:
+    assigned = _mean_counter(run, "moe_assignments_held")
+    if run.trace is None or assigned is None:
         return None
     dev = run.trace.devices[0]
-    rows = statistics.fmean(held) / run.facts["expert_layers"]
+    layers, held = run.facts["expert_layers"], run.facts["experts_held"]
+    rows = assigned / layers
+    with_rows = _mean_counter(run, "moe_held_experts_with_rows")
+    read_matrices = held if with_rows is None else with_rows / layers
     least = harness.load_module(
         run.cell.root, "flops", run.cell.config["flops"]["module"]
     ).grouped_product_least_seconds
@@ -60,9 +78,10 @@ def read(run):
             continue
         calls = dev.ops.name_id == i
         seconds += float(self_s[calls].sum())
-        shapes = [tuple(map(int, m.groups())) for m in _SHAPE.finditer(text)
-                  if int(m.group(1)) == run.facts["experts_held"]]
+        shapes = [m for m in _SHAPE.finditer(text) if int(m.group(1)) == held]
         if shapes and "metadata" not in name:
-            groups, k, n = shapes[0]
-            need += least(rows, groups, k, n, run.peaks)[0] * int(calls.sum())
+            _, k, n = map(int, shapes[0].groups())
+            written = shapes[0].start() < text.find("custom-call(")
+            matrices = held if written else read_matrices
+            need += least(rows, matrices, k, n, run.peaks)[0] * int(calls.sum())
     return 100.0 * need / seconds if seconds else None

@@ -91,7 +91,7 @@ def test_the_cell_resolves_and_builds_the_program_the_file_describes():
         "tokens_per_s_per_chip", "peak_hbm_gb", "setup_s"}
     names = {m["name"] for m in cell.per_layer}
     assert {"short_conv.mixer_device_share", "short_conv.conv_roofline",
-            "moe_experts.kernel_roofline", "cheetah_step.mfu",
+            "moe_products.kernel_roofline", "cheetah_step.mfu",
             "attention_kernels.device_share"} <= names
     assert not {n for n in names if n.startswith(("kda.", "mla_", "bd_",
                                                   "collectives.", "ssd."))}

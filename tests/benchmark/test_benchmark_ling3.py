@@ -80,7 +80,7 @@ def test_the_cell_resolves_and_builds_the_program_the_file_describes():
     assert {m["name"] for m in cell.end_to_end} == {
         "tokens_per_s_per_chip", "peak_hbm_gb", "setup_s"}
     assert {"kda.kernel_roofline", "kda.kernel_device_share",
-            "mla_attention.kernel_roofline", "moe_experts.kernel_roofline",
+            "mla_attention.kernel_roofline", "moe_products.kernel_roofline",
             "cheetah_step.mfu"} <= {m["name"] for m in cell.per_layer}
     job = harness.load_module(ROOT, "jobs", cell.job).Job(
         cell, seed=0, tracked=False, work_dir="", log=lambda s: None)
@@ -162,6 +162,53 @@ def test_kernel_operations_and_bytes():
         1024, 8, 2560, 1536, peaks)
     assert bound == "bytes" and seconds == pytest.approx(
         2 * (8 * 2560 * 1536 + 1024 * (2560 + 1536)) / 819e9)
+
+
+def _program_seeds(monkeypatch, cell_name, seed):
+    """The program's ``random_seed`` as the job hands it to the data loader
+    (the synthetic corpus) and to the runner (weights and windows)."""
+    from fedml_tpu import data as data_mod
+    from fedml_tpu import runner as runner_mod
+
+    seen = {}
+
+    class Built(Exception):
+        pass
+
+    def load(args):
+        seen["corpus"] = int(args.random_seed)
+        return None, None
+
+    def runner(args, *rest):
+        seen["weights_and_windows"] = int(args.random_seed)
+        raise Built
+
+    monkeypatch.setattr(data_mod, "load", load)
+    monkeypatch.setattr(runner_mod, "FedMLRunner", runner)
+    cell = harness.load_cell(cell_name)
+    job = harness.load_module(ROOT, "jobs", cell.job).Job(
+        cell, seed=seed, tracked=False, work_dir="", log=lambda s: None)
+    with pytest.raises(Built):
+        job.setup()
+    return seen
+
+
+def test_the_window_runs_on_the_configurations_seed_whatever_the_seed(
+        monkeypatch):
+    """The cell's steps run on one draw of weights, corpus and windows for
+    every ``--seed`` (``window_seed``); the reference check's draw and batch
+    follow ``--seed`` (``_reference_check`` reads ``job.seed``)."""
+    pinned = int(CONFIG["window_seed"])
+    for seed in (2**31 + 17, 3):
+        assert _program_seeds(monkeypatch, CELL, seed) == {
+            "corpus": pinned, "weights_and_windows": pinned}
+
+
+def test_a_configuration_without_a_window_seed_runs_on_the_seed(monkeypatch):
+    cell = harness.load_cell("pretrain_xing4_ep8_1chip")
+    assert "window_seed" not in cell.config
+    assert _program_seeds(monkeypatch, cell.name, 2**31 + 17) == {
+        "corpus": 2**31 + 17, "weights_and_windows": 2**31 + 17}
 
 
 # ---------------------------------------------------------------------------

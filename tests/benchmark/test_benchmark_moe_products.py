@@ -1,8 +1,10 @@
 """The reader ``moe_products.kernel_roofline`` on a made trace whose seconds
 can be worked out on paper: XLA's ``ragged-dot-*`` calls under no scope and
 the repo's ``expert_gmm_*`` calls under ``moe_products`` read as the same
-work, a call at its least time reads 100%, and a step without an expert
-layer reads nothing."""
+work, a call at its least time reads 100%, a product of the rows is charged
+the matrices of the held experts that received rows and the matrices'
+gradient every held matrix, written, and a step without an expert layer
+reads nothing."""
 
 import os
 import types
@@ -21,13 +23,29 @@ PRODUCTS = "Transformer/CheckpointBlock/MoEFeedForward/moe_products"
 HELD, K, N = 8, 2688, 1856
 ROWS = 4520.0            # assignments held a layer
 LAYERS = 4
+PEAKS = harness.peaks_for("TPU v5 lite")
 flops = harness.load_module(ROOT, "flops", "ssm_moe")
 
 
-def _call(name, held=HELD):
-    """A Mosaic call's text with the matrices operand ``[held, K, N]``."""
-    return (f"%{name} = bf16[49152,{N}]{{1,0}} custom-call(bf16[49152,{K}]{{1,0}} "
-            f"%x, bf16[{held},{K},{N}]{{2,1,0}} %w), "
+def _call(name, held=HELD, k=K, n=N):
+    """A Mosaic call's text with the matrices operand ``[held, k, n]``: the
+    forward product."""
+    return (f"%{name} = bf16[49152,{n}]{{1,0}} custom-call(bf16[49152,{k}]{{1,0}} "
+            f"%x, bf16[{held},{k},{n}]{{2,1,0}} %w), "
+            'custom_call_target="tpu_custom_call"')
+
+
+def _dlhs(name, k=K, n=N):
+    """The rows' gradient: ``dy`` [rows, n] against the matrices operand."""
+    return (f"%{name} = bf16[49152,{k}]{{1,0}} custom-call(bf16[49152,{n}]{{1,0}} "
+            f"%dy, bf16[{HELD},{k},{n}]{{2,1,0}} %w), "
+            'custom_call_target="tpu_custom_call"')
+
+
+def _drhs(name, k=K, n=N):
+    """The matrices' gradient: two row arrays in, ``[held, k, n]`` out."""
+    return (f"%{name} = bf16[{HELD},{k},{n}]{{2,1,0}} custom-call("
+            f"bf16[49152,{k}]{{1,0}} %x, bf16[49152,{n}]{{1,0}} %dy), "
             'custom_call_target="tpu_custom_call"')
 
 
@@ -43,16 +61,21 @@ def _events(*rows):
                      np.array([r[2] for r in rows], float))
 
 
-def _run(rows, counters=True):
-    """One execution of the step holding ``rows`` (text, start, end)."""
+def _run(rows, counters=True, with_rows=None, config=CONFIG, layers=LAYERS,
+         assigned=ROWS):
+    """One execution of the step holding ``rows`` (text, start, end);
+    ``with_rows`` held experts a layer that received rows, where the program
+    counts them; ``assigned`` rows a layer."""
     modules = _events((f"jit_{STEP}(1)", 0.0, max(r[2] for r in rows) + 1.0))
     dev = tr.DeviceTrace(0, modules, _events(*rows), tr.EMPTY)
-    records = ([{"counters": {"moe_assignments_held": ROWS * LAYERS}}]
-               if counters else [{"counters": {}}])
+    counted = {"moe_assignments_held": assigned * layers}
+    if with_rows is not None:
+        counted["moe_held_experts_with_rows"] = with_rows * layers
+    records = [{"counters": counted if counters else {}}]
     return types.SimpleNamespace(
-        cell=types.SimpleNamespace(root=ROOT, config=CONFIG), records=records,
-        facts={"module": STEP, "expert_layers": LAYERS, "experts_held": HELD},
-        trace=tr.Trace([dev], None), peaks=harness.peaks_for("TPU v5 lite"))
+        cell=types.SimpleNamespace(root=ROOT, config=config), records=records,
+        facts={"module": STEP, "expert_layers": layers, "experts_held": HELD},
+        trace=tr.Trace([dev], None), peaks=PEAKS)
 
 
 def _scope_map(paths):
@@ -75,9 +98,8 @@ def publish(monkeypatch):
     return log
 
 
-def _least():
-    return flops.grouped_product_least_seconds(
-        ROWS, HELD, K, N, harness.peaks_for("TPU v5 lite"))[0]
+def _least(matrices=HELD, rows=ROWS):
+    return flops.grouped_product_least_seconds(rows, matrices, K, N, PEAKS)[0]
 
 
 def test_xla_calls_and_the_kernels_read_as_the_same_work(publish):
@@ -135,6 +157,63 @@ def test_nothing_to_read_reads_nothing(publish):
     assert _read(run) is None
 
 
+def test_row_products_are_charged_the_matrices_that_received_rows(publish):
+    """Half the held experts get no rows, and 500 rows a layer leave the
+    calls bound by bytes: the forward product and the rows' gradient must
+    read four matrices of eight, so each at that least time reads 100%. A
+    program without the counter is charged all eight, and the same calls
+    read more than 100%: the count the counter repairs."""
+    rows = 500.0
+    half, whole = _least(HELD / 2, rows), _least(HELD, rows)
+    calls = [(_call("expert_gmm_fwd.1"), 0.0, half),
+             (_dlhs("expert_gmm_dlhs.2"), 10.0, 10.0 + half)]
+    publish[:] = [_scope_map({"expert_gmm_fwd.1": PRODUCTS,
+                              "expert_gmm_dlhs.2": PRODUCTS})]
+    assert _read(_run(calls, with_rows=HELD / 2, assigned=rows)) == \
+        pytest.approx(100.0)
+    assert whole > 1.5 * half
+    assert _read(_run(calls, assigned=rows)) == pytest.approx(
+        100.0 * whole / half)
+
+
+def test_the_matrices_gradient_writes_every_held_matrix_and_reads_none(publish):
+    """Its least time is the rows read and all eight matrices written, with
+    the counter at half or without it."""
+    publish[:] = [_scope_map({"expert_gmm_drhs.1": PRODUCTS})]
+    for with_rows in (HELD / 2, None):
+        run = _run([(_drhs("expert_gmm_drhs.1"), 0.0, _least())],
+                   with_rows=with_rows)
+        assert _read(run) == pytest.approx(100.0)
+
+
+def test_a_ling_shaped_step_at_its_least_time_reads_100_and_no_more(publish):
+    """Six expert layers, 8 of 512 experts held, 727 rows a layer that three
+    of the eight take: the gate-and-up product ``[8, 2560, 1536]`` and the
+    down product ``[8, 768, 2560]``, each forward, rows' gradient and
+    matrices' gradient, every call at its least time. Charged every held
+    matrix, the same calls read over 100%, as the Ling cell did on a TPU
+    v5e (102.35%)."""
+    ling = harness.load_module(ROOT, "flops", "kda_mla_moe")
+    rows, layers, taken = 727.0, 6, 3
+    calls, paths, t = [], {}, 0.0
+    for j, (k, n) in enumerate(((2560, 1536), (768, 2560))):
+        for kind, text, matrices in (("fwd", _call, taken),
+                                     ("dlhs", _dlhs, taken),
+                                     ("drhs", _drhs, HELD)):
+            name = f"expert_gmm_{kind}.{j}"
+            seconds = ling.grouped_product_least_seconds(
+                rows, matrices, k, n, PEAKS)[0]
+            calls.append((text(name, k=k, n=n), t, t + seconds))
+            paths[name] = PRODUCTS
+            t += seconds + 1.0
+    publish[:] = [_scope_map(paths)]
+    shaped = dict(config={"flops": {"module": "kda_mla_moe"}}, layers=layers,
+                  assigned=rows)
+    got = _read(_run(calls, with_rows=taken, **shaped))
+    assert got == pytest.approx(100.0) and got <= 100.0 + 1e-9
+    assert _read(_run(calls, **shaped)) > 100.0
+
+
 def test_the_metric_is_listed_where_its_reader_finds_products():
     import json
 
@@ -144,6 +223,12 @@ def test_the_metric_is_listed_where_its_reader_finds_products():
                 if m["name"] == "moe_products.kernel_roofline"]
     assert entry["layer"] == "expert_layer"
     assert entry["moves"] == "tokens_per_s_per_chip"
-    experts = {m["name"]: m for m in spec["per_layer"]}[
-        "moe_experts.kernel_roofline"]["workloads"]
-    assert set(entry["workloads"]) <= set(experts)
+    assert entry["workloads"] == [
+        "pretrain_xing4_ep8_1chip", "pretrain_ling3_ep64_1chip",
+        "pretrain_sdar_ep8_bd_1chip", "pretrain_nemotron3_ep16_1chip",
+        "pretrain_lfm2_ep4_1chip"]
+    # the reader of XLA's calls alone, silent since the kernels came, is gone
+    assert "moe_experts.kernel_roofline" not in {
+        m["name"] for m in spec["per_layer"]}
+    assert not os.path.exists(os.path.join(
+        ROOT, "benchmark", "layer_metrics", "moe_experts.kernel_roofline.py"))

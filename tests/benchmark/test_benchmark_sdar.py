@@ -95,7 +95,7 @@ def test_the_cell_resolves_and_builds_the_program_the_file_describes():
         "tokens_per_s_per_chip", "peak_hbm_gb", "setup_s"}
     listed = {m["name"] for m in cell.per_layer}
     assert {"bd_attention.kernel_roofline", "bd_attention.kernel_device_share",
-            "moe_experts.kernel_roofline", "moe.assignments_held_share",
+            "moe_products.kernel_roofline", "moe.assignments_held_share",
             "moe.max_expert_load_ratio", "cheetah_step.mfu",
             "attention_kernels.device_share", "device.step_idle_share",
             "device.step_idle_attributed_share",

@@ -183,7 +183,7 @@ def test_run_cell_on_the_tiny_fixture(trace, tmp_path):
     # the counters' readers report; the device trace's find no TPU plane
     assert {"moe.assignments_held_share", "moe.max_expert_load_ratio",
             "entry.compile_s", "cheetah_runner.data_s_per_step"} <= got
-    assert not {"mla_attention.kernel_roofline", "moe_experts.kernel_roofline",
+    assert not {"mla_attention.kernel_roofline", "moe_products.kernel_roofline",
                 "cheetah_step.mfu"} & got
     share = result["metrics"]["moe.assignments_held_share"]["value"]
     assert 10 < share < 50          # 4 of 16 experts held: 25% if balanced
@@ -216,7 +216,11 @@ CALL = ('%{name} = bf16[{shape}]{{1,0}} custom-call(bf16[32768,3584]{{1,0}} %a, 
         'bf16[8,3584,2048]{{2,1,0}} %w), custom_call_target="tpu_custom_call"')
 
 
-def test_kernel_readers_tell_the_two_kernels_apart():
+def test_kernel_readers_tell_the_two_kernels_apart(monkeypatch):
+    from fedml_tpu.core import mlops
+
+    # no scope map: the grouped product is read by its name alone
+    monkeypatch.setattr(mlops, "read_events", lambda path=None: [])
     splash = ('%{name} = bf16[2,32,4096,128]{{3,2,1,0}} custom-call(%q), '
               'custom_call_target="tpu_custom_call"')
     per_call = mla_moe.attention_kernel_flops(CONFIG, 4096, 2)
@@ -235,7 +239,7 @@ def test_kernel_readers_tell_the_two_kernels_apart():
     run = _run(ops, records, FACTS)
     read = lambda name: harness.load_module(ROOT, "layer_metrics", name).read(run)
     assert read("mla_attention.kernel_roofline") == pytest.approx(50.0)
-    assert read("moe_experts.kernel_roofline") == pytest.approx(25.0)
+    assert read("moe_products.kernel_roofline") == pytest.approx(25.0)
     assert read("moe.assignments_held_share") == pytest.approx(12.5)
     assert read("moe.max_expert_load_ratio") == pytest.approx(768 / 512)
 
@@ -247,7 +251,7 @@ def test_new_readers_return_nothing_where_the_program_has_no_counter_or_kernel()
     run = _run(ops, [{"phases": {"data": 0.1}}],
                {"tokens_per_step": 8192, "chips": 1})
     for name in ("moe.assignments_held_share", "moe.max_expert_load_ratio",
-                 "mla_attention.kernel_roofline", "moe_experts.kernel_roofline"):
+                 "mla_attention.kernel_roofline", "moe_products.kernel_roofline"):
         assert harness.load_module(ROOT, "layer_metrics", name).read(run) is None
 
 
@@ -298,7 +302,7 @@ def test_latent_attention_kernels_compile_at_the_published_head_sizes(one_chip):
 def test_grouped_products_compile_to_mosaic_kernels_named_ragged_dot(one_chip):
     """``jax.lax.ragged_dot`` at the expert layer's shapes, forward and both
     gradients: XLA:TPU's own Mosaic kernels, under the name the reader of
-    ``moe_experts.kernel_roofline`` looks for."""
+    ``moe_products.kernel_roofline`` looks for under no scope."""
     def loss(x, w, sizes):
         return jax.lax.ragged_dot(x, w, sizes, preferred_element_type=jnp.bfloat16
                                   ).astype(jnp.float32).sum()
