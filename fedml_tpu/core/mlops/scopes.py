@@ -32,8 +32,8 @@ import jax
 # parallel/transformer.py, parallel/moe.py: what a configuration adds to the
 # blocks of ``_train_step_raw``; absent from a step whose configuration has
 # no latent attention, hyper-connections, experts, MTP module,
-# linear-attention, state-space or short-convolution layers, q/k norms or
-# block-diffusion objective
+# linear-attention, state-space or short-convolution layers, q/k norms,
+# window attention or block-diffusion objective
 TRAIN_STEP_BLOCKS: Tuple[str, ...] = (
     "mla",            # LatentAttention_N: low-rank projections, norms, scores
     "mhc",            # hyper-connection maps, stream reads and writes
@@ -57,6 +57,8 @@ TRAIN_STEP_BLOCKS: Tuple[str, ...] = (
     "shortconv",      # ShortConvMixer_N: LFM2's gated short convolution
     "shortconv_proj", # inside shortconv: the input and the output projection
     "shortconv_conv", # inside shortconv: the two gates and the convolution
+    "window_attention",  # inside Attention_N of a sliding_attention layer:
+                         # the attention over its band (splash or XLA)
 )
 
 # parallel/train_step.py: the jitted ``_train_step_raw``

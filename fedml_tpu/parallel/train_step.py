@@ -11,6 +11,7 @@ chunked loss, which ``lm_loss_chunked`` gathers itself, once a step.
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import logging
 from typing import Any, Optional, Tuple
@@ -365,8 +366,9 @@ class CheetahTrainer:
                 self.experts["token_tile"] = expert_rows.token_tile(
                     tokens, k, D, itemsize)
 
-        # the attention mask of a step at sequences of cfg.max_seq_len, by
-        # name, with the share of the [rows, rows] pairs it lets through
+        # the model's attention mask at sequences of cfg.max_seq_len, by
+        # name, with the share of the [rows, rows] pairs it lets through, and
+        # how many attention layers take each mask ("local:W" a window's)
         self.bd = cfg.objective == "block_diffusion"
         if self.bd and self.loss_chunk <= 0:
             raise NotImplementedError(
@@ -374,12 +376,18 @@ class CheetahTrainer:
                 "scan over whole sequences: it does not run under sequence "
                 "parallelism yet")
         L = cfg.max_seq_len
-        kind = cfg.attn_mask(2 * L if self.bd else L)[0]
+        rows = 2 * L if self.bd else L
+        kind = cfg.attn_mask(rows)[0]
         self.attn_mask = {
             "kind": kind,
             "pair_share": {"block_diffusion": block_diffusion.pair_share(
                 L, cfg.bd_block), "causal": (L + 1) / (2 * L), "full": 1.0}[kind],
         }
+        types = cfg.layer_types or (None,) * cfg.n_layers
+        masks = [cfg.attn_mask(rows, t) for mixer, t in zip(cfg.mixers, types)
+                 if mixer in ("gqa", "mla")]
+        self.attn_mask["layers"] = dict(collections.Counter(
+            f"local:{m[1]}" if m[0] == "local" else m[0] for m in masks))
         if kind == "block_diffusion":
             # the tiles of the forward kernel that evaluate the mask
             self.attn_mask["tiles"] = block_diffusion.tile_counts(

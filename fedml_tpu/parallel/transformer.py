@@ -31,7 +31,9 @@ state-space mixer (``Mamba2Mixer``, ``parallel/ssd.py``), an expert layer, a
 dense feed-forward or attention; a squared-ReLU feed-forward with no gate
 matrix (``cfg.ffn_act``); a mixer list under the published layer names
 (``cfg.layer_types``) whose layers are a mixer and a feed-forward part, the
-mixer LFM2's gated short convolution (``ShortConvMixer``) or attention; an
+mixer LFM2's gated short convolution (``ShortConvMixer``) or attention, full
+or restricted to a window of the last ``cfg.attn_window`` keys, each kind
+of attention layer under its own rotary (``cfg.rope_yarn_types``); an
 output head tied to the embedding (``cfg.tie_embeddings``). A configuration
 that names none of them builds the block it always built.
 """
@@ -67,8 +69,8 @@ LORA = "lora"  # the low-rank side of a latent projection: never sharded
 
 # cfg.layer_pattern's letters: one sublayer a layer
 _PATTERN_LETTERS = "M*E-"
-# cfg.layer_types' names (LFM2's ``layer_types``)
-_LAYER_TYPES = ("conv", "full_attention")
+# cfg.layer_types' names (LFM2's and Mellum2's ``layer_types``)
+_LAYER_TYPES = ("conv", "full_attention", "sliding_attention")
 # ShortConvMixer's taps a channel: LFM2's conv_L_cache
 SHORTCONV_TAPS = 3
 
@@ -147,6 +149,9 @@ class TransformerConfig:
     rope_beta_slow: float = 1.0
     rope_mscale: float = 1.0
     rope_mscale_all_dim: float = 0.0
+    # the layer_types names whose layers take YaRN where rope_factor > 1; the
+    # other layers take plain rotary at rope_theta. (): every layer
+    rope_yarn_types: Tuple[str, ...] = ()
     # -- a mixer per layer ---------------------------------------------------
     # G > 0: layer i runs ``attn_kind`` where (i + 1) % G == 0 and the linear
     # mixer ("kda") elsewhere. 0: ``attn_kind`` in every layer
@@ -169,9 +174,12 @@ class TransformerConfig:
     layer_pattern: str = ""
     # the mixers as data under the published names, one a layer, each layer
     # a mixer and a feed-forward part: "conv" a gated short convolution
-    # (ShortConvMixer), "full_attention" attention of ``attn_kind``;
-    # first_k_dense gives the feed-forward parts. (): the rules above
+    # (ShortConvMixer), "full_attention" attention of ``attn_kind``,
+    # "sliding_attention" the same attention over the last attn_window keys,
+    # its own included (the published sliding_window); first_k_dense gives
+    # the feed-forward parts. (): the rules above
     layer_types: Tuple[str, ...] = ()
+    attn_window: int = 0
     # Mamba2Mixer: ssm_heads heads of ssm_head_dim, B and C in ssm_groups
     # groups of ssm_state, a causal depthwise convolution of ssm_conv_size
     # taps, the recurrence in chunks of ssm_chunk; dt_bias drawn so that the
@@ -296,6 +304,21 @@ class TransformerConfig:
             if self.attn_kind == "kda":
                 raise ValueError("a full_attention layer runs attn_kind: "
                                  "name the attention there (gqa|mla)")
+        windowed = "sliding_attention" in self.layer_types
+        if windowed != (self.attn_window > 0) or self.attn_window < 0:
+            raise ValueError(
+                "a sliding_attention layer and attn_window > 0 go "
+                f"together, got attn_window {self.attn_window} and "
+                f"layer_types {list(self.layer_types)}")
+        if windowed and not (self.causal and self.objective == "next_token"):
+            raise NotImplementedError(
+                "a sliding_attention layer's window is causal: it runs under "
+                "the next_token objective with causal attention only")
+        if self.rope_yarn_types and not set(self.rope_yarn_types) <= set(
+                self.layer_types):
+            raise ValueError(
+                f"rope_yarn_types {list(self.rope_yarn_types)} names layers "
+                f"that layer_types {list(self.layer_types)} does not have")
         if "ssd" in self.mixers:
             H, G = self.ssm_heads, self.ssm_groups
             if not (H > 0 and self.ssm_head_dim > 0 and self.ssm_state > 0
@@ -387,10 +410,15 @@ class TransformerConfig:
     def head_dim(self) -> int:
         return self.attn_head_dim or self.d_model // self.n_heads
 
-    def attn_mask(self, rows: int) -> Tuple:
-        """The attention mask by name for a sequence of ``rows`` rows:
+    def attn_mask(self, rows: int, layer_type: Optional[str] = None) -> Tuple:
+        """The attention mask by name of a layer of ``layer_type`` (a name of
+        ``layer_types``; None: the model's) for a sequence of ``rows`` rows:
+        ``("local", W)`` for a ``sliding_attention`` layer whose window is
+        shorter than the rows (query ``i`` sees the keys ``(i - W, i]``), else
         ``("causal",)``, ``("full",)`` or ``("block_diffusion", L, B)`` over
         ``rows = 2L``."""
+        if layer_type == "sliding_attention" and self.attn_window < rows:
+            return ("local", self.attn_window)
         if self.objective == "block_diffusion":
             if rows % (2 * self.bd_block):
                 raise ValueError(
@@ -398,6 +426,12 @@ class TransformerConfig:
                     f"{self.bd_block}, got {rows} rows")
             return ("block_diffusion", rows // 2, self.bd_block)
         return ("causal",) if self.causal else ("full",)
+
+    def takes_yarn(self, layer_type: Optional[str] = None) -> bool:
+        """Does a layer of ``layer_type`` (None: the model's) rotate by
+        YaRN's tables rather than the plain ones?"""
+        return self.rope_factor > 1.0 and (
+            not self.rope_yarn_types or layer_type in self.rope_yarn_types)
 
     @property
     def rope_dim(self) -> int:
@@ -474,7 +508,9 @@ def train_flops_per_token(cfg: TransformerConfig, seq_len: int) -> float:
     keys on average; MLA's projections, with or without a q-LoRA; or a KDA
     layer, :func:`kda_forward_flops_per_token`; or a state-space layer,
     :func:`ssd_forward_flops_per_token`; the GQA head is
-    ``cfg.head_dim`` wide; none where the layer has no mixer), each layer's
+    ``cfg.head_dim`` wide; a ``sliding_attention`` layer's query sees
+    :func:`window_pairs` ``/ seq_len`` keys on average; none where the layer
+    has no mixer), each layer's
     feed-forward part (dense, three matrices gated and two under ``relu2``,
     or the router, the shared expert and the expected share of the routed
     experts held here; none where the layer is a mixer alone), the
@@ -492,7 +528,7 @@ def train_flops_per_token(cfg: TransformerConfig, seq_len: int) -> float:
     rows = 2 if bd else 1
     pairs = (seq_len + cfg.bd_block) / rows if bd else (seq_len + 1) / 2
 
-    def mixer(kind):
+    def mixer(kind, layer_type=None):
         if kind == "none":
             return 0
         if kind == "shortconv":
@@ -516,7 +552,9 @@ def train_flops_per_token(cfg: TransformerConfig, seq_len: int) -> float:
                         + H * cfg.v_head_dim * D)
             return proj + 2 * H * (dqk + cfg.v_head_dim) * pairs
         proj = 2 * D * cfg.head_dim * (2 * H + 2 * cfg.n_kv_heads)
-        return proj + 2 * 2 * H * cfg.head_dim * pairs
+        seen = (window_pairs(seq_len, cfg.attn_window) / seq_len
+                if layer_type == "sliding_attention" else pairs)
+        return proj + 2 * 2 * H * cfg.head_dim * seen
 
     n = cfg.hc_mult
     hyper = 2 * 2 * (n * D) * (2 * n + n * n) if n > 1 else 0
@@ -528,13 +566,21 @@ def train_flops_per_token(cfg: TransformerConfig, seq_len: int) -> float:
     head = 2 * D * cfg.vocab_size
     kinds = cfg.layer_kinds
     ffn = {"moe": expert, "dense": dense, "none": 0}
-    forward = (sum(mixer(m) for m in cfg.mixers) + len(kinds) * hyper
+    types = cfg.layer_types or (None,) * cfg.n_layers
+    forward = (sum(map(mixer, cfg.mixers, types)) + len(kinds) * hyper
                + sum(ffn[kind] for kind in kinds))
     forward = rows * forward + head
     if cfg.mtp_layers:
         forward += (mixer(cfg.attn_kind) + hyper + 2 * 2 * D * D + head
                     + (expert if cfg.moe_experts > 1 else dense))
     return 3.0 * forward
+
+
+def window_pairs(seq_len: int, window: int) -> int:
+    """(query, key) pairs of a sequence whose query ``i`` sees the keys
+    ``(i - window, i]``: ``sum_i min(i + 1, window)``."""
+    w = min(window, seq_len)
+    return w * (w + 1) // 2 + (seq_len - w) * w
 
 
 def ssd_forward_flops_per_token(d_model: int, heads: int, head_dim: int,
@@ -655,10 +701,11 @@ def rotary_embedding(
     return jnp.cos(angles), jnp.sin(angles)
 
 
-def rope_tables(cfg: "TransformerConfig", positions: jax.Array):
+def rope_tables(cfg: "TransformerConfig", positions: jax.Array,
+                yarn: bool = True):
     """The model's cos/sin tables: plain rotary, or YaRN where
-    ``cfg.rope_factor`` > 1."""
-    if cfg.rope_factor <= 1.0:
+    ``cfg.rope_factor`` > 1 and ``yarn`` (``cfg.takes_yarn``)."""
+    if cfg.rope_factor <= 1.0 or not yarn:
         return rotary_embedding(positions, cfg.rope_dim, cfg.rope_theta)
     return rotary_embedding(
         positions, cfg.rope_dim, cfg.rope_theta,
@@ -780,8 +827,9 @@ def splash_attention_tpu(q: jax.Array, k: jax.Array, v: jax.Array,
 
     ``mask_kind`` names the mask (``TransformerConfig.attn_mask``; None:
     ``causal`` decides). Every kind is a mask object the kernel computes tile
-    by tile: its bookkeeping skips the empty tiles, and no ``[L, L]`` array
-    exists anywhere. The same mask serves the query heads of a group.
+    by tile: its bookkeeping skips the empty tiles (under ``("local", W)``
+    every tile outside the band), and no ``[L, L]`` array exists anywhere.
+    The same mask serves the query heads of a group.
 
     The kernel is built per trace — make_splash_mha captures trace-local
     mask arrays, so caching it across jit traces leaks tracers.
@@ -807,6 +855,9 @@ def splash_attention_tpu(q: jax.Array, k: jax.Array, v: jax.Array,
             if 2 * mask_kind[1] != L:
                 raise ValueError(f"mask {mask_kind} over {L} rows")
             m = splash_mask(*mask_kind[1:])
+        elif mask_kind[0] == "local":
+            # keys (i - W, i]: W - 1 to the left of the query, none right
+            m = sm.LocalMask((L, L), (mask_kind[1] - 1, 0), 0)
         elif mask_kind[0] in ("causal", "full"):
             m = (sm.CausalMask((L, L)) if mask_kind[0] == "causal"
                  else sm.FullMask((L, L)))
@@ -969,19 +1020,33 @@ def attention_scores(
     return jnp.einsum("bhlm,bmhd->blhd", probs, v)
 
 
+def window_band(rows: int, window: int) -> np.ndarray:
+    """Boolean [rows, rows]: query ``i`` sees the keys ``(i - window, i]``."""
+    i = np.arange(rows)
+    return (i[None, :] <= i[:, None]) & (i[None, :] > i[:, None] - window)
+
+
 def attend(cfg: TransformerConfig, q, k, v, mask=None,
-           scale: Optional[float] = None):
+           scale: Optional[float] = None, mask_kind: Optional[Tuple] = None):
     """Attention over projected, rotated heads by the path the context and
     the back-end choose: ring attention under sequence parallelism, the
     splash kernel on a TPU, XLA elsewhere. q: [B, L, H, D]; k: [B, L, Hkv,
-    D]; v: [B, L, Hkv, Dv] -> [B, L, H, Dv]. The mask is the
-    configuration's by name (``cfg.attn_mask``), built once per trace: a
-    mask object for the kernel, the dense boolean form for XLA."""
+    D]; v: [B, L, Hkv, Dv] -> [B, L, H, Dv]. The mask is the layer's by
+    name (``cfg.attn_mask(L, layer_type)``; None: the model's), built once
+    per trace: a mask object for the kernel, the dense boolean form for XLA.
+    A window layer's attention stands under the scope ``window_attention``."""
+    mask_kind = mask_kind or cfg.attn_mask(q.shape[1])
+    if mask_kind[0] != "local":
+        return _attend(cfg, q, k, v, mask, scale, mask_kind)
+    with _scope("window_attention"):
+        return _attend(cfg, q, k, v, mask, scale, mask_kind)
+
+
+def _attend(cfg, q, k, v, mask, scale, mask_kind):
     B, L, H, _ = q.shape
     from .context import get_seq_context
 
     seq_ctx = get_seq_context()
-    mask_kind = cfg.attn_mask(L)
     structured = mask_kind[0] not in ("causal", "full")
     if seq_ctx is not None and structured:
         raise NotImplementedError(
@@ -1044,10 +1109,14 @@ def attend(cfg: TransformerConfig, q, k, v, mask=None,
             q, k, v,
         )
     elif structured:
-        from .block_diffusion import dense_mask
+        if mask_kind[0] == "local":
+            pairs = window_band(L, mask_kind[1])
+        else:
+            from .block_diffusion import dense_mask
 
+            pairs = dense_mask(*mask_kind[1:])
         out = attention_scores(q, k, v, mask, causal=False, scale=scale,
-                               pairs=dense_mask(*mask_kind[1:]))
+                               pairs=pairs)
     else:
         out = attention_scores(q, k, v, mask, causal=cfg.causal, scale=scale)
     return out
@@ -1055,6 +1124,8 @@ def attend(cfg: TransformerConfig, q, k, v, mask=None,
 
 class Attention(nn.Module):
     cfg: TransformerConfig
+    # the layer's mask by name (``cfg.attn_mask``; None: the model's)
+    mask_kind: Optional[Tuple] = None
 
     @nn.compact
     def __call__(self, x, cos, sin, mask=None):
@@ -1091,7 +1162,7 @@ class Attention(nn.Module):
                 q = apply_rotary(q, cos, sin)
                 k = apply_rotary(k, cos, sin)
 
-        out = attend(cfg, q, k, v, mask)
+        out = attend(cfg, q, k, v, mask, mask_kind=self.mask_kind)
         out = out.reshape(B, L, H * hd)
         return jnp.einsum("ble,ed->bld", out, wo.astype(cfg.dtype))
 
@@ -1107,6 +1178,7 @@ class LatentAttention(nn.Module):
     :func:`attention_scale`; ``o = concat(heads) W_o``."""
 
     cfg: TransformerConfig
+    mask_kind: Optional[Tuple] = None  # as Attention's
 
     @nn.compact
     def __call__(self, x, cos, sin, mask=None):
@@ -1150,7 +1222,8 @@ class LatentAttention(nn.Module):
             q = jnp.concatenate([q_nope, q_rope], axis=-1)
             k = jnp.concatenate(
                 [k_nope, jnp.broadcast_to(k_rope, (B, L, H, dr))], axis=-1)
-            out = attend(cfg, q, k, v, mask, scale=attention_scale(cfg))
+            out = attend(cfg, q, k, v, mask, scale=attention_scale(cfg),
+                         mask_kind=self.mask_kind)
             return jnp.einsum("ble,ed->bld", out.reshape(B, L, H * dv), wo)
 
 
@@ -1529,6 +1602,9 @@ class Block(nn.Module):
     # blocks, the MTP module's block); the Transformer's ``cfg.mixers`` says
     # it per layer
     mixer: Optional[str] = None
+    # the layer's name in ``cfg.layer_types`` (None: the model's attention),
+    # which names its attention mask
+    layer_type: Optional[str] = None
 
     @nn.compact
     def __call__(self, x, cos, sin, mask=None):
@@ -1537,8 +1613,11 @@ class Block(nn.Module):
         mixer = self.mixer or cfg.attn_kind
 
         def attention(h):
-            attn_cls = {"gqa": Attention, "mla": LatentAttention,
-                        "kda": KimiDeltaAttention, "ssd": Mamba2Mixer,
+            if mixer in ("gqa", "mla"):
+                attn_cls = Attention if mixer == "gqa" else LatentAttention
+                mask_kind = cfg.attn_mask(h.shape[1], self.layer_type)
+                return attn_cls(cfg, mask_kind)(h, cos, sin, mask)
+            attn_cls = {"kda": KimiDeltaAttention, "ssd": Mamba2Mixer,
                         "shortconv": ShortConvMixer}[mixer]
             return attn_cls(cfg)(h, cos, sin, mask)
 
@@ -1689,16 +1768,25 @@ class Transformer(nn.Module):
                 cos, sin = jnp.cos(ang), jnp.sin(ang)
         elif cfg.pos_emb == "none":
             cos = sin = None  # attention runs position-free: no rotation
-        else:
+        types = cfg.layer_types or (None,) * cfg.n_layers
+        # a table pair for each rotary the layers take (YaRN's, plain), keyed
+        # by cfg.takes_yarn; one pair for all without rotary positions
+        yarns = {cfg.takes_yarn(t) for t in types + (None,)}
+        if cfg.pos_emb == "rope":
             with _scope("rope"):
-                cos, sin = rope_tables(cfg, positions)
+                tables = {y: rope_tables(cfg, positions, y)
+                          for y in sorted(yarns)}
+        else:
+            tables = dict.fromkeys(yarns, (cos, sin))
         # hyper-connections: the embedding is copied into the streams
         x = _constrain_batch_activations(_to_streams(x, cfg.hc_mult))
 
         block_cls = _block_class(cfg)
-        for kind, mixer in zip(cfg.layer_kinds, cfg.mixers, strict=True):
+        for kind, mixer, layer_type in zip(cfg.layer_kinds, cfg.mixers, types,
+                                           strict=True):
             x = _constrain_batch_activations(
-                block_cls(cfg, ffn=kind, mixer=mixer)(x, cos, sin, mask)
+                block_cls(cfg, ffn=kind, mixer=mixer, layer_type=layer_type)(
+                    x, *tables[cfg.takes_yarn(layer_type)], mask)
             )
         if cfg.hc_mult > 1:
             x = x.sum(axis=1)  # the streams are summed before the final norm
@@ -1714,7 +1802,8 @@ class Transformer(nn.Module):
                 # embedding (the roll wraps at the last position, which has
                 # no target)
                 mtp_hidden = MultiTokenPrediction(cfg, name="mtp")(
-                    x, lookup(jnp.roll(tokens, -1, axis=1)), cos, sin, mask)
+                    x, lookup(jnp.roll(tokens, -1, axis=1)),
+                    *tables[cfg.takes_yarn()], mask)
 
         x = RMSNorm(cfg.norm_eps)(x)
         if return_hidden:

@@ -132,7 +132,8 @@ def test_the_published_names_are_the_mixers_and_the_rules_give_what_they_gave():
 
 @pytest.mark.parametrize("change, error, message", [
     (dict(layer_types=TYPES[:-1] + ("mamba",)), ValueError,
-     r"layer_types names \['conv', 'full_attention'\], got \['mamba'\]"),
+     r"layer_types names \['conv', 'full_attention', 'sliding_attention'\], "
+     r"got \['mamba'\]"),
     (dict(n_layers=6), ValueError, "names 7 layers, n_layers is 6"),
     (dict(layer_pattern="-E-E-E-", first_k_dense=0), ValueError,
      "the other spellings"),

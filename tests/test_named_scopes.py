@@ -83,8 +83,9 @@ def test_train_step_names_what_a_configuration_adds_to_the_blocks():
     bd = {"qk_norm", "bd_noise"}   # nor q/k norms or the block-diffusion draw
     ssd = {"mamba", "ssd_proj", "ssd_conv", "ssd_chunk", "ssd_norm"}
     conv = {"shortconv", "shortconv_proj", "shortconv_conv"}
+    window = {"window_attention"}
     assert set(scopes.TRAIN_STEP) - names == (
-        {"grad_accum"} | kda | bd | ssd | conv)
+        {"grad_accum"} | kda | bd | ssd | conv | window)
     assert {"LatentAttention_0", "HyperConnection_0", "MoEFeedForward_0",
             "FeedForward_0", "mtp"} <= names
 
@@ -144,6 +145,20 @@ def test_train_step_names_the_short_convolution_and_its_parts():
             "ShortConvMixer_0", "Attention_0", "qk_norm", "rope",
             "moe_route", "loss"} <= names
     assert "mamba" not in names and "shared_expert" not in names
+
+
+def test_train_step_names_the_window_layers_attention_alone():
+    """Window layers beside a full one under the published names: the
+    attention over a window's band under ``window_attention`` inside that
+    layer's module, the full layer's attention under no such name."""
+    lowered = lowered_step(
+        1, n_layers=3, layer_types=("sliding_attention", "full_attention",
+                                    "sliding_attention"), attn_window=8)
+    stacks = re.findall(r'loc\("([^"]+)"', lowered.as_text(debug_info=True))
+    windowed = {re.search(r"Block_(\d+)", s).group(1) for s in stacks
+                if "/window_attention" in s}
+    assert windowed == {"0", "2"}
+    assert "window_attention" in scope_names(lowered)
 
 
 def test_train_step_learned_positions_are_embed_and_rope():

@@ -537,6 +537,7 @@ def test_the_step_trains_and_reports_its_objective(monkeypatch):
     # 2L = 128 rows under the kernel's default tiles of 128: one tile
     assert event["attn_mask"] == {"kind": "block_diffusion",
                                   "pair_share": (L * L + L * B) / (4 * L * L),
+                                  "layers": {"block_diffusion": 2},
                                   "tiles": {"kept": 1, "crossed": 1, "of": 1}}
     tokens = jnp.tile(jnp.arange(L) % 7, (2, 1)).astype(jnp.int32)
     losses, masked = [], []
@@ -557,7 +558,8 @@ def test_the_step_trains_and_reports_its_objective(monkeypatch):
         assert re.search(rf'[/"(]{scope}[/")]', text), scope
     # every other trainer reports its mask by name too
     plain = CheetahTrainer(TransformerConfig.tiny(), one)
-    assert plain.attn_mask == {"kind": "causal", "pair_share": 129 / 256}
+    assert plain.attn_mask == {"kind": "causal", "pair_share": 129 / 256,
+                               "layers": {"causal": 2}}
     assert not plain.bd
 
 
