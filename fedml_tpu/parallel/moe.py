@@ -98,16 +98,24 @@ def route(cfg: TransformerConfig, scores_in: jax.Array, bias=None):
             select = _keep_best_groups(select, cfg.moe_n_group,
                                        cfg.moe_topk_group)
         _, expert = jax.lax.top_k(select, k)
-        aux = jnp.zeros((), jnp.float32)
     else:
         scores = jax.nn.softmax(scores_in, axis=-1)
         _, expert = jax.lax.top_k(scores, k)
+    chosen = expert[..., None] == jnp.arange(E)                     # [T, k, E]
+    aux = jnp.zeros((), jnp.float32)
+    if cfg.moe_router != "sigmoid":
         # Switch aux loss: E * sum_e frac_e * mean_prob_e. The load fraction
         # is over ALL k assignments (second-choice hot-spotting is visible
         # to the regularizer), normalised by k so a balanced router scores 1
-        frac = jax.nn.one_hot(expert, E, dtype=jnp.float32).sum(1).mean(0) / k
+        frac = chosen.astype(jnp.float32).sum(1).mean(0) / k
         aux = E * jnp.sum(frac * scores.mean(0))
-    gate = jnp.take_along_axis(scores, expert, axis=-1)
+    # the chosen scores by a select over the experts, not a gather: the k
+    # experts are distinct, so each sum has one term and equals the gather,
+    # and its transpose is a select too, where a gather's is a scatter-add.
+    # The barrier keeps XLA from merging the renormaliser's sum over the
+    # choices into this one, which would add the k gates in another order
+    gate = jax.lax.optimization_barrier(
+        jnp.where(chosen, scores[:, None, :], 0).sum(-1))
     if k > 1:  # renormalised over the chosen
         gate = gate / jnp.maximum(gate.sum(-1, keepdims=True), 1e-9)
     return expert.astype(jnp.int32), gate * cfg.moe_routed_scale, aux
@@ -193,9 +201,13 @@ class MoEFeedForward(nn.Module):
                 num_keys=1, is_stable=True)                        # [kT] each
             if cfg.moe_capacity_factor > 0:
                 kept_sorted, slot = _capacity_slots(cfg, sorted_local, counts)
+                kept_flat = None
             else:
+                # dropless, an assignment is kept where its expert is held:
+                # the same mask in both orders, and no gather between them
                 kept_sorted, slot = sorted_local < held, None
-            token, src = index_maps(kept_sorted, order, k)
+                kept_flat = local < held
+            token, src = index_maps(kept_sorted, order, k, kept_flat)
         self.sow("moe_stats", "load", load)
         self.sow("moe_stats", "dropped",
                  counts.sum() - kept_sorted.sum().astype(jnp.int32))
@@ -221,17 +233,21 @@ class MoEFeedForward(nn.Module):
         return y, aux_loss
 
 
-def index_maps(kept_sorted, order, k: int):
+def index_maps(kept_sorted, order, k: int, kept_flat=None):
     """The two index maps of dispatch and combine, from the assignments in
     expert order: ``order`` [kT] the flat index ``c * T + t`` of each sorted
     assignment, ``kept_sorted`` [kT] whether it has a row here. An index one
     past the end stands for a row of zeros: row -> its token ``[kT]``, and
-    (choice, token) -> its row ``[k, T]``."""
+    (choice, token) -> its row ``[k, T]``. ``kept_flat`` is the same mask in
+    flat order, ``kept_sorted[argsort(order)]``, where the caller has it
+    without that gather."""
     kT = order.shape[0]
     T = kT // k
     token = jnp.where(kept_sorted, order % T, T)
     inv = jnp.argsort(order, stable=True).astype(jnp.int32)
-    src = jnp.where(kept_sorted[inv], inv, kT).reshape(k, T)
+    if kept_flat is None:
+        kept_flat = kept_sorted[inv]
+    src = jnp.where(kept_flat, inv, kT).reshape(k, T)
     return token, src
 
 

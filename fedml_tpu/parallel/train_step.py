@@ -350,7 +350,8 @@ class CheetahTrainer:
         # sequences of cfg.max_seq_len, with the kernels' lane tiles for
         # each of the layer's two matrix shapes, and the form of the
         # dispatch's transpose (moe._rows_sum), with the token tile where it
-        # is the kernel ({} without such a layer)
+        # is the kernel, and how the router keeps its assignments ({}
+        # without an expert layer)
         self.experts = {}
         if "moe" in cfg.layer_kinds and cfg.moe_capacity_factor <= 0:
             D, F = cfg.d_model, cfg.expert_d_ff
@@ -365,6 +366,12 @@ class CheetahTrainer:
             if self.experts["gathers"] == "kernel":
                 self.experts["token_tile"] = expert_rows.token_tile(
                     tokens, k, D, itemsize)
+        if "moe" in cfg.layer_kinds:
+            # how the router finds which assignments are kept (moe.index_maps):
+            # by a select where nothing is dropped, by a gather under a
+            # capacity, whose kept mask depends on the slots
+            self.experts["route"] = ("gather" if cfg.moe_capacity_factor > 0
+                                     else "select")
 
         # the model's attention mask at sequences of cfg.max_seq_len, by
         # name, with the share of the [rows, rows] pairs it lets through, and

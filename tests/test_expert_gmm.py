@@ -270,7 +270,7 @@ def test_the_trainer_reports_the_path_and_tiles_and_warns_on_a_tpu_that_runs_xla
              "down": dict(k=24, n=32, fwd=32, dlhs=24, drhs=32)}
     # the dispatch's transpose: rows 32 lanes wide take no kernel, and only
     # the kernel has a token tile
-    gathers = dict(gathers="xla")
+    gathers = dict(gathers="xla", route="select")
     assert event["experts"] == trainer.experts == dict(path="xla", **tiles,
                                                        **gathers)
     assert "parallel/expert_gmm.path" not in caplog.text
@@ -296,10 +296,11 @@ def test_the_trainer_reports_the_path_and_tiles_and_warns_on_a_tpu_that_runs_xla
     assert event["experts"]["gathers"] == "xla"
     assert "parallel/expert_gmm.path" in caplog.text
     assert "parallel/expert_rows.path" in caplog.text
-    # no dropless expert layer: nothing to report
+    # no expert layer: nothing to report; under a capacity, no grouped
+    # products and the router's kept mask by a gather
     assert CheetahTrainer(TransformerConfig.tiny(), one).experts == {}
     capacity = dataclasses.replace(cfg, moe_capacity_factor=1.25)
-    assert CheetahTrainer(capacity, one).experts == {}
+    assert CheetahTrainer(capacity, one).experts == {"route": "gather"}
 
 
 # ---------------------------------------------------------------------------

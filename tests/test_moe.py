@@ -539,3 +539,147 @@ def test_rows_move_twice_through_an_assignments_sized_operand(
                       {(T + 1, D), (kT + 1, D), (slots + 1, D), (slots, D)})
     recomputed = {shape for shape, s in row_gathers if "rematted_computation" in s}
     assert recomputed == others - {(slots, D)}
+
+
+# ---------------------------------------------------------------------------
+# the router without gathers: the chosen gates by a select over the experts,
+# the kept mask in token order from the keys themselves
+# ---------------------------------------------------------------------------
+
+ROUTERS = {
+    "softmax": dict(moe_router="softmax"),
+    "sigmoid-bias": dict(moe_router="sigmoid"),
+    "sigmoid-groups": dict(moe_router="sigmoid", moe_n_group=4,
+                           moe_topk_group=2),
+}
+
+
+def gathered_route(cfg, scores_in, bias=None):
+    """The router as it stood with the chosen gates gathered, kept as the
+    definition: ``take_along_axis(scores, expert)``."""
+    from fedml_tpu.parallel.moe import _keep_best_groups
+
+    E, k = cfg.moe_experts, int(cfg.moe_top_k)
+    if cfg.moe_router == "sigmoid":
+        scores = jax.nn.sigmoid(scores_in)
+        select = scores if bias is None else scores + bias
+        if cfg.moe_n_group > 1:
+            select = _keep_best_groups(select, cfg.moe_n_group,
+                                       cfg.moe_topk_group)
+        _, expert = jax.lax.top_k(select, k)
+        aux = jnp.zeros((), jnp.float32)
+    else:
+        scores = jax.nn.softmax(scores_in, axis=-1)
+        _, expert = jax.lax.top_k(scores, k)
+        frac = jax.nn.one_hot(expert, E, dtype=jnp.float32).sum(1).mean(0) / k
+        aux = E * jnp.sum(frac * scores.mean(0))
+    gate = jnp.take_along_axis(scores, expert, axis=-1)
+    if k > 1:
+        gate = gate / jnp.maximum(gate.sum(-1, keepdims=True), 1e-9)
+    return expert.astype(jnp.int32), gate * cfg.moe_routed_scale, aux
+
+
+def sorted_routing(expert, held, offset):
+    """The layer's keys and sort: each assignment's held expert (``held``
+    where it is held elsewhere) in flat order, and the sorted keys with
+    the flat indices in expert order."""
+    k, T = expert.shape[1], expert.shape[0]
+    local = expert.T.reshape(k * T) - offset
+    local = jnp.where((local >= 0) & (local < held), local, held)
+    sorted_local, order = jax.lax.sort(
+        (local, jnp.arange(k * T, dtype=jnp.int32)), num_keys=1,
+        is_stable=True)
+    return local, sorted_local, order
+
+
+@pytest.mark.parametrize("k", [2, 6, 8])
+@pytest.mark.parametrize("router", list(ROUTERS))
+def test_the_router_is_the_gathered_definition_bit_for_bit(router, k):
+    """``route``'s experts, gates and auxiliary loss, and the scores'
+    gradient through the gates, against the definition that gathers the
+    gates; and ``index_maps`` handed the flat kept mask against the form
+    that gathers it from the sorted one, on a routing in which some tokens
+    choose no held expert."""
+    from fedml_tpu.parallel.moe import index_maps, route
+
+    cfg = oracle_cfg(moe_experts=32, moe_top_k=k, moe_routed_scale=2.5,
+                     **ROUTERS[router])
+    E, T = cfg.moe_experts, 64
+    key = jax.random.PRNGKey(k)
+    scores_in = 3.0 * jax.random.normal(jax.random.fold_in(key, 0), (T, E))
+    bias = None
+    if cfg.moe_router == "sigmoid":
+        bias = 0.1 * jax.random.normal(jax.random.fold_in(key, 1), (E,))
+    weight = jax.random.normal(jax.random.fold_in(key, 2), (T, k))
+
+    def through_gate(fn):
+        def loss(scores_in):
+            _, gate, _ = fn(cfg, scores_in, bias)
+            return jnp.sum(gate * weight)
+        return jax.jit(jax.grad(loss))(scores_in)
+
+    got = jax.jit(lambda s: route(cfg, s, bias))(scores_in)
+    want = jax.jit(lambda s: gathered_route(cfg, s, bias))(scores_in)
+    for name, g, w in zip(("expert", "gate", "aux"), got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w), name)
+    d_got, d_want = through_gate(route), through_gate(gathered_route)
+    assert np.asarray(d_want).any()
+    np.testing.assert_array_equal(np.asarray(d_got), np.asarray(d_want))
+
+    held, offset = cfg.experts_held, cfg.moe_expert_offset
+    expert = np.asarray(got[0])
+    is_held = (expert >= offset) & (expert < offset + held)
+    assert (~is_held.any(1)).any() and is_held.any()
+    local, sorted_local, order = sorted_routing(got[0], held, offset)
+    kept_sorted = sorted_local < held
+    flat = index_maps(kept_sorted, order, k, local < held)
+    gathered = index_maps(kept_sorted, order, k)
+    for name, g, w in zip(("token", "src"), flat, gathered):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w), name)
+    assert (np.asarray(flat[1]) == k * T).any()
+
+
+@pytest.mark.parametrize("router, capacity", [
+    pytest.param("softmax", 0.0, id="softmax"),
+    pytest.param("sigmoid-groups", 0.0, id="sigmoid-groups"),
+    pytest.param("softmax", 0.5, id="softmax-capacity")])
+def test_the_router_gathers_nothing_without_a_capacity(router, capacity):
+    """In the gradient of one rematerialised layer no ``gather`` or
+    ``scatter`` equation stands under ``moe_route`` where nothing is dropped;
+    under a capacity the kept mask still reaches token order by
+    ``index_maps``' gather of the sorted mask, in the forward and in its
+    recomputation."""
+    from flax import linen as nn
+
+    from fedml_tpu.parallel.moe import MoEFeedForward
+
+    k = 2
+    cfg = oracle_cfg(dtype=jnp.bfloat16, moe_top_k=k,
+                     moe_capacity_factor=capacity, **ROUTERS[router])
+    layer, params, x = layer_and_params(cfg, jax.random.PRNGKey(5),
+                                        nn.remat(MoEFeedForward))
+    variables = {"params": params}
+    if cfg.moe_router == "sigmoid":
+        variables["router_state"] = {"bias": jnp.zeros((16,), jnp.float32)}
+
+    def loss(params, x):
+        (y, aux), _ = layer.apply({**variables, "params": params}, x,
+                                  mutable=["moe_stats"])
+        return jnp.square(y.astype(jnp.float32)).sum() + aux
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(params, x).jaxpr
+    routed = [(eqn, stack) for eqn, stack in _equations(jaxpr)
+              if "moe_route" in stack]
+    assert any(eqn.primitive.name == "sort" for eqn, _ in routed)
+    by_index = [(eqn.primitive.name, eqn.invars[0].aval, stack)
+                for eqn, stack in routed
+                if eqn.primitive.name == "gather"
+                or eqn.primitive.name.startswith("scatter")]
+    if capacity == 0:
+        assert not by_index, by_index
+        return
+    kept_mask = [stack for name, aval, stack in by_index
+                 if name == "gather" and aval.dtype == jnp.bool_
+                 and aval.shape == (k * T_ORACLE,)]
+    assert len(kept_mask) == 2, by_index
+    assert sum("rematted_computation" in stack for stack in kept_mask) == 1

@@ -653,11 +653,13 @@ def test_the_programs_flops_count_a_data_token():
 # tests/test_ling3.py's.) Taken again at PR 37, which rewrote the expert
 # layer's dispatch and combine and so every program that holds one (forward
 # bit for bit and gradients against a dense oracle: tests/test_moe.py).
+# Taken again when the router came to obtain its gates and kept mask by
+# selects instead of gathers (routing bit for bit: tests/test_moe.py).
 PARENT_STEPS = {
     "ling_shaped":
-        "46d8d20806451e5a66b28e59e6d8e55b1b159b187b27cbb5317103a59211c4c4",
+        "edca3804ac509b17e95910cd421277e192c263080574845634a714c6cee2e5a1",
     "switch_shaped":
-        "62807bb177a79a906c5894fcbdce26276f9773ada7496bba8fc50f7b852e053d",
+        "0caa260a0fbde63c4761b6c332de34c8a874494fb301a50cdf60749696b64582",
 }
 
 
